@@ -30,7 +30,6 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from apex_example_tpu import amp as amp_lib
-from apex_example_tpu._compat import axis_size, pcast, vma_of
 from apex_example_tpu.amp.policy import Policy
 from apex_example_tpu.amp.scaler import ScalerState
 from apex_example_tpu.obs import numerics as numerics_lib
@@ -38,10 +37,6 @@ from apex_example_tpu.obs.spans import device_span
 from apex_example_tpu.parallel.distributed import DDPConfig, allreduce_grads
 from apex_example_tpu.parallel.mesh import DATA_AXIS
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 spelling
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 @struct.dataclass
@@ -163,7 +158,7 @@ def make_train_step(model, optimizer, policy: Policy,
         diff_params = state.params
         if explicit_reduce:
             diff_params = jax.tree_util.tree_map(
-                lambda p: pcast(p, axis_name, to="varying"),
+                lambda p: jax.lax.pcast(p, axis_name, to="varying"),
                 diff_params)
 
         def scaled_loss_for(stats, x_mb, y_mb):
@@ -349,7 +344,7 @@ def make_sharded_train_step(mesh: Mesh, model, optimizer, policy: Policy,
     # transpose drops cross-replica cotangents and SyncBatchNorm's backward
     # silently loses the terms the reference all-reduces (sum_dy/sum_dy_xmu,
     # SURVEY.md §4.4) — verified by tests/test_parallel.py.
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step_and_sync, mesh=mesh,
         in_specs=(P(), (P(axis_name), P(axis_name))),
         out_specs=(P(), P()))
@@ -533,11 +528,11 @@ def _replicate_mean(tree, axis_name: str):
     """pmean that accepts both replicated and shard-varying leaves."""
     if not jax.tree_util.tree_leaves(tree):
         return tree
-    world = axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
 
     def f(x):
-        if axis_name not in vma_of(x):  # replicated leaf (SyncBN stats)
-            x = pcast(x, axis_name, to="varying")
+        if axis_name not in jax.typeof(x).vma:  # replicated leaf (SyncBN stats)
+            x = jax.lax.pcast(x, axis_name, to="varying")
         return jax.lax.psum(x, axis_name) / world
 
     return jax.tree_util.tree_map(f, tree)

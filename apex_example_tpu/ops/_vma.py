@@ -11,17 +11,12 @@ from __future__ import annotations
 
 import jax
 
-from apex_example_tpu._compat import vma_of
-
 
 def sds(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     vma = frozenset()
     for r in operands:
-        vma = vma | vma_of(r)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # older jax without vma kwarg
-        return jax.ShapeDtypeStruct(shape, dtype)
+        vma = vma | jax.typeof(r).vma
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def align_param_grad(g, param):
@@ -39,5 +34,5 @@ def align_param_grad(g, param):
     summed.
     """
     from jax import lax
-    extra = tuple(sorted(vma_of(g) - vma_of(param)))
+    extra = tuple(sorted(jax.typeof(g).vma - jax.typeof(param).vma))
     return lax.psum(g, extra) if extra else g

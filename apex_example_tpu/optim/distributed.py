@@ -42,11 +42,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from apex_example_tpu.ops.fused_optim import adam_update_leaf
 from apex_example_tpu.optim.fused import Schedule, _lr_at
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 _LANES = 128
 
 
@@ -247,7 +242,7 @@ def make_zero_train_step(mesh: Mesh, model, optimizer: DistributedFusedAdam,
     # Prefix specs: a single P() stands for a whole replicated subtree.
     spec = TrainState(step=P(), params=P(), batch_stats=P(),
                       opt_state=optimizer.state_spec(), scaler=P())
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step_and_sync, mesh=mesh,
         in_specs=(spec, (P(axis), P(axis))),
         out_specs=(spec, P()))

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_example_tpu._compat import axis_size, vma_of
 from apex_example_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -92,15 +91,14 @@ def allreduce_grads(grads: Any, config: DDPConfig = DDPConfig(),
     ``check_vma=False`` vma information is absent, so callers must pass it
     explicitly (False for raw per-shard grads).
     """
-    world = axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     pre = config.gradient_predivide_factor
     post = (world / pre) if config.gradient_average else (1.0 / pre)
 
     def reduce_one(g):
         dt = g.dtype
         if already_reduced is None:
-            vma = vma_of(g)
-            reduced = axis_name not in vma
+            reduced = axis_name not in jax.typeof(g).vma
         else:
             reduced = already_reduced
         if reduced:

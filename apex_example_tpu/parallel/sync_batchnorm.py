@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from apex_example_tpu._compat import axis_size
-
 
 class SyncBatchNorm(nn.Module):
     """Drop-in BatchNorm with optional cross-replica stat reduction.
@@ -116,7 +114,7 @@ class SyncBatchNorm(nn.Module):
             for a in reduce_axes:
                 n *= x.shape[a]
             if axis is not None:
-                n *= axis_size(axis)
+                n *= lax.axis_size(axis)
         else:
             # XLA composite form: one fused (Σ(x-c), Σ(x-c)²) read, psum
             # Welford merge, elementwise apply.  XLA fuses the stat reduces
@@ -135,7 +133,7 @@ class SyncBatchNorm(nn.Module):
             if axis is not None:
                 # Cross-replica Welford merge (reference: syncbn allreduce of
                 # (count, mean, M2); here two psums over the mesh axis).
-                world = axis_size(axis)
+                world = lax.axis_size(axis)
                 n = n_local * world
                 mean_c = lax.psum(local_sum, axis) / n
                 m2 = lax.psum(

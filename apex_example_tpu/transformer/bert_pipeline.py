@@ -77,11 +77,6 @@ from apex_example_tpu.parallel.mesh import DATA_AXIS, PIPE_AXIS
 from apex_example_tpu.transformer.pipeline_parallel.schedules import (
     pipeline_1f1b, spmd_pipeline)
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 def _rest_keys(dense_params) -> Tuple[str, ...]:
     """Everything that is not a stacked encoder layer — embedding + head
     params.  Derived from the tree itself so one pack/unpack pair serves
@@ -869,10 +864,10 @@ def make_bert_pp_train_step(mesh: Mesh, model: BertForMaskedLM, optimizer,
     from apex_example_tpu.workloads import partial_manual_axis_names
     manual = frozenset({PIPE_AXIS, DATA_AXIS}
                        | ({CONTEXT_AXIS} if cp > 1 else set()))
-    kw = partial_manual_axis_names(mesh, model, manual, "TP x PP")
+    kw = partial_manual_axis_names(mesh, model, manual)
     b = P(DATA_AXIS, CONTEXT_AXIS) if cp > 1 else P(DATA_AXIS)
     bspec = (b, b) if is_gpt else (b, (b, b))
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(state_spec, bspec),
         out_specs=(state_spec, P()), **kw)

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_example_tpu import _compat
 from apex_example_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from apex_example_tpu.transformer import parallel_state
 
@@ -46,11 +45,8 @@ def _manual_axes() -> frozenset:
     """Mesh axes the current trace is *manual* over (bound by an enclosing
     shard_map).  Empty outside shard_map.  Constraints must not name these:
     inside the body the arrays are per-shard slices and the axis is already
-    consumed by the shard_map's in_specs.  (Routed through _compat: jax
-    versions without abstract meshes report no manual axes — the pure-
-    GSPMD TP paths this rig runs never have any.)"""
-    am = _compat.get_abstract_mesh()
-    return frozenset(getattr(am, "manual_axes", ()) or ())
+    consumed by the shard_map's in_specs."""
+    return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
@@ -81,7 +77,7 @@ def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
         tuple(filter(None, (live(a) for a in e))) or None
         if isinstance(e, tuple) else live(e)
         for e in spec)
-    target = _compat.get_abstract_mesh() if manual else mesh
+    target = jax.sharding.get_abstract_mesh() if manual else mesh
     return jax.lax.with_sharding_constraint(x, NamedSharding(target,
                                                              P(*spec)))
 
@@ -217,7 +213,11 @@ class VocabParallelEmbedding(nn.Module):
     axis_name: str = MODEL_AXIS
     dtype: Optional[jnp.dtype] = None
     param_dtype: jnp.dtype = jnp.float32
-    embedding_init: Initializer = nn.initializers.normal(stddev=0.02)
+    # nn.Embed's own default: with the same param name and rng path, a TP
+    # model then STARTS from the dense model's weights (same seed => same
+    # step-1 loss), not just from an interchangeable checkpoint layout.
+    embedding_init: Initializer = nn.initializers.variance_scaling(
+        1.0, "fan_in", "normal", out_axis=0)
 
     def setup(self):
         # setup() (not @nn.compact) so ``attend`` can reuse the table — the

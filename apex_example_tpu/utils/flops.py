@@ -1,8 +1,9 @@
-"""Analytic FLOPs models for the benchmark configs → MFU accounting.
+"""Analytic FLOPs models for the benchmark configs → MFU accounting, and
+the one table of device peaks every roofline number divides by.
 
-VERDICT r4 item 3: ``bench.py`` must state what fraction of the chip's peak
-each throughput number represents, not just raw img/s / tok/s.  The models
-here are deterministic closed forms (no device, no tracing):
+``bench.py`` must state what fraction of the chip's peak each throughput
+number represents, not just raw img/s / tok/s.  The models here are
+deterministic closed forms (no device, no tracing):
 
 - **Transformers** (BERT/GPT/TXL): the standard training-compute model —
   ``6 · N_matmul`` FLOPs per token (2 per MAC × 3 for fwd+bwd, counting
@@ -16,19 +17,54 @@ here are deterministic closed forms (no device, no tracing):
   FLOPs per image forward, training ×3 (dgrad and wgrad are each conv-
   shaped).  BN/ReLU/pool FLOPs are noise against the convs and count 0.
 
-MFU uses the v5e bf16 peak (197 TFLOP/s/chip) uniformly — also for the
-fp32 c1 row, so every row is comparable against the same roofline (the
-fp32 row's MFU is then conservative: fp32 MXU peak is lower).
+MFU uses the device's bf16 peak uniformly — also for the fp32 c1 row, so
+every row is comparable against the same roofline (the fp32 row's MFU is
+then conservative: fp32 MXU peak is lower).
 """
 
 from __future__ import annotations
 
-V5E_BF16_PEAK_FLOPS = 197e12      # per chip; Cloud TPU v5e spec sheet
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one ``device_kind``."""
+    bf16_flops: float           # FLOP/s
+    hbm_bytes_per_s: float
+    hbm_bytes: float
+    source: str
+
+
+# What jax.devices()[0].device_kind reads on a v5e (read off the chip).
+V5E = "TPU v5 lite"
+
+# Keyed by ``jax.devices()[0].device_kind``.  A device that is not here is
+# an error (device_peaks), never a default: a utilization against the wrong
+# peak is a wrong number under a right name.
+DEVICE_PEAKS = {
+    V5E: DevicePeaks(
+        bf16_flops=197e12, hbm_bytes_per_s=819e9, hbm_bytes=16e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM2e, 819 GB/s per chip"),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The table row for ``device_kind``; KeyError names what is missing."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"device_kind {device_kind!r} is not in utils.flops."
+            f"DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); add its "
+            "published peaks with their source") from None
 
 
 def mfu_pct(items_per_sec: float, flops_per_item: float,
-            peak_flops: float = V5E_BF16_PEAK_FLOPS) -> float:
-    """Model-FLOPs utilization in percent."""
+            peak_flops: float) -> float:
+    """Model-FLOPs utilization in percent of ``peak_flops`` (a
+    ``DevicePeaks.bf16_flops``)."""
     return 100.0 * items_per_sec * flops_per_item / peak_flops
 
 

@@ -23,11 +23,6 @@ from apex_example_tpu.ops.xentropy import softmax_cross_entropy
 from apex_example_tpu.parallel.distributed import DDPConfig, allreduce_grads
 from apex_example_tpu.parallel.mesh import DATA_AXIS
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def mlm_loss(logits: jnp.ndarray, target: Tuple[jnp.ndarray, jnp.ndarray]
              ) -> jnp.ndarray:
@@ -217,7 +212,7 @@ def make_sharded_txl_train_step(mesh: Mesh, model, optimizer, policy: Policy,
                                     max_grad_norm=max_grad_norm,
                                     grad_accum=grad_accum)
     mem_spec = P(None, axis_name)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), mem_spec, (P(axis_name), P(axis_name))),
         out_specs=(P(), mem_spec, P()))
@@ -262,7 +257,7 @@ def make_bert_cp_train_step(mesh: Mesh, model, optimizer, policy: Policy,
                                 loss_fn=cp_mlm_loss, compute_accuracy=False,
                                 grad_accum=grad_accum)
     st_spec = _cp_state_spec(optimizer)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(st_spec, (P(DATA_AXIS, CONTEXT_AXIS),
                             (P(DATA_AXIS, CONTEXT_AXIS),
@@ -280,8 +275,8 @@ def make_bert_cp_train_step(mesh: Mesh, model, optimizer, policy: Policy,
     return jax.jit(sharded, donate_argnums=(0,) if donate else (), **jkw)
 
 
-def partial_manual_axis_names(mesh: Mesh, model, manual_axes: frozenset,
-                              label: str) -> dict:
+def partial_manual_axis_names(mesh: Mesh, model,
+                              manual_axes: frozenset) -> dict:
     """shard_map kwargs for a TP-composed step: with a nontrivial 'model'
     axis the map goes manual over ``manual_axes`` ONLY, leaving 'model'
     automatic so the GSPMD TP layers (tensor_parallel=True) run inside
@@ -291,18 +286,13 @@ def partial_manual_axis_names(mesh: Mesh, model, manual_axes: frozenset,
     from apex_example_tpu.parallel.mesh import require_model_axis_match
     tp = require_model_axis_match(mesh, getattr(model, "tensor_parallel",
                                                 False))
-    if tp > 1 and not hasattr(jax, "shard_map"):  # pragma: no cover
-        raise RuntimeError(
-            f"the {label} composition needs jax.shard_map's axis_names "
-            "(jax >= 0.7); the jax.experimental fallback cannot express "
-            "a partially-manual mesh")
     return {"axis_names": set(manual_axes)} if tp > 1 else {}
 
 
 def _cp_axis_names(mesh: Mesh, model) -> dict:
     from apex_example_tpu.parallel.mesh import CONTEXT_AXIS
     return partial_manual_axis_names(
-        mesh, model, frozenset({DATA_AXIS, CONTEXT_AXIS}), "CP x TP")
+        mesh, model, frozenset({DATA_AXIS, CONTEXT_AXIS}))
 
 
 def _cp_state_spec(optimizer):
@@ -346,9 +336,9 @@ def make_bert_cp_eval_step(mesh: Mesh, model):
                 / den * 100.0}
 
     spec = P(DATA_AXIS, CONTEXT_AXIS)
-    sharded = _shard_map(per_shard, mesh=mesh,
-                         in_specs=(P(), (spec, (spec, spec))),
-                         out_specs=P(), **_cp_axis_names(mesh, model))
+    sharded = jax.shard_map(per_shard, mesh=mesh,
+                             in_specs=(P(), (spec, (spec, spec))),
+                             out_specs=P(), **_cp_axis_names(mesh, model))
     return jax.jit(sharded)
 
 
@@ -414,10 +404,10 @@ def make_gpt_cp_train_step(mesh: Mesh, model, optimizer, policy: Policy,
                                 grad_accum=grad_accum)
     spec = P(DATA_AXIS, CONTEXT_AXIS)
     st_spec = _cp_state_spec(optimizer)
-    sharded = _shard_map(per_shard, mesh=mesh,
-                         in_specs=(st_spec, (spec, spec)),
-                         out_specs=(st_spec, P()),
-                         **_cp_axis_names(mesh, model))
+    sharded = jax.shard_map(per_shard, mesh=mesh,
+                             in_specs=(st_spec, (spec, spec)),
+                             out_specs=(st_spec, P()),
+                             **_cp_axis_names(mesh, model))
     sharded = _cp_layout_wrap(sharded, mesh, model, mode)
     jkw = {}
     if state_shardings is not None:
@@ -439,9 +429,9 @@ def make_gpt_cp_eval_step(mesh: Mesh, model, mode: str = "ring"):
                                         (DATA_AXIS, CONTEXT_AXIS))}
 
     spec = P(DATA_AXIS, CONTEXT_AXIS)
-    sharded = _shard_map(per_shard, mesh=mesh,
-                         in_specs=(P(), (spec, spec)), out_specs=P(),
-                         **_cp_axis_names(mesh, model))
+    sharded = jax.shard_map(per_shard, mesh=mesh,
+                             in_specs=(P(), (spec, spec)), out_specs=P(),
+                             **_cp_axis_names(mesh, model))
     return jax.jit(_cp_layout_wrap(sharded, mesh, model, mode))
 
 
@@ -540,8 +530,7 @@ def bert_moe_state_shardings(mesh: Mesh, state: TrainState, optimizer,
 
 
 def _moe_axis_names(mesh: Mesh, model) -> dict:
-    return partial_manual_axis_names(mesh, model, frozenset({DATA_AXIS}),
-                                     "MoE x TP")
+    return partial_manual_axis_names(mesh, model, frozenset({DATA_AXIS}))
 
 
 def _moe_cp_axis_names(mesh: Mesh, model) -> dict:
@@ -550,7 +539,7 @@ def _moe_cp_axis_names(mesh: Mesh, model) -> dict:
     composition is not wired (train.py rejects it)."""
     from apex_example_tpu.parallel.mesh import CONTEXT_AXIS
     return partial_manual_axis_names(
-        mesh, model, frozenset({DATA_AXIS, CONTEXT_AXIS}), "MoE x CP x TP")
+        mesh, model, frozenset({DATA_AXIS, CONTEXT_AXIS}))
 
 
 def _moe_batch_plumbing(mesh: Mesh, model, objective: str,
@@ -672,9 +661,9 @@ def make_bert_moe_train_step(mesh: Mesh, model, optimizer, policy: Policy,
     b, manual, wrap = _moe_batch_plumbing(mesh, model, objective,
                                           context_parallel, mode)
     batch_spec = (b, (b, b)) if objective == "mlm" else (b, b)
-    sharded = wrap(_shard_map(per_shard, mesh=mesh,
-                              in_specs=(spec_state, batch_spec),
-                              out_specs=(spec_state, P()), **manual))
+    sharded = wrap(jax.shard_map(per_shard, mesh=mesh,
+                                  in_specs=(spec_state, batch_spec),
+                                  out_specs=(spec_state, P()), **manual))
     jkw = {}
     if state_shardings is not None:
         # MoE x TP: pin the returned state to its combined placement
@@ -722,8 +711,8 @@ def make_bert_moe_eval_step(mesh: Mesh, model, params_template,
     b, manual, wrap = _moe_batch_plumbing(mesh, model, objective,
                                           context_parallel, mode)
     batch_spec = (b, (b, b)) if objective == "mlm" else (b, b)
-    sharded = wrap(_shard_map(per_shard, mesh=mesh,
-                              in_specs=(_moe_param_spec_tree(
-                                  params_template), batch_spec),
-                              out_specs=P(), **manual))
+    sharded = wrap(jax.shard_map(per_shard, mesh=mesh,
+                                  in_specs=(_moe_param_spec_tree(
+                                      params_template), batch_spec),
+                                  out_specs=P(), **manual))
     return jax.jit(sharded)

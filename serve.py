@@ -589,7 +589,9 @@ def run_serve(args):
                                         synthetic_requests)
     from apex_example_tpu.transformer import parallel_state
     from apex_example_tpu.utils.checkpoint import restore_params
+    from apex_example_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     mesh = None
     dp = tp = 1
     if args.mesh:

@@ -13,10 +13,7 @@ from apex_example_tpu.ops.batch_norm import _pick_block
 from apex_example_tpu.parallel.mesh import make_data_mesh
 from apex_example_tpu.parallel.sync_batchnorm import SyncBatchNorm
 
-try:
-    from jax import shard_map as shard_map_fn
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as shard_map_fn
+from jax import shard_map as shard_map_fn
 from jax.sharding import PartitionSpec as P
 
 

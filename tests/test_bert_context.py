@@ -215,8 +215,7 @@ def test_cp_tp_train_matches_dense(devices8):
         step_d = jax.jit(make_train_step(dense, opt(), policy,
                                          loss_fn=mlm_loss,
                                          compute_accuracy=False))
-        # Dense init (the TP twin's VocabParallelEmbedding has a different
-        # initializer), placed into the TP metadata shardings.
+        # Dense init, placed into the TP metadata shardings.
         state_c = create_train_state(jax.random.PRNGKey(0), dense, opt(),
                                      sample, policy, scaler)
         sh = gspmd_state_shardings(mesh, tp_model, opt(), sample, policy)

@@ -283,10 +283,7 @@ def _golden_moe_cp_step(mesh, model_gold, optimizer, policy, mode):
     from apex_example_tpu.engine import make_train_step
     from apex_example_tpu.workloads import (_cp_layout_wrap,
                                             _global_lm_loss)
-    try:
-        from jax import shard_map as smap
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as smap
+    from jax import shard_map as smap
 
     def gold_loss(out, y):
         logits, aux = out

@@ -15,10 +15,7 @@ from apex_example_tpu.transformer.expert_parallel import (
     EXPERT_AXIS, MoEParams, _dispatch_masks, init_moe_params,
     moe_forward, moe_forward_dense_reference)
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _mesh(devices8):

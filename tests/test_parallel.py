@@ -18,10 +18,7 @@ from apex_example_tpu.parallel import (
     make_data_mesh)
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _bn_apply(axis_name=None, train=True):
@@ -191,15 +188,9 @@ class TestDDP:
 
 
 def _shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map without replication checking, spelled for BOTH jax
-    eras: vma-typed (check_vma) and classic (check_rep) — the rig's
-    0.4.37 carries only the latter."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """shard_map without variance checking."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 class TestDDPPrecision:

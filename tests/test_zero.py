@@ -70,10 +70,7 @@ def test_zero_apply_matches_fused_adam_fixed_grads(devices8):
     """One sharded apply on fixed (params, grads) == replicated FusedAdam
     elementwise — no model in the loop, so no sign-flip amplification."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as smap
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as smap
+    from jax import shard_map as smap
 
     mesh = make_data_mesh(devices=devices8)
     hp = dict(lr=3e-3, betas=(0.9, 0.999), eps=1e-8, weight_decay=1e-2)

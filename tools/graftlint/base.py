@@ -22,9 +22,9 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 # Directories never scanned: tests exercise the rules with deliberate
-# positive fixtures, superseded/ is dead code kept for archaeology, and
-# csrc/ is not python.
-EXCLUDE_DIRS = {"tests", "__pycache__", "superseded", ".git", ".claude",
+# positive fixtures, chip_work/ holds unpacked copies of other commits for
+# chip runs (.gitignore), and csrc/ is not python.
+EXCLUDE_DIRS = {"tests", "__pycache__", "chip_work", ".git", ".claude",
                 "csrc", "related", "node_modules"}
 
 _SUPPRESS = re.compile(r"#\s*graftlint:\s*ignore(?:\[([a-z0-9_,\- ]+)\])?")

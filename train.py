@@ -53,6 +53,7 @@ from apex_example_tpu.resilience import (EX_TEMPFAIL, FaultPlan,
 from apex_example_tpu.utils import AverageMeter, Throughput
 from apex_example_tpu.utils.checkpoint import (CheckpointManager,
                                                restore_under_mesh)
+from apex_example_tpu.utils.compile_cache import enable_compile_cache
 from apex_example_tpu.workloads import (lm_loss,
                                         make_sharded_txl_train_step,
                                         make_txl_train_step, mlm_loss)
@@ -627,6 +628,7 @@ def build_zero_optimizer(args, n_dev, gspmd=False,
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     if args.grad_accum > 1 and args.batch_size % args.grad_accum:
         # Uniform rejection for every path (the microbatch split would
         # otherwise surface as a reshape TypeError deep inside tracing).
