@@ -80,8 +80,9 @@ def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray
                        ) -> jnp.ndarray:
     """Mean softmax-CE in fp32 (the reference computes criterion on
     ``output.float()``)."""
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), labels).mean()
+    with device_span("loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), labels).mean()
 
 
 def _apply_model(model, params, batch_stats, x, train: bool):

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from apex_example_tpu.normalization import FusedLayerNorm
+from apex_example_tpu.obs.spans import device_span
 
 # Measured fused-vs-XLA crossover on the v5e rig (PERF.md attention table):
 # the flash kernel loses below ~2k tokens (XLA's fusions keep the small
@@ -250,81 +251,85 @@ class BertSelfAttention(nn.Module):
                 # 1. Copy-on-write: slots whose next write lands in a
                 # shared (immutable) block copy it first — dst -1 means
                 # no COW this tick and the scatter drops out of range.
-                src = jnp.clip(paged["cow_src"], 0, NB - 1)
-                dst = jnp.where(paged["cow_dst"] >= 0, paged["cow_dst"],
-                                NB)
-                ck.value = arena(ck.value.at[dst].set(ck.value[src],
-                                                      mode="drop"))
-                cv.value = arena(cv.value.at[dst].set(cv.value[src],
-                                                      mode="drop"))
-                if self.kv_quant:
-                    # Scales are block-resident state: a COW must carry
-                    # them with the payload, or the copy dequantizes
-                    # under the zero scales of a fresh block.
-                    cks.value = cks.value.at[dst].set(cks.value[src],
-                                                      mode="drop")
-                    cvs.value = cvs.value.at[dst].set(cvs.value[src],
-                                                      mode="drop")
+                with device_span("kv_cow"):
+                    src = jnp.clip(paged["cow_src"], 0, NB - 1)
+                    dst = jnp.where(paged["cow_dst"] >= 0, paged["cow_dst"],
+                                    NB)
+                    ck.value = arena(ck.value.at[dst].set(ck.value[src],
+                                                          mode="drop"))
+                    cv.value = arena(cv.value.at[dst].set(cv.value[src],
+                                                          mode="drop"))
+                    if self.kv_quant:
+                        # Scales are block-resident state: a COW must carry
+                        # them with the payload, or the copy dequantizes
+                        # under the zero scales of a fresh block.
+                        cks.value = cks.value.at[dst].set(cks.value[src],
+                                                          mode="drop")
+                        cvs.value = cvs.value.at[dst].set(cvs.value[src],
+                                                          mode="drop")
                 # 2. Scatter this tick's K/V through the block table:
                 # token j of slot s lands at logical position fill[s]+j,
                 # physical arena row table[s, pos//BS]*BS + pos%BS.
                 # Lanes past n_new[s] scatter out of range and drop —
                 # the host only maps exclusively-owned blocks for the
                 # write span, so no two slots write one block.
-                pos = fill[:, None] + jnp.arange(C)[None, :]
-                blk = jnp.take_along_axis(
-                    table, jnp.clip(pos // BS, 0, table.shape[1] - 1),
-                    axis=1)
-                flat = blk * BS + pos % BS
-                valid = jnp.arange(C)[None, :] < n_new[:, None]
-                flat = jnp.where(valid, flat, NB * BS).reshape(-1)
-                if self.kv_quant:
-                    # Quantize on the write: one symmetric max-abs
-                    # scale per token over its [h, hd] vector, scale
-                    # rows scattered through the SAME flat indices as
-                    # the int8 payload (quant/kv.py).
-                    k, k_sc = kv_quant.quantize_write(k)
-                    v, v_sc = kv_quant.quantize_write(v)
-                    cks.value = cks.value.reshape(NB * BS).at[flat].set(
-                        k_sc.reshape(S * C),
-                        mode="drop").reshape(NB, BS)
-                    cvs.value = cvs.value.reshape(NB * BS).at[flat].set(
-                        v_sc.reshape(S * C),
-                        mode="drop").reshape(NB, BS)
-                ck.value = arena(
-                    ck.value.reshape(NB * BS, h, hd).at[flat].set(
-                        k.reshape(S * C, h, hd),
-                        mode="drop").reshape(NB, BS, h, hd))
-                cv.value = arena(
-                    cv.value.reshape(NB * BS, h, hd).at[flat].set(
-                        v.reshape(S * C, h, hd),
-                        mode="drop").reshape(NB, BS, h, hd))
+                with device_span("kv_write"):
+                    pos = fill[:, None] + jnp.arange(C)[None, :]
+                    blk = jnp.take_along_axis(
+                        table, jnp.clip(pos // BS, 0, table.shape[1] - 1),
+                        axis=1)
+                    flat = blk * BS + pos % BS
+                    valid = jnp.arange(C)[None, :] < n_new[:, None]
+                    flat = jnp.where(valid, flat, NB * BS).reshape(-1)
+                    if self.kv_quant:
+                        # Quantize on the write: one symmetric max-abs
+                        # scale per token over its [h, hd] vector, scale
+                        # rows scattered through the SAME flat indices as
+                        # the int8 payload (quant/kv.py).
+                        k, k_sc = kv_quant.quantize_write(k)
+                        v, v_sc = kv_quant.quantize_write(v)
+                        cks.value = cks.value.reshape(NB * BS).at[flat].set(
+                            k_sc.reshape(S * C),
+                            mode="drop").reshape(NB, BS)
+                        cvs.value = cvs.value.reshape(NB * BS).at[flat].set(
+                            v_sc.reshape(S * C),
+                            mode="drop").reshape(NB, BS)
+                    ck.value = arena(
+                        ck.value.reshape(NB * BS, h, hd).at[flat].set(
+                            k.reshape(S * C, h, hd),
+                            mode="drop").reshape(NB, BS, h, hd))
+                    cv.value = arena(
+                        cv.value.reshape(NB * BS, h, hd).at[flat].set(
+                            v.reshape(S * C, h, hd),
+                            mode="drop").reshape(NB, BS, h, hd))
                 # 3. Gather each slot's logical K/V view back out of the
                 # arena ([S, max_blocks*BS, H, D], logical order) and
                 # attend under the per-slot causal live mask: query j
                 # (position fill+j) sees keys at positions <= fill+j —
                 # unwritten/stale arena rows sit beyond it and garbage
                 # lanes of dead slots are discarded by the host.
-                tbl = jnp.clip(table, 0, NB - 1)
-                keys = ck.value[tbl].reshape(S, -1, h, hd)
-                vals = cv.value[tbl].reshape(S, -1, h, hd)
-                if self.kv_quant:
-                    # Scale-fused dequant of the gathered logical view:
-                    # attention (softmax included) runs at full
-                    # precision on the dequantized values.
-                    keys = kv_quant.dequantize_gather(
-                        keys, cks.value[tbl].reshape(S, -1), self.dtype)
-                    vals = kv_quant.dequantize_gather(
-                        vals, cvs.value[tbl].reshape(S, -1), self.dtype)
-                L = keys.shape[1]
-                live = jnp.arange(L)[None, None, :] <= pos[:, :, None]
-                # head_spec: under TP the arena shards over heads
-                # ('model') exactly like training attention.
-                ctx = _softmax_attention(q, head_spec(keys),
-                                         head_spec(vals),
-                                         self.softmax_dtype, self.dtype,
-                                         bool_mask=live[:, None])
-                return dense_out(ctx.reshape(*x.shape[:-1], d))
+                with device_span("kv_gather"):
+                    tbl = jnp.clip(table, 0, NB - 1)
+                    keys = ck.value[tbl].reshape(S, -1, h, hd)
+                    vals = cv.value[tbl].reshape(S, -1, h, hd)
+                    if self.kv_quant:
+                        # Scale-fused dequant of the gathered logical view:
+                        # attention (softmax included) runs at full
+                        # precision on the dequantized values.
+                        keys = kv_quant.dequantize_gather(
+                            keys, cks.value[tbl].reshape(S, -1), self.dtype)
+                        vals = kv_quant.dequantize_gather(
+                            vals, cvs.value[tbl].reshape(S, -1), self.dtype)
+                with device_span("paged_attention"):
+                    L = keys.shape[1]
+                    live = jnp.arange(L)[None, None, :] <= pos[:, :, None]
+                    # head_spec: under TP the arena shards over heads
+                    # ('model') exactly like training attention.
+                    ctx = _softmax_attention(q, head_spec(keys),
+                                             head_spec(vals),
+                                             self.softmax_dtype, self.dtype,
+                                             bool_mask=live[:, None])
+                    return dense_out(ctx.reshape(*x.shape[:-1], d))
             if cache_ready:      # per-token decode step (cache exists)
                 if x.shape[1] != 1:
                     raise ValueError("decode takes ONE token per call "
@@ -620,18 +625,19 @@ class BertForMaskedLM(nn.Module):
         # is the parallel LM head (vocab-sharded logits — the CE's logsumexp
         # reduction over vocab becomes a psum, GSPMD's lowering of
         # Megatron's vocab_parallel_cross_entropy).
-        x = nn.Dense(self.hidden_size, dtype=self.dtype,
-                     param_dtype=self.param_dtype, name="mlm_dense")(x)
-        x = nn.gelu(x, approximate=False)
-        x = FusedLayerNorm(dtype=ln_io, name="mlm_ln")(
-            x.astype(ln_io)).astype(self.dtype)
-        logits = word_emb.attend(x)
-        bias_init = nn.initializers.zeros
-        if self.tensor_parallel:
-            bias_init = nn.with_partitioning(bias_init, ("model",))
-        logits = logits + self.param("mlm_bias", bias_init,
-                                     (self.vocab_size,), jnp.float32)
-        logits = logits.astype(jnp.float32)
+        with device_span("mlm_head"):
+            x = nn.Dense(self.hidden_size, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="mlm_dense")(x)
+            x = nn.gelu(x, approximate=False)
+            x = FusedLayerNorm(dtype=ln_io, name="mlm_ln")(
+                x.astype(ln_io)).astype(self.dtype)
+            logits = word_emb.attend(x)
+            bias_init = nn.initializers.zeros
+            if self.tensor_parallel:
+                bias_init = nn.with_partitioning(bias_init, ("model",))
+            logits = logits + self.param("mlm_bias", bias_init,
+                                         (self.vocab_size,), jnp.float32)
+            logits = logits.astype(jnp.float32)
         if self.moe_experts:
             return logits, aux_total / self.num_layers
         return logits

@@ -1,21 +1,26 @@
-"""Host-side spans that mirror the device-side ``jax.named_scope`` phase
-labels, so host timelines and xprof traces share one naming convention.
+"""One span vocabulary for host timelines and the device trace.
 
-Two kinds of region exist in this stack and they need different tools:
+Three kinds of region exist in this stack and they need different tools:
 
 - **Traced (device) regions** — code under ``jit``.  Host timing there
   is meaningless (it measures tracing, once); the right annotation is
-  ``jax.named_scope``, which lands the label in the xprof timeline.
-  :func:`device_span` is that, re-exported so the engine's phase names
-  come from the single :data:`PHASES` table below.
+  ``jax.named_scope``, which lands the label in every operation's
+  ``op_name`` and so in the profiler's device trace.
+  :func:`device_span` is that, re-exported so the names come from the
+  single :data:`PHASES` table below.
 - **Host regions** — the train loop's data fetch, step dispatch,
   checkpoint IO.  :func:`span` times those with ``perf_counter``, nests,
   and (optionally) feeds a ``span.<name>`` histogram in a
   :class:`~apex_example_tpu.obs.metrics.MetricsRegistry`.
+- **The contiguous phases of one loop iteration** — the serve tick.
+  :class:`Phases` reads each boundary once and gives every consumer the
+  same readings; each phase is also a ``jax.profiler.TraceAnnotation``,
+  so that an open profiler session shows what the host was doing on the
+  same clock as the device's operations.
 
 Using the same names on both sides ("fwd_bwd" as a host span around a
-block that is "fwd_bwd" in the device trace) is the point: a future perf
-PR reads one vocabulary across JSONL telemetry and xprof.
+block that is "fwd_bwd" in the device trace) is the point: a perf PR
+reads one vocabulary across JSONL telemetry and the profiler's trace.
 """
 
 from __future__ import annotations
@@ -29,9 +34,12 @@ import jax
 
 from apex_example_tpu.obs import trace as trace_lib
 
-# Canonical phase labels.  The device-side entries are emitted by
-# engine.make_train_step via device_span; the host-side entries by the
-# train loop.  Keep README's "Observability" section in sync.
+# Canonical labels.  The host-side entries are emitted by the train
+# loop through span(); the device-side ones through device_span by
+# engine.make_train_step, the loss functions of workloads.py, the models'
+# heads, the paged branch of models/bert.py and serve/engine._slot_step.
+# The serve tick's host phases are tickprof.ENGINE_PHASES (a jax-free
+# table).  Keep README's "Span naming" paragraph in sync.
 PHASES = (
     "data",             # host: batch synthesis / prefetcher fetch
     "step",             # host: step dispatch (+ fetch when telemetry is on)
@@ -39,6 +47,14 @@ PHASES = (
     "grad_allreduce",   # device: DDP gradient reduction
     "unscale_check",    # device: unscale + finite check
     "optimizer",        # device: fused optimizer apply
+    "mlm_head",         # device: the LM head (transform + vocabulary logits)
+    "loss",             # device: the loss on the model's outputs
+    "dequant_weights",  # device: serve step, low-bit weights to compute dtype
+    "kv_cow",           # device: paged decode, copy-on-write block copies
+    "kv_write",         # device: paged decode, K/V scatter through the table
+    "kv_gather",        # device: paged decode, each slot's K/V view gathered
+    "paged_attention",  # device: paged decode, attention + output projection
+    "sample",           # device: serve step, last-lane take + sampling
 )
 
 device_span = jax.named_scope
@@ -72,9 +88,6 @@ class Span:
     def dur_s(self) -> float:
         return (self.dur_ms or 0.0) / 1e3
 
-    def path(self) -> str:
-        return self.name
-
 
 def _stack() -> List[Span]:
     if not hasattr(_tls, "stack"):
@@ -88,14 +101,12 @@ def current_span() -> Optional[Span]:
 
 
 @contextmanager
-def span(name: str, registry=None, device: bool = False):
+def span(name: str, registry=None):
     """Time a host region.
 
     Nested spans attach to their parent (``Span.children``); completed
     spans feed ``span.<dotted.path>`` histograms in ``registry`` (or the
-    default registry).  ``device=True`` additionally enters
-    ``jax.named_scope(name)``, for host regions that also dispatch traced
-    work — the xprof timeline then carries the same label.
+    default registry).
 
     Yields the :class:`Span`; read ``sp.dur_ms`` after the ``with`` for
     the measured duration.
@@ -115,14 +126,9 @@ def span(name: str, registry=None, device: bool = False):
     if tracer is not None:
         sp.span_id = tracer.next_id()
     stack.append(sp)
-    scope = jax.named_scope(name) if device else None
-    if scope is not None:
-        scope.__enter__()
     try:
         yield sp
     finally:
-        if scope is not None:
-            scope.__exit__(None, None, None)
         sp.dur_ms = (time.perf_counter() - sp.t0) * 1e3
         stack.pop()
         reg = registry if registry is not None else _default_registry
@@ -135,3 +141,72 @@ def span(name: str, registry=None, device: bool = False):
                 tid=threading.current_thread().name,
                 span_id=sp.span_id,
                 parent_id=parent.span_id if parent is not None else None)
+
+
+class Phases:
+    """The contiguous phases of one loop iteration, each boundary read
+    once.
+
+    Opening reads ``perf_counter`` and starts ``first``; ``enter(name)``
+    reads it again, ends the running phase there and starts ``name``;
+    ``close()`` ends the last.  ``names[i]`` ran from ``at[i]`` to
+    ``at[i + 1]``, so whatever wants a tick's timing (the Tracer's X
+    events, ``TickProfiler.observe_tick``, a token's stamp) takes it from
+    these readings and the parts telescope to the whole.
+
+    With ``annotate`` the iteration (``root``, carrying ``meta``) and each
+    phase are also ``jax.profiler.TraceAnnotation`` events: while a
+    profiler session is open they land in its ``.xplane.pb`` as host
+    events on the clock of the device's lines; with none open each is an
+    inactive check (under a microsecond).  A context manager, so that an
+    exception leaves no annotation open.
+    """
+
+    __slots__ = ("names", "at", "_root", "_open")
+
+    def __init__(self, root: str, first: str, annotate: bool = True,
+                 **meta):
+        self._root = self._open = None
+        if annotate:
+            self._root = jax.profiler.TraceAnnotation(root, **meta)
+            self._root.__enter__()
+            self._open = jax.profiler.TraceAnnotation(first)
+            self._open.__enter__()
+        self.names = [first]
+        self.at = [time.perf_counter()]
+
+    def enter(self, name: str) -> float:
+        """End the running phase and start ``name``; the boundary."""
+        now = time.perf_counter()
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = jax.profiler.TraceAnnotation(name)
+            self._open.__enter__()
+        self.names.append(name)
+        self.at.append(now)
+        return now
+
+    def set_meta(self, **meta) -> None:
+        """Further metadata for the iteration's annotation."""
+        if self._root is not None:
+            self._root.set_metadata(**meta)
+
+    def close(self) -> float:
+        """End the last phase and the iteration; the end (idempotent)."""
+        if len(self.at) == len(self.names):
+            self.at.append(time.perf_counter())
+            if self._root is not None:
+                self._open.__exit__(None, None, None)
+                self._root.__exit__(None, None, None)
+        return self.at[-1]
+
+    def ms(self, *names: str) -> float:
+        """Milliseconds spent in ``names`` (closed phases)."""
+        return sum(self.at[i + 1] - self.at[i]
+                   for i, n in enumerate(self.names) if n in names) * 1e3
+
+    def __enter__(self) -> "Phases":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()

@@ -13,19 +13,18 @@ A serve tick (serve/engine.py ``step``) decomposes into::
     dispatch_enqueue  host marshalling + handing the step to the
                       runtime (up to the point the compiled call
                       returns its unresolved outputs)
-    device_wait       an explicit ``jax.block_until_ready`` boundary
-                      the engine inserts ONLY when armed — the first
-                      time enqueue cost and device execution are
-                      separable (on CPU jax dispatch is synchronous,
-                      so device_wait reads ~0 and the device time
-                      hides in dispatch_enqueue; on a real TPU the
-                      split is the whole point — see README)
+    device_wait       the host sync on the sampled tokens
+                      (``np.asarray``): the device's run plus the
+                      device-to-host copy (on CPU jax dispatch is
+                      synchronous, so device_wait reads ~0 and the
+                      device time hides in dispatch_enqueue; on a real
+                      TPU the split is the whole point — see README)
     harvest           per-slot token handling, eviction, completion
     spool_io          handoff spool writes inside harvest (measured
                       around ``handoff_sink`` and subtracted from
                       harvest so disagg IO is not mistaken for
                       scheduler cost)
-    telemetry         gauge emission, SLO fold, tracer bookkeeping
+    telemetry         gauge emission, SLO fold
 
 and a train step (train.py main loop) into::
 
@@ -94,6 +93,21 @@ except ImportError:                      # file-path load: no package
 
 SERVE_PHASES = ("admit", "dispatch_enqueue", "device_wait", "harvest",
                 "spool_io", "telemetry")
+# The serve tick's phases as serve/engine.py reads them: contiguous, in
+# this order, each boundary taken once (obs/spans.py ``Phases``).  Under
+# these names they are ``jax.profiler.TraceAnnotation`` host events on
+# the device trace's clock, children of one ENGINE_TICK event; the value
+# is the ``tick_profile`` phase each folds into (``spool_io`` is cut out
+# of engine.harvest by its own pair of readings round the handoff sink).
+ENGINE_TICK = "engine.tick"
+ENGINE_PHASES = {
+    "engine.admit": "admit",              # mature/expire/shed/evict/admit
+    "engine.marshal": "dispatch_enqueue",  # per-slot arrays, rng, puts
+    "engine.enqueue": "dispatch_enqueue",  # the compiled call's return
+    "engine.sync": "device_wait",         # device run + device-to-host
+    "engine.harvest": "harvest",          # per-slot loop, finishes
+    "engine.gauges": "telemetry",         # histograms, gauges, SLO fold
+}
 TRAIN_PHASES = ("data_wait", "dispatch", "device", "checkpoint",
                 "telemetry")
 

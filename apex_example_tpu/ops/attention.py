@@ -335,6 +335,7 @@ def _attn_fwd_pallas(q, k, v, bias, causal, scale, h):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
+        name="flash_attention_fwd",
         interpret=_cfg.INTERPRET,
     )(*operands)
     return o, lse
@@ -384,6 +385,7 @@ def _attn_bwd_pallas(q, k, v, bias, causal, scale, h, o, lse, do,
                    sds((bh, sk, d), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
+        name="flash_attention_bwd_dkv",
         interpret=_cfg.INTERPRET,
     )(*operands, do, lse, dl)
 
@@ -398,6 +400,7 @@ def _attn_bwd_pallas(q, k, v, bias, causal, scale, h, o, lse, do,
         out_specs=[mat(bq, lambda b, i, j: (b, i, 0))],
         out_shape=[sds((bh, sq, d), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=_cfg.INTERPRET,
     )(*operands, do, lse, dl)
     return dq, dk, dv

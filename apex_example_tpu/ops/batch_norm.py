@@ -121,6 +121,7 @@ def bn_stats(x2: jnp.ndarray, c: jnp.ndarray
                                memory_space=pltpu.VMEM), vec()],
         out_specs=[vec(), vec()],
         out_shape=[sds((C,), jnp.float32, x2, c)] * 2,
+        name="bn_stats",
         interpret=_interpret(),
     )(x2, c)
 
@@ -148,6 +149,7 @@ def bn_bwd_sums(x2: jnp.ndarray, dy2: jnp.ndarray, mean: jnp.ndarray,
         in_specs=[mat(), mat(), vec(), vec()],
         out_specs=[vec(), vec()],
         out_shape=[sds((C,), jnp.float32, x2, dy2)] * 2,
+        name="bn_bwd_sums",
         interpret=_interpret(),
     )(x2, dy2, mean, inv)
 

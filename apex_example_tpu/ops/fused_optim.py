@@ -108,6 +108,7 @@ def adam_update_leaf(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay,
                    sds(p2.shape, m.dtype, p2, g2, m2, v2),
                    sds(p2.shape, v.dtype, p2, g2, m2, v2)],
         input_output_aliases={0: 0, 2: 1, 3: 2},
+        name="adam",
         interpret=_interpret(),
     )(p2, g2, m2, v2, scal)
 
@@ -205,6 +206,7 @@ def lamb_stage1_leaf(p, g, m, v, *, beta1, beta2, eps, weight_decay,
                    sds(p2.shape, v.dtype, p2, g2, m2, v2),
                    sds((2,), jnp.float32, p2, g2, m2, v2)],
         input_output_aliases={2: 1, 3: 2},
+        name="lamb_stage1",
         interpret=_interpret(),
     )(p2, g2, m2, v2, scal)
 
@@ -247,6 +249,7 @@ def lamb_stage2_leaf(p, update, scaled_lr):
         out_specs=bspec(),
         out_shape=sds(p2.shape, p.dtype, p2, u2),
         input_output_aliases={0: 0},
+        name="lamb_stage2",
         interpret=_interpret(),
     )(p2, u2, jnp.asarray(scaled_lr, jnp.float32).reshape(1))
     return _unpad(po, n, p)
@@ -323,6 +326,7 @@ def novograd_update_leaf(p, g, m, *, inv_denom, lr_c1, beta1, weight_decay,
         out_shape=[sds(p2.shape, p.dtype, p2, g2, m2),
                    sds(p2.shape, m.dtype, p2, g2, m2)],
         input_output_aliases={0: 0, 2: 1},
+        name="novograd",
         interpret=_interpret(),
     )(p2, g2, m2, scal)
     return _unpad(po, n, p), _unpad(mo, n, m)
@@ -365,6 +369,7 @@ def sgd_update_leaf(p, g, buf, *, lr, momentum, weight_decay, dampening=0.0,
         out_shape=[sds(p2.shape, p.dtype, p2, g2, b2),
                    sds(p2.shape, buf.dtype, p2, g2, b2)],
         input_output_aliases={0: 0, 2: 1},
+        name="sgd",
         interpret=_interpret(),
     )(p2, g2, b2, scal)
     return _unpad(po, n, p), _unpad(bo, n, buf)
@@ -424,6 +429,7 @@ def adagrad_update_leaf(p, g, h, *, lr, eps, weight_decay,
         out_shape=[sds(p2.shape, p.dtype, p2, g2, h2),
                    sds(p2.shape, h.dtype, p2, g2, h2)],
         input_output_aliases={0: 0, 2: 1},
+        name="adagrad",
         interpret=_interpret(),
     )(p2, g2, h2, scal)
     return _unpad(po, n, p), _unpad(ho, n, h)

@@ -149,6 +149,7 @@ def _norm_fwd_pallas(x2d, gamma, beta, eps):
         out_specs=[mat()] + [stat()] * n_stats,
         out_shape=([sds((np_, h), x2d.dtype, x2d)]
                    + [sds((np_, 1), jnp.float32, x2d)] * n_stats),
+        name=("layer_norm_fwd" if with_mean else "rms_norm_fwd"),
         interpret=_cfg.INTERPRET,
     )(*([x2d, gamma, beta] if with_mean else [x2d, gamma]))
     if with_mean:
@@ -198,6 +199,7 @@ def _norm_bwd_pallas(x2d, gamma, mean, rstd, dy2d):
         out_specs=[mat()] + [vec()] * n_grads,
         out_shape=([sds((np_, h), x2d.dtype, x2d, dy2d)]
                    + [sds((h,), jnp.float32, x2d, dy2d, gamma)] * n_grads),
+        name=("layer_norm_bwd" if with_mean else "rms_norm_bwd"),
         interpret=_cfg.INTERPRET,
     )(x2d, gamma, *stats2, dy2d)
     dx = outs[0][:n] if pad else outs[0]

@@ -117,6 +117,7 @@ def _scale_leaf_pallas(x: jnp.ndarray, scale: jnp.ndarray):
             sds(x2d.shape, x.dtype, x2d),
             sds((1, 1), jnp.int32, x2d),
         ],
+        name="scale",
         interpret=_interpret(),
     )(x2d, scale.astype(jnp.float32).reshape(1))
     return _unpad(y, n, x), bad[0, 0] > 0
@@ -180,6 +181,7 @@ def _axpby_leaf_pallas(x, y, a, b):
         out_specs=pl.BlockSpec((block, _LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=sds(x2d.shape, y.dtype, x2d, y2d),
+        name="axpby",
         interpret=_interpret(),
     )(x2d, y2d, ab)
     return _unpad(out, n, x)
@@ -227,6 +229,7 @@ def _sqsum_leaf_pallas(x) -> jnp.ndarray:
                                memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=sds((1, 1), jnp.float32, x2d),
+        name="l2norm",
         interpret=_interpret(),
     )(x2d)
     return acc[0, 0]

@@ -87,6 +87,8 @@ from apex_example_tpu.obs import costmodel as costmodel_lib
 from apex_example_tpu.obs import trace as trace_lib
 from apex_example_tpu.obs.metrics import Histogram, nearest_rank
 from apex_example_tpu.obs.slo import SloTracker
+from apex_example_tpu.obs.spans import Phases, device_span
+from apex_example_tpu.obs.tickprof import ENGINE_PHASES, ENGINE_TICK
 from apex_example_tpu.resilience.faults import FaultInjected
 from apex_example_tpu.serve.queue import (STATUSES, Completion, Request,
                                           RequestQueue)
@@ -138,17 +140,19 @@ def _slot_step(dec, dequant_weights: bool = False):
              cow_dst, rng, temperature, top_k):
         if dequant_weights:
             from apex_example_tpu.quant import weights as _qw
-            params = _qw.dequantize_tree(params)
+            with device_span("dequant_weights"):
+                params = _qw.dequantize_tree(params)
         paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
                  "cow_src": cow_src, "cow_dst": cow_dst}
         logits, mut = dec.apply({"params": params, "cache": cache}, tok,
                                 train=False, paged=paged,
                                 mutable=["cache"])
-        idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
-        last = jnp.take_along_axis(logits, idx[:, None, None],
-                                   axis=1)[:, 0]
-        nxt = sample_tokens(rng, last, temperature, top_k)
-        finite = jnp.all(jnp.isfinite(last), axis=-1)
+        with device_span("sample"):
+            idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
+            last = jnp.take_along_axis(logits, idx[:, None, None],
+                                       axis=1)[:, 0]
+            nxt = sample_tokens(rng, last, temperature, top_k)
+            finite = jnp.all(jnp.isfinite(last), axis=-1)
         return mut["cache"], nxt, finite
 
     return step
@@ -182,19 +186,21 @@ def _slot_step_spec(dec, dequant_weights: bool = False):
              cow_dst, rng, temperature, top_k):
         if dequant_weights:
             from apex_example_tpu.quant import weights as _qw
-            params = _qw.dequantize_tree(params)
+            with device_span("dequant_weights"):
+                params = _qw.dequantize_tree(params)
         paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
                  "cow_src": cow_src, "cow_dst": cow_dst}
         logits, mut = dec.apply({"params": params, "cache": cache}, tok,
                                 train=False, paged=paged,
                                 mutable=["cache"])
-        idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
-        last = jnp.take_along_axis(logits, idx[:, None, None],
-                                   axis=1)[:, 0]
-        nxt = sample_tokens(rng, last, temperature, top_k)
-        finite = jnp.all(jnp.isfinite(last), axis=-1)
-        lane_greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        lane_finite = jnp.all(jnp.isfinite(logits), axis=-1)
+        with device_span("sample"):
+            idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
+            last = jnp.take_along_axis(logits, idx[:, None, None],
+                                       axis=1)[:, 0]
+            nxt = sample_tokens(rng, last, temperature, top_k)
+            finite = jnp.all(jnp.isfinite(last), axis=-1)
+            lane_greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            lane_finite = jnp.all(jnp.isfinite(logits), axis=-1)
         return mut["cache"], nxt, finite, lane_greedy, lane_finite
 
     return step
@@ -528,12 +534,9 @@ class ServeEngine:
                 emit=sink.write if sink is not None else None,
                 run_id=run_id)
         # --tick-profile (obs/tickprof.py, ISSUE 17): per-tick phase
-        # decomposition.  Armed, the step inserts ONE extra
-        # block_until_ready at the enqueue/device boundary — a
-        # value-preserving host sync on outputs the tick was about to
-        # block on anyway (np.asarray), so greedy outputs stay
-        # token-identical and NO new program compiles.  Unarmed, the
-        # tick path is unchanged.  Idle-spin accounting (idle_ticks /
+        # decomposition, folded from the boundaries every tick reads
+        # anyway (step(): obs.spans.Phases), so arming it changes no
+        # device work and no value.  Idle-spin accounting (idle_ticks /
         # idle_wait_ms) is always on: it is free.
         self.tickprof = tick_profiler
         self.idle_ticks = 0
@@ -595,12 +598,25 @@ class ServeEngine:
     def step(self) -> bool:
         """One engine tick.  Returns True when a decode step ran (some
         slot was live); False is an idle tick (virtual time still
-        advances, so ``arrival_step`` gates keep maturing)."""
+        advances, so ``arrival_step`` gates keep maturing).
+
+        The tick's phases (tickprof.ENGINE_PHASES) are read once, here
+        and in ``_tick``, and feed the profiler's trace, the Tracer and
+        the TickProfiler alike.  A tick with nothing live and nothing
+        to admit annotates nothing (an idle spin must not flood a
+        trace); one that turned away all it had closes after
+        ``engine.admit`` alone."""
+        busy = bool(self.pool.live or self.queue.ready(self.step_count)
+                    or (self.sched is not None and self.sched.pending()))
+        with Phases(ENGINE_TICK, "engine.admit", annotate=busy,
+                    tick=self.step_count) as ph:
+            return self._tick(ph)
+
+    def _tick(self, ph: Phases) -> bool:
         pool = self.pool
         step = self.step_count
         tick1 = step + 1            # 1-based, for --inject-fault kind@tick
-        now = time.perf_counter()
-        t_tick_start = now          # ``now`` is re-taken post-dispatch
+        now = ph.at[0]              # re-taken once the tokens are fetched
         if not self.draining:
             self.queue.mature(step)
             # Expire BEFORE evaluating the bound: requests already dead
@@ -684,27 +700,10 @@ class ServeEngine:
                 self.fault.maybe_fire(tick1)
             return False
 
+        ph.set_meta(live=len(live))
+        t_admit_end = ph.enter("engine.marshal")
         tracer = self._tracer
-        prof = self.tickprof
-        tick_sid = None
-        t_admit_end = now
-        if tracer is not None or prof is not None:
-            # Admit-phase boundary: taken once, shared by the tracer
-            # span and the profiler's phase fold.
-            t_admit_end = time.perf_counter()
-        if tracer is not None:
-            # The tick span opens retroactively at the tick boundary
-            # (``now``, taken before expire/admit ran) so the admit
-            # phase is inside it; idle ticks emit nothing — a
-            # wall-clock producer's idle spin must not flood the
-            # stream.
-            tick_sid = tracer.begin("tick", tid="engine", ts=now,
-                                    cat="tick",
-                                    args={"tick": step,
-                                          "live": len(live)})
-            tracer.complete("admit", now, t_admit_end - now,
-                            tid="engine", cat="tick",
-                            parent_id=tick_sid)
+        self._spool_ms = 0.0
         # Chunk width: block_size for interleaved/prefill engines, ONE
         # for a decode-role engine — its slots only ever feed a single
         # token per tick (handoffs arrive pre-filled), so its compiled
@@ -746,6 +745,12 @@ class ServeEngine:
             temps[i] = slot.request.temperature
             ks[i] = slot.request.top_k
         self.rng, key = jax.random.split(self.rng)
+        args = (self.params, pool.cache, jnp.asarray(tok),
+                jnp.asarray(pool.table), jnp.asarray(fill),
+                jnp.asarray(n_new), jnp.asarray(cow_src),
+                jnp.asarray(cow_dst), key, jnp.asarray(temps),
+                jnp.asarray(ks))
+        ph.enter("engine.enqueue")
         if self.mesh is not None:
             # Pallas custom calls are opaque to the SPMD partitioner;
             # pin the XLA reference ops for the sharded trace exactly
@@ -753,52 +758,24 @@ class ServeEngine:
             # so this costs nothing after the first call).
             from apex_example_tpu.ops import _config as ops_config
             with ops_config.force_xla():
-                outs = self._step_fn(
-                    self.params, pool.cache, jnp.asarray(tok),
-                    jnp.asarray(pool.table), jnp.asarray(fill),
-                    jnp.asarray(n_new), jnp.asarray(cow_src),
-                    jnp.asarray(cow_dst), key,
-                    jnp.asarray(temps), jnp.asarray(ks))
+                outs = self._step_fn(*args)
         else:
-            outs = self._step_fn(
-                self.params, pool.cache, jnp.asarray(tok),
-                jnp.asarray(pool.table), jnp.asarray(fill),
-                jnp.asarray(n_new), jnp.asarray(cow_src),
-                jnp.asarray(cow_dst), key,
-                jnp.asarray(temps), jnp.asarray(ks))
+            outs = self._step_fn(*args)
+        # The compiled call has returned (enqueue cost paid) but its
+        # outputs may still be computing: what follows is the device's
+        # run and the device-to-host copy.  (On CPU jax dispatch is
+        # synchronous, so the device time hides in engine.enqueue.)
+        ph.enter("engine.sync")
         lane_greedy = lane_finite = None
         if self.speculate:
             pool.cache, nxt, finite, lane_greedy, lane_finite = outs
-        else:
-            pool.cache, nxt, finite = outs
-        t_enqueue_end = t_device_end = 0.0
-        if prof is not None:
-            # The dispatch/device boundary ISSUE 17 exists to draw:
-            # the compiled call has returned (enqueue cost paid) but
-            # its outputs may still be computing.  Blocking HERE — on
-            # values the np.asarray sync below was about to block on
-            # anyway — splits enqueue from device execution without
-            # changing any value or compiling anything new.  (On CPU
-            # jax dispatch is synchronous, so device_wait reads ~0 and
-            # the device time hides in dispatch_enqueue; see README.)
-            t_enqueue_end = time.perf_counter()
-            jax.block_until_ready(outs)
-            t_device_end = time.perf_counter()
-            self._spool_ms = 0.0
-        nxt = np.asarray(nxt)          # the scheduler's host sync
-        finite = np.asarray(finite)
-        if self.speculate:
             lane_greedy = np.asarray(lane_greedy)
             lane_finite = np.asarray(lane_finite)
-        now = time.perf_counter()
-        t_dispatch_end = now
-        if tracer is not None:
-            # Dispatch = host marshal + the compiled step + the host
-            # sync above: what one tick paid for device work.
-            tracer.complete("dispatch", t_admit_end, now - t_admit_end,
-                            tid="engine", cat="tick",
-                            parent_id=tick_sid,
-                            args={"lanes": int(n_new.sum())})
+        else:
+            pool.cache, nxt, finite = outs
+        nxt = np.asarray(nxt)          # the scheduler's host sync
+        finite = np.asarray(finite)
+        now = t_dispatch_end = ph.enter("engine.harvest")
 
         fault = self.fault
         fail_slot = -1
@@ -902,7 +879,7 @@ class ServeEngine:
                 self._handoff_slot(i, now)
         self.compute_steps += 1
         self._occupancy_sum += len(live)
-        t_harvest_end = time.perf_counter() if prof is not None else 0.0
+        ph.enter("engine.gauges")
         # Gauge the tick AFTER harvest: what is RESIDENT at the tick
         # boundary (a finished slot's blocks were just unref'd — the
         # reclamation the dense layout could never express).
@@ -924,32 +901,39 @@ class ServeEngine:
                                   num_slots=self.pool.num_slots,
                                   blocks_live=blocks_live,
                                   kv_bytes_live=kv_live)
-        if tracer is not None:
-            t_end = time.perf_counter()
-            tracer.complete("harvest", t_dispatch_end,
-                            t_end - t_dispatch_end, tid="engine",
-                            cat="tick", parent_id=tick_sid,
-                            args={"live": live_slots,
-                                  "blocks": blocks_live})
-            tracer.end("tick", tid="engine", ts=t_end)
+        t_end = ph.close()
         self.step_count += 1
-        if prof is not None:
-            # Contiguous boundaries telescope: the six phases sum to
-            # the measured wall EXACTLY (modulo float rounding), which
-            # is what perf_ledger's 1% consistency gate verifies.  The
-            # profiler's own record emit happens after t_tick_end and
-            # never pollutes the measurement.
-            t_tick_end = time.perf_counter()
-            spool = self._spool_ms
-            prof.observe_tick(
-                t_tick_start,
-                (t_tick_end - t_tick_start) * 1e3,
-                admit=(t_admit_end - t_tick_start) * 1e3,
-                dispatch_enqueue=(t_enqueue_end - t_admit_end) * 1e3,
-                device_wait=(t_device_end - t_enqueue_end) * 1e3,
-                harvest=(t_harvest_end - t_device_end) * 1e3 - spool,
-                spool_io=spool,
-                telemetry=(t_tick_end - t_harvest_end) * 1e3)
+        # The records of the two host-side consumers, written after the
+        # tick has closed so that they never pollute its measurement.
+        if tracer is not None:
+            # One "tick" B/E pair on the engine row with its admit /
+            # dispatch (marshal + compiled step + host sync: what the
+            # tick paid for device work) / harvest children; idle ticks
+            # emit nothing — a wall-clock producer's idle spin must not
+            # flood the stream.
+            sid = tracer.begin("tick", tid="engine", ts=ph.at[0],
+                               cat="tick",
+                               args={"tick": step, "live": len(live)})
+            for name, t0, t1, meta in (
+                    ("admit", ph.at[0], t_admit_end, None),
+                    ("dispatch", t_admit_end, t_dispatch_end,
+                     {"lanes": int(n_new.sum())}),
+                    ("harvest", t_dispatch_end, t_end,
+                     {"live": live_slots, "blocks": blocks_live})):
+                tracer.complete(name, t0, t1 - t0, tid="engine",
+                                cat="tick", parent_id=sid, args=meta)
+            tracer.end("tick", tid="engine", ts=t_end)
+        if self.tickprof is not None:
+            # Contiguous boundaries telescope: the phases sum to the
+            # wall EXACTLY (modulo float rounding), which is what
+            # perf_ledger's 1% consistency gate verifies.
+            folded = {p: 0.0 for p in ENGINE_PHASES.values()}
+            for name, into in ENGINE_PHASES.items():
+                folded[into] += ph.ms(name)
+            folded["harvest"] -= self._spool_ms
+            self.tickprof.observe_tick(
+                ph.at[0], (t_end - ph.at[0]) * 1e3,
+                spool_io=self._spool_ms, **folded)
         if fault is not None:
             # crash/sigterm/hang fire AFTER the tick's harvest (matching
             # the training loops: forensics hold the last good tick).

@@ -338,6 +338,12 @@ class RequestQueue:
                 return None
             return self._q.popleft()
 
+    def ready(self, step: int) -> bool:
+        """Would ``pop(step)`` hand out a request?  (O(1): the head gates
+        the rest.)"""
+        with self._lock:
+            return bool(self._q) and self._q[0].arrived(step)
+
     def push_front(self, request: Request) -> None:
         """Hand a popped request back to the HEAD of the queue — the
         engine's deterministic out-of-blocks queueing (head-of-line:

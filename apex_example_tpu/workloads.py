@@ -19,6 +19,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from apex_example_tpu import amp as amp_lib
 from apex_example_tpu.amp.policy import Policy
 from apex_example_tpu.engine import TrainState, _wrap_optimizer
+from apex_example_tpu.obs.spans import device_span
 from apex_example_tpu.ops.xentropy import softmax_cross_entropy
 from apex_example_tpu.parallel.distributed import DDPConfig, allreduce_grads
 from apex_example_tpu.parallel.mesh import DATA_AXIS
@@ -32,14 +33,16 @@ def mlm_loss(logits: jnp.ndarray, target: Tuple[jnp.ndarray, jnp.ndarray]
     at vocab 30k that residual is the largest activation in the step
     (ops/xentropy.py, the contrib-xentropy analog)."""
     labels, weights = target
-    ce = softmax_cross_entropy(logits, labels)
-    denom = jnp.maximum(weights.sum(), 1.0)
-    return (ce * weights).sum() / denom
+    with device_span("loss"):
+        ce = softmax_cross_entropy(logits, labels)
+        denom = jnp.maximum(weights.sum(), 1.0)
+        return (ce * weights).sum() / denom
 
 
 def lm_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Next-token CE, mean over all positions (Transformer-XL objective)."""
-    return softmax_cross_entropy(logits, labels).mean()
+    with device_span("loss"):
+        return softmax_cross_entropy(logits, labels).mean()
 
 
 def _global_lm_loss(logits, labels, axes):
@@ -47,10 +50,11 @@ def _global_lm_loss(logits, labels, axes):
     psum-ed count, so shards (whose local means would misweight) combine
     exactly to lm_loss on the full batch.  One definition shared by the CP
     train/eval and MoE 'lm' train/eval steps."""
-    ce = softmax_cross_entropy(logits, labels)
-    num = jax.lax.psum(ce.sum(), axes)
-    den = jax.lax.psum(jnp.asarray(ce.size, jnp.float32), axes)
-    return num / den
+    with device_span("loss"):
+        ce = softmax_cross_entropy(logits, labels)
+        num = jax.lax.psum(ce.sum(), axes)
+        den = jax.lax.psum(jnp.asarray(ce.size, jnp.float32), axes)
+        return num / den
 
 
 def make_txl_train_step(model, optimizer, policy: Policy,
@@ -244,10 +248,11 @@ def make_bert_cp_train_step(mesh: Mesh, model, optimizer, policy: Policy,
     def cp_mlm_loss(logits, target):
         labels, weights = target
         axes = (DATA_AXIS, CONTEXT_AXIS)
-        ce = softmax_cross_entropy(logits, labels)
-        num = jax.lax.psum((ce * weights).sum(), axes)
-        den = jnp.maximum(jax.lax.psum(weights.sum(), axes), 1.0)
-        return num / den
+        with device_span("loss"):
+            ce = softmax_cross_entropy(logits, labels)
+            num = jax.lax.psum((ce * weights).sum(), axes)
+            den = jnp.maximum(jax.lax.psum(weights.sum(), axes), 1.0)
+            return num / den
 
     # grad_accum=K: the engine's microbatch scan splits the LOCAL batch dim;
     # each microbatch's loss is normalized by ITS OWN global (psum-ed)
@@ -640,9 +645,11 @@ def make_bert_moe_train_step(mesh: Mesh, model, optimizer, policy: Policy,
             aux = jax.lax.pmean(aux, CONTEXT_AXIS)
         if objective == "mlm":
             labels, weights = target
-            ce = softmax_cross_entropy(logits, labels)
-            num = jax.lax.psum((ce * weights).sum(), loss_axes)
-            den = jnp.maximum(jax.lax.psum(weights.sum(), loss_axes), 1.0)
+            with device_span("loss"):
+                ce = softmax_cross_entropy(logits, labels)
+                num = jax.lax.psum((ce * weights).sum(), loss_axes)
+                den = jnp.maximum(jax.lax.psum(weights.sum(), loss_axes),
+                                  1.0)
             return (num / den
                     + jnp.asarray(aux_weight, jnp.float32) * aux)
         # next-token CE (MoE GPT)
