@@ -120,7 +120,8 @@ class BertSelfAttention(nn.Module):
     decode: bool = False
     # Block-paged slot decode (with decode=True): instead of a dense
     # [B, max_len, H, D] page per row, K/V live in one shared arena of
-    # shape [kv_num_blocks, kv_block_size, H, D] per layer.  Each batch
+    # shape [kv_num_blocks, kv_block_size, H*D] per layer (heads and
+    # head dim merged: see the paged branch of __call__).  Each batch
     # row is an independent request slot whose logical sequence is
     # scattered across arena blocks named by a per-slot block table —
     # the ``paged`` call argument carries the table plus per-slot fill
@@ -189,11 +190,21 @@ class BertSelfAttention(nn.Module):
             from jax import lax as _lax
             cache_ready = self.has_variable("cache", "cached_key")
             if self.slot_decode:
-                # Block-paged arena: one [NB, BS, H, D] K and V buffer
+                # Block-paged arena: one [NB, BS, H*D] K and V buffer
                 # per layer, shared by every slot through per-slot block
                 # tables.  Allocation/refcounts/COW policy are host-side
                 # (serve/slots.py); the compiled step only executes the
-                # table the host hands it.
+                # table the host hands it.  Heads and head dim are ONE
+                # stored dimension so that the COW block copy, the
+                # per-token write (through the flat [NB*BS, H*D] view,
+                # a bitcast of the same tiles) and the block gather
+                # all index the leading dimension of one tiled layout.
+                # With separate [.., H, D] dimensions each of the three
+                # gets a tiling of its own on the TPU and XLA converts
+                # the whole arena between every pair.  One layout plus
+                # a donated cache (serve/engine.py) lets the step
+                # update the arena in place; [S, L, H, D] exists only
+                # on the gathered view (tests/test_arena_inplace.py).
                 NB, BS = self.kv_num_blocks, self.kv_block_size
                 if NB < 1 or BS < 1:
                     raise ValueError(
@@ -202,9 +213,9 @@ class BertSelfAttention(nn.Module):
                         f"(got {NB}/{BS})")
                 kv_store = jnp.int8 if self.kv_quant else k.dtype
                 ck = self.variable("cache", "cached_key", jnp.zeros,
-                                   (NB, BS, h, hd), kv_store)
+                                   (NB, BS, d), kv_store)
                 cv = self.variable("cache", "cached_value", jnp.zeros,
-                                   (NB, BS, h, hd), kv_store)
+                                   (NB, BS, d), kv_store)
                 if self.kv_quant:
                     from apex_example_tpu.quant import kv as kv_quant
                     cks = self.variable("cache", "cached_key_scale",
@@ -234,15 +245,16 @@ class BertSelfAttention(nn.Module):
                 NB, BS = self.kv_num_blocks, self.kv_block_size
                 S, C = x.shape[0], x.shape[1]
                 if self.tensor_parallel:
-                    # Under TP the [NB, BS, h, hd] arenas shard over
+                    # Under TP the [NB, BS, h*hd] arenas shard over
                     # heads on 'model' exactly like the dense decode
-                    # cache; re-constraining after every in-place
-                    # update keeps GSPMD from gathering the arena
-                    # through the COW/scatter chain (the block tables,
-                    # fills and scale tables stay replicated — they
-                    # are host policy, not sharded state).
-                    arena = lambda t: constrain(t, None, None, "model",
-                                                None)
+                    # cache (h is the outer factor of the merged
+                    # dimension, so a shard holds whole heads);
+                    # re-constraining after every in-place update
+                    # keeps GSPMD from gathering the arena through
+                    # the COW/scatter chain (the block tables, fills
+                    # and scale tables stay replicated — they are
+                    # host policy, not sharded state).
+                    arena = lambda t: constrain(t, None, None, "model")
                 else:
                     arena = lambda t: t
                 table = paged["block_table"]          # [S, max_blocks]
@@ -295,13 +307,13 @@ class BertSelfAttention(nn.Module):
                             v_sc.reshape(S * C),
                             mode="drop").reshape(NB, BS)
                     ck.value = arena(
-                        ck.value.reshape(NB * BS, h, hd).at[flat].set(
-                            k.reshape(S * C, h, hd),
-                            mode="drop").reshape(NB, BS, h, hd))
+                        ck.value.reshape(NB * BS, d).at[flat].set(
+                            k.reshape(S * C, d),
+                            mode="drop").reshape(NB, BS, d))
                     cv.value = arena(
-                        cv.value.reshape(NB * BS, h, hd).at[flat].set(
-                            v.reshape(S * C, h, hd),
-                            mode="drop").reshape(NB, BS, h, hd))
+                        cv.value.reshape(NB * BS, d).at[flat].set(
+                            v.reshape(S * C, d),
+                            mode="drop").reshape(NB, BS, d))
                 # 3. Gather each slot's logical K/V view back out of the
                 # arena ([S, max_blocks*BS, H, D], logical order) and
                 # attend under the per-slot causal live mask: query j

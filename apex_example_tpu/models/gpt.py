@@ -64,7 +64,7 @@ class GPTForCausalLM(nn.Module):
     # token at a time with mutable=["cache"].
     decode: bool = False
     # Block-paged slot decode (with decode=True): K/V live in one
-    # [kv_num_blocks, kv_block_size, H, D] arena per layer, addressed
+    # [kv_num_blocks, kv_block_size, H*D] arena per layer, addressed
     # through per-slot block tables, and there is NO device-side index
     # state at all — the host (serve/slots.py BlockPool) owns fill
     # levels, allocation, refcounts and copy-on-write, and passes the

@@ -2,10 +2,11 @@
 
 The block arena (serve/slots.py geometry, models/bert.py execution)
 stores int8 K/V with BLOCK-RESIDENT scales: per layer, alongside each
-``[NB, BS, H, D]`` int8 arena sits a ``[NB, BS]`` bf16 scale table —
+``[NB, BS, H*D]`` int8 arena sits a ``[NB, BS]`` bf16 scale table —
 one symmetric max-abs scale per cached token (the [H, D] vector a
-block row holds).  Scales live AT block granularity in the arena, so
-every block operation carries them for free:
+block row holds, stored as one merged dimension).  Scales live AT
+block granularity in the arena, so every block operation carries
+them for free:
 
 - the tick's scatter writes ``quantize_write``'s int8 rows and their
   scales through the SAME flat block-table indices,

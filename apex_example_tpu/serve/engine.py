@@ -122,7 +122,12 @@ def _slot_step(dec, dequant_weights: bool = False):
     are real per slot, and sampling reads the logits AFTER each slot's
     last real token.  COW copies, the block-table K/V scatter and the
     gathered-attention live mask all run inside this one program
-    (models/bert.py).  Besides the sampled tokens it returns a per-slot
+    (models/bert.py).  ``cache`` is DONATED: the arena leaves keep one
+    physical layout from argument to result, so the program updates
+    them in place and the leaves passed in are deleted by the call —
+    the caller must rebind its cache from the first output (ServeEngine
+    does, straight after the call) and hold the old leaves nowhere
+    else.  Besides the sampled tokens it returns a per-slot
     logits-finite mask: argmax/categorical over NaN logits yield an
     IN-RANGE index, so a token-range check alone can never see real NaN
     fallout — the finiteness of the logits themselves is the signal,
@@ -135,7 +140,7 @@ def _slot_step(dec, dequant_weights: bool = False):
     matmul.  Part of the lru_cache key: arming quantization builds ONE
     new program; re-running either variant reuses its compile."""
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, cache, tok, block_table, fill, n_new, cow_src,
              cow_dst, rng, temperature, top_k):
         if dequant_weights:
@@ -162,7 +167,8 @@ def _slot_step(dec, dequant_weights: bool = False):
 def _slot_step_spec(dec, dequant_weights: bool = False):
     """The speculative variant of _slot_step (ISSUE 18): identical
     multi-lane dispatch — [SLOTS, C] tokens, per-slot n_new lane counts,
-    COW + scatter + live mask all inside the one program — plus two
+    COW + scatter + live mask all inside the one program, the cache
+    donated and updated in place — plus two
     extra outputs the accept/reject harvest needs host-side:
 
       * ``lane_greedy`` [SLOTS, C]: argmax over every lane's logits.
@@ -181,7 +187,7 @@ def _slot_step_spec(dec, dequant_weights: bool = False):
     config, dequant flag): arming --speculate K builds exactly ONE new
     program for the [SLOTS, max(BS, K+1)] geometry."""
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, cache, tok, block_table, fill, n_new, cow_src,
              cow_dst, rng, temperature, top_k):
         if dequant_weights:

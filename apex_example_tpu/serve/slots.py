@@ -4,7 +4,7 @@ The dense layout this replaces pinned a [SLOTS, max_len, H, D] page per
 slot, so HBM cost scaled with ``max_len`` regardless of request length
 (PR 6's gauges measured ~92% ``kv_waste_pct`` on the smoke workload).
 Here every layer owns ONE shared arena of shape
-``[num_blocks, block_size, H, D]`` and a request maps only the blocks
+``[num_blocks, block_size, H*D]`` and a request maps only the blocks
 its sequence actually touches, through a per-slot block table
 (``[SLOTS, max_blocks]`` int32) the attention layers gather through
 inside the one compiled decode step (models/bert.py).  Geometry stays
@@ -80,10 +80,12 @@ def _fused_block_scatter(shapes):
     """ONE jitted scatter writing a handoff payload into every arena
     leaf in a single dispatch (cached per geometry — ``shapes`` is the
     arena leaf shape tuple, so every admission at one geometry reuses
-    one executable).  Out-of-range pad lanes drop."""
+    one executable).  Out-of-range pad lanes drop.  The leaves are
+    DONATED (an admission writes a few blocks in place, it does not
+    copy every arena): the caller rebinds its cache from the result."""
     del shapes                        # cache key only; shapes ride args
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0,))
     def scatter(leaves, idx, rows):
         return tuple(l.at[idx].set(r, mode="drop")
                      for l, r in zip(leaves, rows))
@@ -385,12 +387,14 @@ class BlockPool:
 
     def shard(self, mesh) -> None:
         """TP-shard the arenas over the mesh's ``model`` axis: every
-        [NB, BS, H, D] payload leaf is placed head-sharded (the same
-        layout the dense decode cache uses under TP), scale tables
-        replicated.  The block tables, free list and admission logic
-        stay host-side and replicated — sharding is a placement of the
-        SAME geometry, so allocation/COW/refcount policy is untouched
-        and the compiled step lowers once with GSPMD shardings."""
+        [NB, BS, H*D] payload leaf is placed head-sharded (heads are
+        the outer factor of the merged last dimension, so a shard holds
+        whole heads: the same split the dense decode cache uses under
+        TP), scale tables replicated.  The block tables, free list and
+        admission logic stay host-side and replicated — sharding is a
+        placement of the SAME geometry, so allocation/COW/refcount
+        policy is untouched and the compiled step lowers once with
+        GSPMD shardings."""
         self._mesh = mesh
 
         def put(path, leaf):
@@ -406,8 +410,7 @@ class BlockPool:
 
         from apex_example_tpu.parallel.mesh import MODEL_AXIS
         if _leaf_name(path) in _PAGE_LEAVES:
-            return NamedSharding(self._mesh,
-                                 P(None, None, MODEL_AXIS, None))
+            return NamedSharding(self._mesh, P(None, None, MODEL_AXIS))
         return NamedSharding(self._mesh, P())
 
     # ------------------------------------------------------------ state
@@ -703,7 +706,7 @@ class BlockPool:
     def kv_bytes_reserved(self) -> int:
         """HBM bytes the arenas pin for the engine's lifetime: every
         ``cached_key``/``cached_value`` leaf is a full
-        [num_blocks, block_size, H, D] allocation.  The default
+        [num_blocks, block_size, H*D] allocation.  The default
         ``num_blocks`` makes this equal to the dense layout's
         reservation — the paged win shows up in the per-tick committed/
         live gauges, not here."""

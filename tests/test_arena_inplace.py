@@ -1,0 +1,162 @@
+"""The serve tick updates the paged K/V arena in place (ISSUE 25).
+
+Two halves of one property, read off the compiled program:
+
+(a) compiled for a described TPU (``v5e:2x2``, no chip attached) the
+    tick holds no ``copy`` as large as one arena leaf: the COW block
+    copy, the per-token write and the block gather all address the
+    leading dimension of ONE tiled layout, so XLA has nothing to
+    convert between them (with ``[NB, BS, H, D]`` leaves it converted
+    the whole arena between every pair, six passes a tick);
+(b) every arena byte is aliased from argument to result
+    (``donate_argnums`` on the cache).  This half also holds on the
+    CPU backend and needs no topology.
+
+Geometry: the real head shape (12 x 64), 2 layers, 8 slots x 128,
+block 16, and a pool of 96 blocks (dense capacity would be 64): the
+gathered per-slot view ``[8, 128, 12, 64]`` is then SMALLER than an
+arena leaf, so "at least one arena's element count" cannot be met by
+the view's own relayout, which is work of attention and not of the
+arena's update.  The vocabulary is cut to 512 (it is not the arena's
+business and is most of the compile time).
+
+The TPU half compiles in this process (never in a child: libtpu
+belongs to one process), inside fixtures (never at import).
+"""
+
+import math
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from apex_example_tpu.models.gpt import GPTForCausalLM
+from apex_example_tpu.serve import engine as engine_lib
+from apex_example_tpu.serve.slots import BlockPool
+
+pytestmark = pytest.mark.serve
+
+LAYERS, SLOTS, MAX_LEN, BS, NB = 2, 8, 128, 16, 96
+HIDDEN, HEADS = 768, 12
+SPEC_K = 3                         # lanes = max(BS, K + 1) = BS here
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:         # libtpu cannot describe the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _model():
+    return GPTForCausalLM(vocab_size=512, hidden_size=HIDDEN,
+                          num_layers=LAYERS, num_heads=HEADS,
+                          intermediate_size=4 * HIDDEN,
+                          max_position=MAX_LEN)
+
+
+def _lowered(speculative: bool, kv_quant: bool, sharding):
+    """The tick's program lowered from shapes alone (no array is made):
+    the model clone ``BlockPool`` builds, the step ``ServeEngine``
+    calls, every argument a ShapeDtypeStruct on ``sharding``."""
+    dec = _model().clone(decode=True, slot_decode=True,
+                         fused_attention=False, kv_num_blocks=NB,
+                         kv_block_size=BS, kv_quant=kv_quant)
+    shapes = jax.eval_shape(dec.init, jax.random.PRNGKey(0),
+                            jnp.zeros((SLOTS, MAX_LEN), jnp.int32))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def tree(t):
+        return jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), t)
+
+    lanes = max(BS, SPEC_K + 1) if speculative else BS
+    i32 = jnp.int32
+    args = (tree(shapes["params"]), tree(shapes["cache"]),
+            sds((SLOTS, lanes), i32), sds((SLOTS, MAX_LEN // BS), i32),
+            sds((SLOTS,), i32), sds((SLOTS,), i32), sds((SLOTS,), i32),
+            sds((SLOTS,), i32), sds((2,), jnp.uint32),
+            sds((SLOTS,), jnp.float32), sds((SLOTS,), i32))
+    step = (engine_lib._slot_step_spec if speculative
+            else engine_lib._slot_step)(dec)
+    leaves = jax.tree_util.tree_leaves(shapes["cache"])
+    arena_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
+    arena_elems = max(l.size for l in leaves)
+    assert arena_elems == NB * BS * HIDDEN
+    return step.lower(*args), arena_bytes, arena_elems
+
+
+_RESULT = re.compile(r"= (\w+)\[([\d,]*)\]\S* copy\(")
+
+
+def arena_sized_copies(hlo_text: str, arena_elems: int):
+    """Every ``copy`` instruction of the optimised HLO (top level or
+    inside a fusion) whose result holds at least one arena leaf's
+    element count, as ``(dtype, dims)``."""
+    found = []
+    for dtype, dims in _RESULT.findall(hlo_text):
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if n >= arena_elems:
+            found.append((dtype, dims))
+    return found
+
+
+def test_copy_counter_sees_the_copies_it_is_for():
+    text = ("%copy.54 = f32[96,16,12,64]{3,1,0,2:T(8,128)S(1)} copy(%f)\n"
+            "ROOT %copy.9 = bf16[1536,12,64]{2,0,1:T(8,128)(2,1)} copy(%p)\n"
+            "%copy.3 = bf16[8,128,12,64]{3,1,2,0} copy(%g)\n"
+            "%fusion.1 = f32[96,16,768]{2,1,0} fusion(%a), kind=kLoop\n")
+    assert arena_sized_copies(text, NB * BS * HIDDEN) == [
+        ("f32", "96,16,12,64"), ("bf16", "1536,12,64")]
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["plain", "speculative"])
+def test_tpu_tick_has_no_arena_sized_copy_and_aliases_the_arena(
+        one_chip, speculative):
+    lowered, arena_bytes, arena_elems = _lowered(speculative, False,
+                                                 one_chip)
+    compiled = lowered.compile()
+    assert arena_sized_copies(compiled.as_text(), arena_elems) == []
+    assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
+
+
+def test_tpu_tick_aliases_the_int8_arena(one_chip):
+    lowered, arena_bytes, _ = _lowered(False, True, one_chip)
+    mem = lowered.compile().memory_analysis()
+    # >=: the four [NB, BS] bfloat16 scale tables are padded to whole
+    # tiles on the TPU (3072 -> 4096 bytes each at this geometry)
+    assert arena_bytes <= mem.alias_size_in_bytes < arena_bytes + 2 ** 16
+
+
+@pytest.mark.parametrize("speculative,kv_quant",
+                         [(False, False), (True, False), (False, True)],
+                         ids=["plain", "speculative", "kv_quant"])
+def test_cpu_tick_aliases_the_arena(speculative, kv_quant):
+    cpu = SingleDeviceSharding(jax.devices("cpu")[0])
+    lowered, arena_bytes, _ = _lowered(speculative, kv_quant, cpu)
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes == arena_bytes
+
+
+def test_arena_leaf_is_blocks_by_rows_by_merged_heads():
+    pool = BlockPool(_model(), num_slots=2, max_len=32, block_size=BS)
+    shapes = {l.shape for l in jax.tree_util.tree_leaves(pool.cache)}
+    assert shapes == {(4, BS, HIDDEN)}
+    # layout-blind accounting: K and V, float32, every layer
+    assert pool.kv_bytes_per_token() == 2 * LAYERS * HIDDEN * 4
+    assert pool.kv_bytes_per_token_bf16() == 2 * LAYERS * HIDDEN * 2

@@ -177,7 +177,7 @@ def test_tp_sharded_serving_token_identity(devices8, model_and_params,
         # a head-sharded param really is distributed under the mesh
         q = eng.params["layer_0"]["attention"]["query"]["kernel"]
         assert q.addressable_shards[0].data.shape[1] == q.shape[1] // 4
-        # ... and so is the KV arena: [NB, BS, H, D] sharded over heads
+        # ... and so is the KV arena: [NB, BS, H*D] sharded over heads
         ck = next(leaf for p, leaf in
                   jax.tree_util.tree_flatten_with_path(eng.pool.cache)[0]
                   if "cached_key" in str(p[-1]) and "scale" not in str(p[-1]))

@@ -408,3 +408,194 @@ def test_queue_push_front_preserves_fifo():
     assert got is a
     q.push_front(got)
     assert q.pop(0) is a and q.pop(0) is b
+
+
+# =================== in-place arena updates (ISSUE 25) ==============
+#
+# The tick donates the cache and the arena leaf is [NB, BS, H*D]: COW,
+# write and gather address one layout, so the program updates the
+# arena in place.  What that could break is tested here: a caller
+# still holding the old leaves, a write clobbering the block a COW
+# copies from in the same tick, the served tokens, and the handoff
+# payload's row shape.  (tests/test_arena_inplace.py reads the same
+# property off the compiled program.)
+
+_ARENA_MODES = {"float32": {}, "kv_quant": {"kv_quant": True},
+                "speculative": {"speculate": 3}}
+
+
+def _arena_leaves(pool):
+    """Leaves by the path string handoff payloads are keyed by."""
+    from apex_example_tpu.serve.slots import _path_str
+    return {_path_str(path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(pool.cache)[0]}
+
+
+@pytest.mark.parametrize("mode", sorted(_ARENA_MODES) + ["cost_model"])
+def test_tick_deletes_the_leaves_passed_in_and_rebinds_the_cache(
+        model_and_params, mode):
+    """``cost_model``: obs/costmodel.instrument runs the step through
+    ``lower().compile()``; the compiled executable must keep the
+    donation the jitted function declares."""
+    from apex_example_tpu import obs
+    from apex_example_tpu.obs import costmodel
+    model, params = model_and_params
+    cm = obs.CostModel() if mode == "cost_model" else None
+    costmodel.set_default(cm)
+    try:
+        eng = ServeEngine(model, params, num_slots=SLOTS, max_len=MAX_LEN,
+                          rng=jax.random.PRNGKey(0),
+                          **_ARENA_MODES.get(mode, {}))
+    finally:
+        costmodel.set_default(None)
+    eng.queue.submit_all([Request(prompt=list(range(3, 15)),
+                                  max_new_tokens=4)])
+    eng.queue.close()
+    for _ in range(3):                       # prefill, prefill, decode
+        old = jax.tree_util.tree_leaves(eng.pool.cache)
+        assert eng.step()
+        assert all(leaf.is_deleted() for leaf in old)
+        new = jax.tree_util.tree_leaves(eng.pool.cache)
+        assert not any(leaf.is_deleted() for leaf in new)
+        assert [l.shape for l in new] == [l.shape for l in old]
+    payload = [l for l in new if l.ndim == 3]
+    assert payload and all(
+        l.shape == (eng.pool.num_blocks, BS, model.hidden_size)
+        for l in payload)
+    assert any(np.asarray(l).any() for l in payload)   # really written
+    eng.run(max_steps=100)
+    assert eng.counts["ok"] == 1
+    if cm is not None:
+        assert cm.compile_counts == {"serve_decode_step": 1}
+
+
+@pytest.mark.parametrize("kv_quant", [False, True],
+                         ids=["float32", "kv_quant"])
+def test_cow_then_write_in_one_tick_leaves_the_source_block_bit_identical(
+        model_and_params, kv_quant):
+    """One tick copies block ``src`` to ``dst`` AND writes a token into
+    ``dst`` (the first divergent write after a shared prefix).  In
+    place, the write must land in the copy and nowhere else: ``src``
+    stays bit-identical, ``dst`` is ``src`` except the written row,
+    every other block keeps its bytes."""
+    from apex_example_tpu.serve.engine import _slot_step
+    model, params = model_and_params
+    pool = BlockPool(model, num_slots=SLOTS, max_len=MAX_LEN,
+                     block_size=BS, kv_quant=kv_quant)
+    step = _slot_step(pool.dec)
+    src, dst, row = 3, 5, 4
+    rng = jax.random.PRNGKey(0)
+    z = lambda *s: jnp.zeros(s, jnp.int32)
+    none = jnp.full((SLOTS,), -1, jnp.int32)
+    table = np.zeros((SLOTS, pool.max_blocks), np.int32)
+
+    def tick(tok, table, fill, n_new, cow_src, cow_dst):
+        pool.cache, nxt, finite = step(
+            params, pool.cache, jnp.asarray(tok), jnp.asarray(table),
+            jnp.asarray(fill), jnp.asarray(n_new), cow_src, cow_dst, rng,
+            jnp.zeros((SLOTS,), jnp.float32), z(SLOTS))
+        assert bool(np.asarray(finite)[0])
+        return {k: np.asarray(v) for k, v in _arena_leaves(pool).items()}
+
+    # tick 1: slot 0 fills block ``src`` with a whole chunk
+    tok = np.zeros((SLOTS, BS), np.int32)
+    tok[0] = np.arange(10, 10 + BS)
+    table[0, 0] = src
+    before = tick(tok, table, [0] * SLOTS, [BS, 0, 0, 0], none, none)
+    assert all(v[src].any() for v in before.values())
+    assert not any(v[dst].any() for v in before.values())
+    # tick 2: slot 1 shares the first ``row`` tokens, COWs src -> dst
+    # and writes its divergent token at row ``row`` of dst
+    tok = np.zeros((SLOTS, BS), np.int32)
+    tok[1, 0] = 99
+    table[1, 0] = dst
+    after = tick(tok, table, [0, row, 0, 0], [0, 1, 0, 0],
+                 none.at[1].set(src), none.at[1].set(dst))
+    assert sorted(after) == sorted(before)
+    for name, a in after.items():
+        b = before[name]
+        np.testing.assert_array_equal(a[src], b[src], err_msg=name)
+        keep = np.arange(BS) != row
+        np.testing.assert_array_equal(a[dst][keep], b[src][keep],
+                                      err_msg=name)
+        assert not np.array_equal(a[dst][row], b[src][row]), name
+        others = [i for i in range(pool.num_blocks) if i not in (src, dst)]
+        np.testing.assert_array_equal(a[others], b[others], err_msg=name)
+
+
+@pytest.mark.parametrize("mode", sorted(_ARENA_MODES))
+def test_mixed_prefill_decode_run_serves_the_reference_tokens(
+        model_and_params, mode):
+    """Long prompts prefilling in chunks beside slots that decode, with
+    a shared prefix so COWs fire: float32 and speculative decoding
+    serve generate()'s tokens exactly; the int8 arena serves what each
+    request gets when it runs alone (its own quantised reference)."""
+    model, params = model_and_params
+    rs = np.random.RandomState(25)
+    shared = [int(t) for t in rs.randint(0, model.vocab_size, 12)]
+    reqs = []
+    for i, (extra, new) in enumerate([(9, 6), (1, 8), (12, 5), (3, 7),
+                                      (6, 6), (2, 8)]):
+        tail = [int(t) for t in rs.randint(0, model.vocab_size, extra)]
+        reqs.append(Request(prompt=shared + tail, max_new_tokens=new,
+                            arrival_step=2 * i))
+    eng = _run(model, params, reqs, **_ARENA_MODES[mode])
+    assert eng.counts["ok"] == len(reqs)
+    assert eng.pool.cow_copies >= 1 and eng.pool.prefix_hit_rate() > 0
+    for c in eng.completions:
+        prompt = list(c.request.prompt)
+        if mode == "kv_quant":
+            solo = _run(model, params,
+                        [Request(prompt=prompt,
+                                 max_new_tokens=c.request.max_new_tokens)],
+                        kv_quant=True)
+            want = solo.completions[0].tokens
+        else:
+            want = _ref_tokens(model, params, prompt, len(c.tokens))
+        assert c.tokens == want, (mode, c.request.uid)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True],
+                         ids=["float32", "kv_quant"])
+def test_handoff_export_import_round_trips_bit_exactly(model_and_params,
+                                                       kv_quant):
+    """extract_blocks -> admit_prefilled -> extract_blocks gives the
+    same bytes back, rows shaped like the arena leaf's ([BS, H*D]
+    payload, [BS] scales); the import writes in place (its leaves are
+    donated) and touches no block but the ones it allocated."""
+    model, params = model_and_params
+    src = ServeEngine(model, params, num_slots=SLOTS, max_len=MAX_LEN,
+                      rng=jax.random.PRNGKey(0), kv_quant=kv_quant)
+    req = Request(prompt=list(range(40, 53)), max_new_tokens=6)
+    src.queue.submit_all([req])
+    src.queue.close()
+    for _ in range(4):                       # 13-token prompt + 2 decodes
+        src.step()
+    idx = src.pool.live[0]
+    fill, n, payload = src.pool.extract_blocks(idx)
+    assert fill == 15 and n == 2
+    assert len(payload) == (4 if kv_quant else 2) * model.num_layers
+    for key, rows in payload.items():
+        want = (n, BS) if key.endswith("_scale") \
+            else (n, BS, model.hidden_size)
+        assert rows.shape == want, key
+        assert rows.any(), key
+
+    dst = BlockPool(model, num_slots=SLOTS, max_len=MAX_LEN, block_size=BS,
+                    kv_quant=kv_quant)
+    dst.alloc.alloc()                        # so the import lands off 0
+    old = jax.tree_util.tree_leaves(dst.cache)
+    assert dst.can_admit_prefilled(req)
+    j = dst.admit_prefilled(req, step=0, fill=fill, payload=payload,
+                            tokens=list(src.pool.slots[idx].tokens))
+    assert all(leaf.is_deleted() for leaf in old)
+    fill2, n2, back = dst.extract_blocks(j)
+    assert (fill2, n2) == (fill, n) and sorted(back) == sorted(payload)
+    for key in payload:
+        assert back[key].dtype == payload[key].dtype, key
+        np.testing.assert_array_equal(back[key], payload[key], err_msg=key)
+    mapped = set(int(b) for b in dst.table[j, :n])
+    assert 0 not in mapped
+    for key, leaf in _arena_leaves(dst).items():
+        rest = [i for i in range(dst.num_blocks) if i not in mapped]
+        assert not np.asarray(leaf)[rest].any(), key
