@@ -37,7 +37,8 @@ from apex_example_tpu.obs import trace as trace_lib
 # Canonical labels.  The host-side entries are emitted by the train
 # loop through span(); the device-side ones through device_span by
 # engine.make_train_step, the loss functions of workloads.py, the models'
-# heads, the paged branch of models/bert.py and serve/engine._slot_step.
+# heads, the paged branch of models/bert.py, models/xing4.py, the dropless
+# layer of transformer/expert_parallel.py and serve/engine._slot_step.
 # The serve tick's host phases are tickprof.ENGINE_PHASES (a jax-free
 # table).  Keep README's "Span naming" paragraph in sync.
 PHASES = (
@@ -55,6 +56,13 @@ PHASES = (
     "kv_gather",        # device: paged decode, each slot's K/V view gathered
     "paged_attention",  # device: paged decode, attention + output projection
     "sample",           # device: serve step, last-lane take + sampling
+    "latent_attention",  # device: models/xing4.py, MLA (absorbed or expanded)
+    "hc_mix",           # device: hyper-connection coefficients, Sinkhorn, mixing
+    "moe_route",        # device: dropless experts, router + top-k + gates
+    "moe_dispatch",     # device: dropless experts, sort by expert + gather
+    "moe_experts",      # device: dropless experts, the grouped products
+    "moe_combine",      # device: dropless experts, back to token order + sum
+    "shared_expert",    # device: the shared expert beside the routed ones
 )
 
 device_span = jax.named_scope

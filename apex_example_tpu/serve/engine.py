@@ -73,6 +73,7 @@ models/gpt.sample_tokens), so greedy and sampled requests batch together.
 
 from __future__ import annotations
 
+import collections
 import functools
 import time
 import traceback
@@ -120,9 +121,13 @@ def _slot_step(dec, dequant_weights: bool = False):
     kv_block_size: a prefill chunk for slots inside their prompt, one
     token (lane 0) for decoding slots; ``n_new`` says how many lanes
     are real per slot, and sampling reads the logits AFTER each slot's
-    last real token.  COW copies, the block-table K/V scatter and the
-    gathered-attention live mask all run inside this one program
-    (models/bert.py).  ``cache`` is DONATED: the arena leaves keep one
+    last real token (a model whose paged head runs on that lane alone
+    hands back ``[SLOTS, 1, V]`` logits and the take is skipped).  COW
+    copies, the block-table K/V scatter and the gathered-attention live
+    mask all run inside this one program (models/bert.py,
+    models/xing4.py).  A model that sows into a ``counters`` collection
+    (an expert layer's per-tick load) gets it back as a fourth output.
+    ``cache`` is DONATED: the arena leaves keep one
     physical layout from argument to result, so the program updates
     them in place and the leaves passed in are deleted by the call —
     the caller must rebind its cache from the first output (ServeEngine
@@ -151,13 +156,21 @@ def _slot_step(dec, dequant_weights: bool = False):
                  "cow_src": cow_src, "cow_dst": cow_dst}
         logits, mut = dec.apply({"params": params, "cache": cache}, tok,
                                 train=False, paged=paged,
-                                mutable=["cache"])
+                                mutable=["cache", "counters"])
         with device_span("sample"):
-            idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
-            last = jnp.take_along_axis(logits, idx[:, None, None],
-                                       axis=1)[:, 0]
+            if logits.shape[1] == tok.shape[1]:
+                idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
+                last = jnp.take_along_axis(logits, idx[:, None, None],
+                                           axis=1)[:, 0]
+            else:
+                # the model ran its head on each slot's sampled lane only
+                last = logits[:, 0]
             nxt = sample_tokens(rng, last, temperature, top_k)
             finite = jnp.all(jnp.isfinite(last), axis=-1)
+        if mut.get("counters"):
+            # what the model counted this tick (an expert layer's load):
+            # a fourth output, kept unfetched by the engine
+            return mut["cache"], nxt, finite, mut["counters"]
         return mut["cache"], nxt, finite
 
     return step
@@ -386,6 +399,11 @@ class ServeEngine:
         if speculate and speculate + 1 > max_len:
             raise ValueError(f"speculate {speculate} exceeds max_len "
                              f"{max_len} lanes")
+        if speculate and not getattr(model, "all_lane_logits", True):
+            raise ValueError(
+                "speculate verifies draft lanes against every lane's "
+                f"logits; {type(model).__name__}'s paged head runs on the "
+                "sampled lane only (ROADMAP: self-drafting)")
         self.pool = BlockPool(model, num_slots, max_len,
                               block_size=block_size,
                               num_blocks=num_blocks, kv_quant=kv_quant,
@@ -512,6 +530,14 @@ class ServeEngine:
         self._kv_hist = Histogram("serve.kv_bytes_live")
         self._blk_hist = Histogram("serve.blocks_live")
         self._committed_hist = Histogram("serve.kv_bytes_committed")
+        # What the model itself counted in a tick (a "counters"
+        # collection: the expert layers' load over the live lanes), kept
+        # with the gauges as (tick's end on perf_counter, device arrays)
+        # and NOT fetched — a fetch a tick would sit in every tick's host
+        # gap; whoever reads the log fetches (benchmarks/runners/
+        # serve_blocked.py; 8192 ticks are kept).
+        self.counter_log: collections.deque = collections.deque(
+            maxlen=8192)
         # --trace (obs/trace.py): the process-default tracer, when one
         # is armed, receives the per-tick admit/dispatch/harvest spans
         # and a per-request lifecycle span tree.  Everything below is
@@ -773,12 +799,13 @@ class ServeEngine:
         # synchronous, so the device time hides in engine.enqueue.)
         ph.enter("engine.sync")
         lane_greedy = lane_finite = None
+        counted = []
         if self.speculate:
             pool.cache, nxt, finite, lane_greedy, lane_finite = outs
             lane_greedy = np.asarray(lane_greedy)
             lane_finite = np.asarray(lane_finite)
         else:
-            pool.cache, nxt, finite = outs
+            pool.cache, nxt, finite, *counted = outs
         nxt = np.asarray(nxt)          # the scheduler's host sync
         finite = np.asarray(finite)
         now = t_dispatch_end = ph.enter("engine.harvest")
@@ -898,6 +925,8 @@ class ServeEngine:
         self._blk_hist.observe(blocks_live)
         self._committed_hist.observe(
             self.pool.blocks_committed() * per_block)
+        if counted:
+            self.counter_log.append((now, counted[0]))
         if self.registry is not None:
             self.registry.gauge("serve.slots_live").set(live_slots)
             self.registry.gauge("serve.kv_bytes_live").set(kv_live)

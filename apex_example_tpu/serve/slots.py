@@ -3,8 +3,10 @@
 The dense layout this replaces pinned a [SLOTS, max_len, H, D] page per
 slot, so HBM cost scaled with ``max_len`` regardless of request length
 (PR 6's gauges measured ~92% ``kv_waste_pct`` on the smoke workload).
-Here every layer owns ONE shared arena of shape
-``[num_blocks, block_size, H*D]`` and a request maps only the blocks
+Here every layer owns shared arena leaves of shape
+``[num_blocks, block_size, W]`` (``W`` is the model's: merged heads times
+head size for per-head K and V, latent rank plus rotary size for a latent
+cache) and a request maps only the blocks
 its sequence actually touches, through a per-slot block table
 (``[SLOTS, max_blocks]`` int32) the attention layers gather through
 inside the one compiled decode step (models/bert.py).  Geometry stays
@@ -56,16 +58,18 @@ import numpy as np
 
 from apex_example_tpu.serve.queue import Request
 
-# Arena payload leaves, and the scale tables that ride along under
-# kv_quant (ISSUE 13) — accounting sums BOTH so the committed/live
-# byte gauges stay honest about the quantized layout's true footprint.
-_PAGE_LEAVES = ("cached_key", "cached_value")
-_SCALE_LEAVES = ("cached_key_scale", "cached_value_scale")
 
-
-def _leaf_name(path) -> str:
-    last = path[-1]
-    return getattr(last, "key", getattr(last, "name", str(last)))
+def _block_leaf(leaf, num_blocks: int, block_size: int) -> int:
+    """Is this cache leaf block-resident state, by shape alone?  2 for an
+    arena payload ``[num_blocks, block_size, W]`` (whatever the model
+    keeps a token a layer: merged K or V heads, a latent), 1 for a
+    per-token scale table ``[num_blocks, block_size]`` that rides along
+    under kv_quant (ISSUE 13: accounting sums BOTH so the byte gauges stay
+    honest about the quantized layout's true footprint), 0 for anything
+    else.  The pool knows no leaf by name."""
+    if leaf.shape[:2] != (num_blocks, block_size):
+        return 0
+    return {3: 2, 2: 1}.get(leaf.ndim, 0)
 
 
 def _path_str(path) -> str:
@@ -321,10 +325,14 @@ class Slot:
 class BlockPool:
     """``num_slots`` request slots over one block-paged KV arena.
 
-    ``model`` is the plain (training) GPT module; the pool derives the
+    ``model`` is the plain (training) module; the pool derives the
     paged slot-decode clone and allocates the per-layer arenas via an
     abstract init trace (no real forward runs), exactly like
-    models/gpt.generate.  ``num_blocks`` defaults to the dense
+    models/gpt.generate.  Every cache leaf shaped ``[num_blocks,
+    block_size, W]`` is an arena page leaf, whatever its name and width
+    (``_block_leaf``): handoff, migration and the byte accounting carry
+    whatever page leaves the model allocates.  ``num_blocks`` defaults
+    to the dense
     layout's capacity (``num_slots * ceil(max_len / block_size)``), so
     the default arena reserves the same HBM the old [SLOTS, max_len]
     pages did — the win is that admission now shares and packs it.
@@ -372,6 +380,12 @@ class BlockPool:
             jnp.zeros((num_slots, max_len), jnp.int32))["cache"]
         self.cache = jax.tree_util.tree_map(
             lambda t: jnp.zeros(t.shape, t.dtype), shapes)
+        if self.kv_quant and not any(
+                k == 1 for _, _, k in self._block_leaves()):
+            raise ValueError(
+                "kv_quant: the model allocated no per-token scale table "
+                "beside its arena leaves — quantized paged KV is not built "
+                "for this cache layout")
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.table = np.zeros((num_slots, self.max_blocks), np.int32)
         self.slots: List[Optional[Slot]] = [None] * num_slots
@@ -385,9 +399,20 @@ class BlockPool:
 
     # --------------------------------------------------------- sharding
 
+    def _block_leaves(self):
+        """``(path, leaf, kind)`` of every block-resident cache leaf
+        (kind 2 payload, 1 scale table: ``_block_leaf``)."""
+        out = []
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self.cache)[0]:
+            kind = _block_leaf(leaf, self.num_blocks, self.block_size)
+            if kind:
+                out.append((path, leaf, kind))
+        return out
+
     def shard(self, mesh) -> None:
         """TP-shard the arenas over the mesh's ``model`` axis: every
-        [NB, BS, H*D] payload leaf is placed head-sharded (heads are
+        [NB, BS, W] payload leaf is placed head-sharded (heads are
         the outer factor of the merged last dimension, so a shard holds
         whole heads: the same split the dense decode cache uses under
         TP), scale tables replicated.  The block tables, free list and
@@ -395,21 +420,26 @@ class BlockPool:
         placement of the SAME geometry, so allocation/COW/refcount
         policy is untouched and the compiled step lowers once with
         GSPMD shardings."""
+        from apex_example_tpu.parallel.mesh import MODEL_AXIS
+        if mesh.shape.get(MODEL_AXIS, 1) > 1 \
+                and not self.dec.tensor_parallel:
+            raise ValueError(
+                f"mesh has '{MODEL_AXIS}' size {mesh.shape[MODEL_AXIS]} "
+                "but the model was built without tensor_parallel=True: "
+                "its arena leaves have no head axis to shard")
         self._mesh = mesh
+        self.cache = jax.tree_util.tree_map(
+            lambda leaf: jax.device_put(leaf, self._leaf_sharding(leaf)),
+            self.cache)
 
-        def put(path, leaf):
-            return jax.device_put(leaf, self._leaf_sharding(path))
-
-        self.cache = jax.tree_util.tree_map_with_path(put, self.cache)
-
-    def _leaf_sharding(self, path):
+    def _leaf_sharding(self, leaf):
         """The NamedSharding one cache leaf gets under the registered
         mesh: heads over 'model' for arena payloads, replicated for
         scale tables (and anything else)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from apex_example_tpu.parallel.mesh import MODEL_AXIS
-        if _leaf_name(path) in _PAGE_LEAVES:
+        if _block_leaf(leaf, self.num_blocks, self.block_size) == 2:
             return NamedSharding(self._mesh, P(None, None, MODEL_AXIS))
         return NamedSharding(self._mesh, P())
 
@@ -531,14 +561,11 @@ class BlockPool:
             raise RuntimeError(f"slot {idx} is free — nothing to hand off")
         n = slot.n_mapped
         bids = jnp.asarray(np.ascontiguousarray(self.table[idx, :n]))
-        payload: Dict[str, np.ndarray] = {}
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.cache)[0]:
-            if _leaf_name(path) in _PAGE_LEAVES + _SCALE_LEAVES:
-                # np.array (not asarray): an OWNED writable host copy —
-                # np.asarray of a jax array is a read-only view that
-                # would pin the gather buffer across the transport.
-                payload[_path_str(path)] = np.array(leaf[bids])
+        # np.array (not asarray): an OWNED writable host copy —
+        # np.asarray of a jax array is a read-only view that would pin
+        # the gather buffer across the transport.
+        payload = {_path_str(path): np.array(leaf[bids])
+                   for path, leaf, _ in self._block_leaves()}
         return slot.cursor, n, payload
 
     def blocks_needed_prefilled(self, request: Request) -> int:
@@ -607,9 +634,10 @@ class BlockPool:
         idx = np.full((pad,), self.num_blocks, np.int32)
         idx[:n_pay] = bids
         leaves, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
+        NB, BS = self.num_blocks, self.block_size
         arena, rows_in, out = [], [], []
         for path, leaf in leaves:
-            if _leaf_name(path) not in _PAGE_LEAVES + _SCALE_LEAVES:
+            if not _block_leaf(leaf, NB, BS):
                 continue
             key = _path_str(path)
             if key not in payload:
@@ -636,12 +664,12 @@ class BlockPool:
             tuple(arena), jnp.asarray(idx),
             tuple(jnp.asarray(r) for r in rows_in))
         it = iter(new)
-        for path, leaf in leaves:
-            if _leaf_name(path) in _PAGE_LEAVES + _SCALE_LEAVES:
+        for _, leaf in leaves:
+            if _block_leaf(leaf, NB, BS):
                 leaf = next(it)
                 if self._mesh is not None:
                     leaf = jax.device_put(leaf,
-                                          self._leaf_sharding(path))
+                                          self._leaf_sharding(leaf))
             out.append(leaf)
         self.cache = jax.tree_util.tree_unflatten(treedef, out)
 
@@ -705,23 +733,20 @@ class BlockPool:
 
     def kv_bytes_reserved(self) -> int:
         """HBM bytes the arenas pin for the engine's lifetime: every
-        ``cached_key``/``cached_value`` leaf is a full
-        [num_blocks, block_size, H*D] allocation.  The default
+        page leaf is a full [num_blocks, block_size, W] allocation
+        (scale tables counted with them).  The default
         ``num_blocks`` makes this equal to the dense layout's
         reservation — the paged win shows up in the per-tick committed/
         live gauges, not here."""
         if self._kv_reserved is None:       # geometry is fixed; compute once
-            total = 0
-            for path, leaf in jax.tree_util.tree_flatten_with_path(
-                    self.cache)[0]:
-                if _leaf_name(path) in _PAGE_LEAVES + _SCALE_LEAVES:
-                    total += leaf.size * leaf.dtype.itemsize
-            self._kv_reserved = total
+            self._kv_reserved = sum(
+                leaf.size * leaf.dtype.itemsize
+                for _, leaf, _ in self._block_leaves())
         return self._kv_reserved
 
     def kv_bytes_per_token(self) -> int:
-        """Bytes one cached token occupies across every layer's K and V
-        arena (``kv_bytes_reserved / (num_blocks * block_size)``) —
+        """Bytes one cached token occupies across every layer's arena
+        leaves (``kv_bytes_reserved / (num_blocks * block_size)``) —
         dtype-accurate: int8 payload plus the bf16 block scales under
         kv_quant, the full-precision payload otherwise."""
         return self.kv_bytes_reserved() \
@@ -732,19 +757,15 @@ class BlockPool:
         arena of this geometry (2 bytes per K/V element, no scales) —
         the bf16-equivalent baseline the quant compression ratio and
         the ci_gate ``--quant-stream`` floor are computed against."""
-        elems = 0
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.cache)[0]:
-            if _leaf_name(path) in _PAGE_LEAVES:
-                elems += leaf.size
+        elems = sum(leaf.size for _, leaf, kind in self._block_leaves()
+                    if kind == 2)
         return elems * 2 // (self.num_blocks * self.block_size)
 
     @property
     def kv_dtype(self) -> str:
         """The arena payload dtype name ("int8" under kv_quant)."""
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.cache)[0]:
-            if _leaf_name(path) in _PAGE_LEAVES:
+        for _, leaf, kind in self._block_leaves():
+            if kind == 2:
                 return str(leaf.dtype)
         return "none"                        # zero-layer model; untestable
 

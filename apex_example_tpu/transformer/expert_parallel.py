@@ -274,3 +274,87 @@ class MoEMLP(nn.Module):
                 params, flat, self.capacity_factor, activation=nn.gelu,
                 top_k=self.top_k)
         return y.reshape(x.shape).astype(self.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing for serving (sigmoid scores, selection-only bias, top-k,
+# grouped product over the experts held).  A server may not drop a token an
+# expert is full for, so nothing here has a capacity: every chosen
+# (token, expert) pair is computed.  The capacity path above is the
+# trainers' and is untouched.
+
+def dropless_route(x: jnp.ndarray, w_router: jnp.ndarray,
+                   bias: jnp.ndarray, top_k: int,
+                   scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Chosen experts ``[T, k]`` (int32) and gates ``[T, k]`` (float32) of
+    tokens ``x [T, d]``.  Scores are ``sigmoid(x @ w_router)`` in float32;
+    chosen are the ``top_k`` largest of ``score + bias`` (the bias steers
+    selection only, ``noaux_tc``); a gate is the chosen expert's own score,
+    normalized over the chosen and multiplied by ``scale``."""
+    with jax.named_scope("moe_route"):
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+        g = jnp.take_along_axis(s, idx, axis=-1)
+        g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx.astype(jnp.int32), g
+
+
+def expert_load(idx: jnp.ndarray, n_experts: int,
+                live: jnp.ndarray = None) -> jnp.ndarray:
+    """Tokens routed to each expert ``[E]`` (int32) over the lanes that
+    ``live [T]`` marks (all of them by default)."""
+    w = jnp.ones(idx.shape[:1], jnp.int32) if live is None \
+        else live.astype(jnp.int32)
+    return jnp.zeros((n_experts,), jnp.int32).at[idx].add(w[:, None])
+
+
+def ragged_dot_f32(a, w, sizes):
+    """Grouped product ``a[rows of group g] @ w[g]``, float32 out."""
+    return lax.ragged_dot(a, w.astype(a.dtype), sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def dropless_experts(x: jnp.ndarray, idx: jnp.ndarray, gates: jnp.ndarray,
+                     w_gate: jnp.ndarray, w_up: jnp.ndarray,
+                     w_down: jnp.ndarray,
+                     experts_held: Tuple[int, int],
+                     live: jnp.ndarray = None) -> jnp.ndarray:
+    """What the experts held here add to tokens ``x [T, d]``:
+    ``sum_i gates[t, i] * E_idx[t, i](x[t])`` over the chosen experts in
+    ``experts_held = (first, count)``, each ``E(x) = w_down(silu(w_gate x)
+    * w_up x)``.  The stacked weights hold the ``count`` experts from
+    ``first`` on; pairs routed elsewhere add nothing (another chip's
+    share).  The ``T * k`` pairs are sorted by expert, multiplied group by
+    group (``lax.ragged_dot``: each expert's weights are read once, no
+    padding to a capacity; lanes that ``live [T]`` marks dead — the padding
+    of a static serving batch — belong to no group, so the products' work
+    follows the live tokens and not what the padding happens to hold; on
+    the TPU XLA lowers it to its own grouped
+    Mosaic kernels, which the device trace names ``ragged-dot-*`` and puts
+    under no scope of ours) and summed back per token."""
+    T, k = idx.shape
+    first, count = experts_held
+    with jax.named_scope("moe_dispatch"):
+        local = idx.reshape(-1) - first
+        held = (local >= 0) & (local < count)
+        if live is not None:
+            held = held & jnp.repeat(live, k)
+        group = jnp.where(held, local, count)       # strangers sort last
+        order = jnp.argsort(group, stable=True)
+        xs = x[order // k]                           # [T*k, d]
+        sizes = jnp.zeros((count,), jnp.int32).at[group].add(
+            1, mode="drop")
+    with jax.named_scope("moe_experts"):
+        h = (jax.nn.silu(ragged_dot_f32(xs, w_gate, sizes))
+             * ragged_dot_f32(xs, w_up, sizes)).astype(x.dtype)
+        ys = ragged_dot_f32(h, w_down, sizes)        # [T*k, d] float32
+    with jax.named_scope("moe_combine"):
+        # back to token order by a gather through the inverse permutation
+        # (a row scatter runs its rows one after another on the TPU)
+        inverse = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        g = jnp.where(held, gates.reshape(-1), 0.0)
+        y = jnp.where(g[:, None] != 0, ys[inverse] * g[:, None], 0.0)
+        return y.reshape(T, k, -1).sum(axis=1).astype(x.dtype)

@@ -140,7 +140,16 @@ def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         description="continuous-batching GPT inference")
     p.add_argument("--arch", default="gpt_tiny",
-                   choices=["gpt_tiny", "gpt_base"])
+                   choices=["gpt_tiny", "gpt_base", "xing4_tiny",
+                            "xing4_29b_a4b_cut"],
+                   help="gpt_*: the post-LN decoder (float32). xing4_*: "
+                        "latent attention, dropless experts, hyper-"
+                        "connected residual (models/xing4.py): the tiny "
+                        "preset in float32, the published widths cut to 6 "
+                        "layers in bfloat16 — the architecture sets the "
+                        "dtype; --kv-quant, --weight-quant, --speculate "
+                        "and a model-sharded --mesh are refused where the "
+                        "model, pool or engine cannot build them")
     p.add_argument("--checkpoint-dir", default=None,
                    help="CheckpointManager directory to restore params "
                         "from (omit = random init, smoke mode)")
@@ -577,6 +586,8 @@ def run_serve(args):
 
     from apex_example_tpu import obs
     from apex_example_tpu.models.gpt import gpt_base, gpt_tiny
+    from apex_example_tpu.models.xing4 import (xing4_29b_a4b_cut,
+                                               xing4_tiny)
     from apex_example_tpu.parallel.mesh import (parse_serve_mesh,
                                                 serve_mesh)
     from apex_example_tpu.resilience import (EX_TEMPFAIL, FaultPlan,
@@ -604,8 +615,10 @@ def run_serve(args):
     # tp > 1 serves the Megatron-TP model (identical param tree — dense
     # checkpoints restore unchanged; the layers' constraint points do
     # the sharding).
-    model = {"gpt_tiny": gpt_tiny,
-             "gpt_base": gpt_base}[args.arch](tensor_parallel=tp > 1)
+    model = {"gpt_tiny": gpt_tiny, "gpt_base": gpt_base,
+             "xing4_tiny": xing4_tiny,
+             "xing4_29b_a4b_cut": xing4_29b_a4b_cut,
+             }[args.arch](tensor_parallel=tp > 1)
     max_len = args.max_len
     if max_len is None:
         max_len = min(model.max_position, 128)
@@ -760,6 +773,11 @@ def run_serve(args):
         from apex_example_tpu.quant import quantize_params
         qpolicy = get_quant_policy(args.weight_quant, args.kv_quant)
         params, quant_stats = quantize_params(params, args.weight_quant)
+        if not quant_stats["tensors"]:
+            raise SystemExit(
+                f"--weight-quant {args.weight_quant}: quantize_params found "
+                f"no leaf it quantizes in --arch {args.arch}'s parameter "
+                "tree; serving it would stream the weights as they are")
         source += f" -> {qpolicy.weight_dtype_name} weights"
 
     emitter = sink = recorder = None

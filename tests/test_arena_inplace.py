@@ -43,6 +43,7 @@ pytestmark = pytest.mark.serve
 LAYERS, SLOTS, MAX_LEN, BS, NB = 2, 8, 128, 16, 96
 HIDDEN, HEADS = 768, 12
 SPEC_K = 3                         # lanes = max(BS, K + 1) = BS here
+LATENT_WIDTH = 640                 # 512 + 64 in whole 128-lane tiles
 
 
 @pytest.fixture(scope="module")
@@ -68,11 +69,21 @@ def _model():
                           max_position=MAX_LEN)
 
 
-def _lowered(speculative: bool, kv_quant: bool, sharding):
+def _latent_model():
+    """models/xing4.py at the published latent widths (rank 512 + 64
+    rotary, 32 heads), everything else small."""
+    from apex_example_tpu.models.xing4 import Xing4ForCausalLM
+    return Xing4ForCausalLM(vocab_size=512, hidden_size=512, num_layers=2,
+                            first_k_dense=1, intermediate_size=512,
+                            moe_intermediate_size=256, n_routed_experts=8)
+
+
+def _lowered(speculative: bool, kv_quant: bool, sharding, model=None):
     """The tick's program lowered from shapes alone (no array is made):
     the model clone ``BlockPool`` builds, the step ``ServeEngine``
     calls, every argument a ShapeDtypeStruct on ``sharding``."""
-    dec = _model().clone(decode=True, slot_decode=True,
+    width = HIDDEN if model is None else LATENT_WIDTH
+    dec = (model or _model()).clone(decode=True, slot_decode=True,
                          fused_attention=False, kv_num_blocks=NB,
                          kv_block_size=BS, kv_quant=kv_quant)
     shapes = jax.eval_shape(dec.init, jax.random.PRNGKey(0),
@@ -96,7 +107,7 @@ def _lowered(speculative: bool, kv_quant: bool, sharding):
     leaves = jax.tree_util.tree_leaves(shapes["cache"])
     arena_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
     arena_elems = max(l.size for l in leaves)
-    assert arena_elems == NB * BS * HIDDEN
+    assert arena_elems == NB * BS * width
     return step.lower(*args), arena_bytes, arena_elems
 
 
@@ -132,6 +143,22 @@ def test_tpu_tick_has_no_arena_sized_copy_and_aliases_the_arena(
                                                  one_chip)
     compiled = lowered.compile()
     assert arena_sized_copies(compiled.as_text(), arena_elems) == []
+    assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
+
+
+def test_tpu_tick_updates_the_latent_arena_in_place(one_chip):
+    """The head-less latent leaf (ISSUE 27) is stored 640 wide, not 576:
+    at 576 XLA gives the arena one layout coming in and another going out
+    and copies it twice a layer; at whole tiles it updates it in place."""
+    lowered, arena_bytes, arena_elems = _lowered(False, False, one_chip,
+                                                 _latent_model())
+    compiled = lowered.compile()
+    # this model's attention weights (512 x 32 x 128) outsize the small
+    # pool's leaf, so only copies of exactly a leaf's element count count
+    copies = [(dtype, dims) for dtype, dims
+              in arena_sized_copies(compiled.as_text(), arena_elems)
+              if math.prod(int(d) for d in dims.split(",")) == arena_elems]
+    assert copies == []
     assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
 
 
