@@ -26,7 +26,20 @@ form: queries are carried into the latent space (``q_nope W_UK^T``), scores
 and the weighted sum run against the cached latents themselves, and the
 result is carried out through ``W_UV``.  The cache is never up-projected.
 The arena is updated in place exactly as models/bert.py's (one physical
-layout for copy-on-write, write and gather; the cache donated).
+layout for copy-on-write, write and read; the cache donated).
+
+Scores, mask, softmax and weighted sum of the paged path are one op,
+``ops.attention.paged_latent_attention``, in two forms chosen by the
+backend as every kernel here is.  On the TPU (and under the interpreter,
+which the tests run) a Pallas kernel walks each slot's live blocks where
+they lie in the arena — ``ceil((fill + n_new) / block)`` of them, none for
+a dead slot, a decode slot's one live lane in one row tile — with an online
+softmax in float32 scratch, and no ``[S, L, W]`` view or ``[S, H, C, L]``
+score tensor exists.  On the CPU and under ``FORCE_XLA`` the XLA form
+gathers every slot's whole row of the block table (``kv_gather``) and
+scores all ``L`` positions: the same function, the tests' golden.  The
+model sows what either read, ``attn_positions_walked [layers, S]``, beside
+``expert_load`` in the ``counters`` collection.
 
 In the paged path the vocabulary head runs on each slot's *sampled lane*
 only (``[SLOTS, 1, V]`` logits): at a vocabulary of 131072 the all-lane
@@ -52,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_example_tpu.obs.spans import device_span
+from apex_example_tpu.ops.attention import paged_latent_attention
 from apex_example_tpu.transformer.expert_parallel import (dropless_experts,
                                                           dropless_route,
                                                           expert_load)
@@ -230,7 +244,9 @@ class RoutedExperts(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """MLA; see the module docstring for the two forms."""
+    """MLA; see the module docstring for the two forms.  Returns ``(y,
+    walked)``: ``walked [S]`` the cache positions the paged form read for
+    each slot this call, None from the plain forward."""
 
     hidden_size: int
     num_heads: int
@@ -311,8 +327,9 @@ class LatentAttention(nn.Module):
             # ONE head-less [NB, BS, W] leaf: c_kv (after the norm) and
             # k_rope (after the rotation) side by side, so that the COW
             # copy, the per-token write (flat [NB*BS, W] view) and the
-            # block gather all index the leading dimension of one layout
-            # and the donated arena is updated in place.
+            # read (the kernel's page DMAs; the XLA form's block gather)
+            # all index the leading dimension of one layout and the
+            # donated arena is updated in place.
             cl = self.variable("cache", "cached_latent", jnp.zeros,
                                (NB, BS, W), self.dtype)
             if cache_ready:
@@ -341,9 +358,6 @@ class LatentAttention(nn.Module):
                          jnp.zeros((S, C, W - kr - dr), self.dtype)], -1)
                     cl.value = cl.value.reshape(NB * BS, W).at[flat].set(
                         lat.reshape(S * C, W), mode="drop").reshape(NB, BS, W)
-                with device_span("kv_gather"):
-                    view = cl.value[jnp.clip(table, 0, NB - 1)].reshape(
-                        S, -1, W)
                 with device_span("latent_attention"):
                     # absorbed: queries into the latent space, scores and
                     # the weighted sum against the cached latents, out
@@ -352,15 +366,18 @@ class LatentAttention(nn.Module):
                         [ein("schd,rhd->schr", q_nope, w_uk).astype(
                             self.dtype), q_rope,
                          jnp.zeros((S, C, H, W - kr - dr), self.dtype)], -1)
-                    scores = ein("schw,slw->shcl", qf, view) * scale
-                    live = jnp.arange(view.shape[1])[None, None, :] \
-                        <= pos[:, :, None]
-                    probs = jax.nn.softmax(
-                        jnp.where(live[:, None], scores, -1e30), -1)
-                    ol = ein("shcl,slr->schr", probs.astype(self.dtype),
-                             view[..., :kr]).astype(self.dtype)
+                # scores, mask, softmax and weighted sum.  On the TPU one
+                # Pallas call that walks each slot's live blocks where
+                # they lie in the arena; on the CPU and under FORCE_XLA
+                # the XLA form, which gathers every slot's [L, W] view
+                # (kv_gather) and scores all L positions.  The op names
+                # its own scopes (ops/attention.py).
+                ol, walked = paged_latent_attention(
+                    qf, cl.value, table, paged["fill"], n_new, scale=scale,
+                    kr=kr)
+                with device_span("latent_attention"):
                     o = ein("schr,rhd->schd", ol, w_uv).astype(self.dtype)
-                    return mm(o.reshape(S, C, H * dv), w_o)
+                    return mm(o.reshape(S, C, H * dv), w_o), walked
             # init trace on the [B, max_len] dummy: the cache is allocated
             # above; fall through so that params and shapes initialize.
         with device_span("latent_attention"):
@@ -373,7 +390,7 @@ class LatentAttention(nn.Module):
             probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), -1)
             o = ein("bhqk,bkhd->bqhd", probs.astype(self.dtype),
                     v).astype(self.dtype)
-            return mm(o.reshape(B, L, H * dv), w_o)
+            return mm(o.reshape(B, L, H * dv), w_o), None
 
 
 class Xing4Layer(nn.Module):
@@ -398,7 +415,7 @@ class Xing4Layer(nn.Module):
             "beta_slow", "mscale", "mscale_all_dim"))
         hc = hyper("attn_hc")
         u, coeff = hc.mix_in(X)
-        y = LatentAttention(
+        y, walked = LatentAttention(
             d, c["num_heads"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
             c["v_head_dim"], c["q_lora_rank"], c["kv_lora_rank"], eps, rope,
             dtype, pd, c["decode"], c["slot_decode"], c["kv_num_blocks"],
@@ -418,7 +435,7 @@ class Xing4Layer(nn.Module):
                 float(c["routed_scaling_factor"]),
                 tuple(c["experts_held"] or (0, E)), dtype, pd,
                 name="moe")(u, live)
-        return hc.mix_out(X, y, coeff), load
+        return hc.mix_out(X, y, coeff), load, walked
 
 
 class Xing4ForCausalLM(nn.Module):
@@ -494,18 +511,25 @@ class Xing4ForCausalLM(nn.Module):
                            (self.vocab_size, d), self.param_dtype)
         x = embed[input_ids].astype(self.dtype)
         X = jnp.repeat(x[:, :, None, :], self.hc_mult, axis=2)  # [B,L,n,d]
-        loads = []
+        loads, walks = [], []
         for i in range(self.num_layers):
-            X, load = Xing4Layer(cfg, i < self.first_k_dense,
-                                 name=f"layer_{i}")(X, pos, paged, live)
+            X, load, walked = Xing4Layer(
+                cfg, i < self.first_k_dense, name=f"layer_{i}")(
+                    X, pos, paged, live)
             if load is not None:
                 loads.append(load)
-        if loads:
-            # live lanes routed to each expert of each expert layer this
-            # call, [expert layers, E]: read by the engine when the
-            # "counters" collection is mutable, dropped otherwise
-            self.sow("counters", "expert_load", jnp.stack(loads),
-                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
+            if walked is not None:
+                walks.append(walked)
+        # what the layers counted this call — live lanes routed to each
+        # expert of each expert layer [expert layers, E]; cache positions
+        # paged attention read for each slot [layers, S] — read by the
+        # engine when the "counters" collection is mutable, dropped
+        # otherwise
+        for name, rows in (("expert_load", loads),
+                           ("attn_positions_walked", walks)):
+            if rows:
+                self.sow("counters", name, jnp.stack(rows),
+                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
         if paged is not None:
             # the head on each slot's sampled lane only
             lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)

@@ -5,9 +5,9 @@ The TPU-native counterpart of the reference's ``csrc/`` native-extension layer
 on TPU) and an XLA reference implementation (CPU fallback + test golden).
 """
 
-from apex_example_tpu.ops.attention import (attention_reference,
-                                            flash_attention,
-                                            flash_attention_with_lse)
+from apex_example_tpu.ops.attention import (
+    attention_reference, flash_attention, flash_attention_with_lse,
+    paged_latent_attention, paged_latent_attention_reference)
 from apex_example_tpu.ops.layer_norm import (layer_norm,
                                              layer_norm_reference, rms_norm,
                                              rms_norm_reference)

@@ -539,3 +539,261 @@ def flash_attention_with_lse(q, k, v, bias=None, causal: bool = False,
     if bias is not None:
         bias = lax.stop_gradient(bias)
     return _flash_attention_with_lse_op(q, k, v, bias, causal, scale)
+
+
+# --------------------------------------------------------------------------
+# Paged latent attention (the serve tick of models/xing4.py): the absorbed
+# MLA form over a head-less [NB, BS, W] arena whose rows are key and value
+# at once.  ``paged_latent_attention`` is the op; the XLA form below it is
+# the CPU fallback, FORCE_XLA's path and the kernel tests' golden.
+# --------------------------------------------------------------------------
+
+def paged_latent_attention_reference(qf, arena, block_table, fill, n_new,
+                                     scale, kr):
+    """The XLA form: every slot's whole row of the block table gathered
+    into a ``[S, L, W]`` view, scores against all ``L`` positions, masked,
+    softmaxed and multiplied back.  Same contract as
+    :func:`paged_latent_attention`; ``walked`` is ``L`` for every slot."""
+    S, C = qf.shape[:2]
+    NB, _, W = arena.shape
+    with jax.named_scope("kv_gather"):
+        view = arena[jnp.clip(block_table, 0, NB - 1)].reshape(S, -1, W)
+    with jax.named_scope("latent_attention"):
+        L = view.shape[1]
+        lane = jnp.arange(C)[None, :]
+        scores = jnp.einsum("schw,slw->shcl", qf, view,
+                            preferred_element_type=jnp.float32) * scale
+        live = jnp.arange(L)[None, None, :] <= (fill[:, None] + lane)[..., None]
+        probs = jax.nn.softmax(jnp.where(live[:, None], scores, -1e30), -1)
+        ol = jnp.einsum("shcl,slr->schr", probs.astype(qf.dtype),
+                        view[..., :kr], preferred_element_type=jnp.float32)
+        ol = jnp.where((lane < n_new[:, None])[..., None, None], ol, 0.0)
+        return ol.astype(qf.dtype), jnp.full((S,), L, jnp.int32)
+
+
+def _paged_latent_kernel(table_ref, fill_ref, n_new_ref, q_ref, arena_ref,
+                         o_ref, walked_ref, kbuf, sem, first_buf, acc, m, l,
+                         *, scale, kr, heads, bs, pages, max_blocks,
+                         row_tile):
+    """One grid step = one slot.  Its ``rows = C * heads`` query rows lie
+    lane-major (row // heads is the lane), so the live ones come first and
+    row tiles past ``n_new * heads`` are never computed.  The slot's live
+    blocks are walked ``pages`` a compute tile: each page one DMA from
+    where it lies in the arena, the next tile in flight while this one is
+    scored (two buffers; during a slot's last tile the next slot's first is
+    in flight), online softmax state in float32 scratch."""
+    s, slots = pl.program_id(0), pl.num_programs(0)
+    rows, nb = q_ref.shape[1], arena_ref.shape[0]
+    tile = pages * bs
+
+    def blocks_tiles(slot):
+        """A slot's live blocks (never more than its row of the table
+        holds) and the compute tiles they make."""
+        n_new = n_new_ref[slot]
+        blocks = jnp.where(
+            n_new > 0,
+            jnp.minimum((fill_ref[slot] + n_new + bs - 1) // bs, max_blocks),
+            0)
+        return blocks, (blocks + pages - 1) // pages
+
+    fill, n_new = fill_ref[s], n_new_ref[s]
+    total = fill + n_new
+    live_rows = n_new * heads
+    n_blocks, n_tiles = blocks_tiles(s)
+    walked_ref[s] = n_blocks * bs
+
+    def page_dmas(slot, t, buf, act):
+        """``act`` on the DMA of every live page of ``slot``'s tile ``t``."""
+        first = t * pages
+
+        def one(p, carry):
+            page = table_ref[slot * max_blocks + first + p]
+            act(pltpu.make_async_copy(
+                arena_ref.at[jnp.clip(page, 0, nb - 1)],
+                kbuf.at[buf, pl.ds(pl.multiple_of(p * bs, bs), bs)],
+                sem.at[buf]))
+            return carry
+
+        lax.fori_loop(0, jnp.clip(blocks_tiles(slot)[0] - first, 0, pages),
+                      one, 0)
+
+    start = lambda dma: dma.start()
+    wait = lambda dma: dma.wait()
+
+    def row_tiles(lo, hi, body):
+        """``body(rs, r)`` for the row tiles ``lo <= i < hi``: ``rs`` the
+        tile's rows as a slice, ``r`` its first row."""
+        def one(i, carry):
+            r = pl.multiple_of(i * row_tile, row_tile)
+            body(pl.ds(r, row_tile), r)
+            return carry
+        lax.fori_loop(lo, hi, one, 0)
+
+    live_tiles = (live_rows + row_tile - 1) // row_tile
+
+    def init(rs, r):
+        m[rs] = jnp.full((row_tile, 1), _MASK, jnp.float32)
+        l[rs] = jnp.zeros((row_tile, 1), jnp.float32)
+        acc[rs] = jnp.zeros((row_tile, kr), jnp.float32)
+
+    row_tiles(0, live_tiles, init)
+
+    # tile t of this slot lies in buffer (first + t) % 2.  The slot before,
+    # if it walked anything, left ``first`` behind and our tile 0 in flight
+    before, after = jnp.maximum(s - 1, 0), jnp.minimum(s + 1, slots - 1)
+    in_flight = jnp.logical_and(s > 0, blocks_tiles(before)[1] > 0)
+    hand_on = jnp.logical_and(s + 1 < slots, blocks_tiles(after)[1] > 0)
+    first = jnp.where(s > 0, first_buf[0], 0)
+
+    @pl.when(jnp.logical_and(n_tiles > 0, jnp.logical_not(in_flight)))
+    def _():
+        page_dmas(s, 0, first, start)
+
+    def walk(t, carry):
+        buf = lax.rem(first + t, 2)
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            page_dmas(s, t + 1, 1 - buf, start)
+
+        @pl.when(jnp.logical_and(t + 1 == n_tiles, hand_on))
+        def _():
+            page_dmas(after, 0, 1 - buf, start)
+
+        page_dmas(s, t, buf, wait)
+        t0 = t * tile
+
+        @pl.when(t0 + tile > total)
+        def _():
+            # the slot's last tile: rows past its last token (the tail of a
+            # page, pages not fetched) hold whatever was there; as values
+            # they would meet a zero probability, and 0 * NaN is NaN
+            at = lax.broadcasted_iota(jnp.int32, kbuf.shape[1:], 0)
+            kbuf[buf] = jnp.where(at < total - t0, kbuf[buf], 0)
+
+        def score(rs, r):
+            k = kbuf[buf]
+            sc = _dot_f32(q_ref[0, rs, :], k, trans_b=True) * scale
+            row = r + lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+            col = t0 + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            # lane j = row // heads sees positions <= fill + j
+            sc = jnp.where((col - fill) * heads <= row, sc, _MASK)
+            m_new = jnp.maximum(m[rs], jnp.max(sc, -1, keepdims=True))
+            alpha = jnp.exp(m[rs] - m_new)
+            p = jnp.exp(sc - m_new)
+            l[rs] = l[rs] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc[rs] = acc[rs] * alpha + _dot_f32(p.astype(k.dtype),
+                                                 k[:, :kr])
+            m[rs] = m_new
+
+        row_tiles(0, live_tiles, score)
+        return carry
+
+    lax.fori_loop(0, n_tiles, walk, 0)
+    first_buf[0] = lax.rem(first + n_tiles, 2)
+
+    def write(rs, r):
+        row = r + lax.broadcasted_iota(jnp.int32, (row_tile, kr), 0)
+        o_ref[0, rs, :] = jnp.where(row < live_rows, acc[rs] / l[rs],
+                                    0.0).astype(o_ref.dtype)
+
+    def blank(rs, r):
+        o_ref[0, rs, :] = jnp.zeros((row_tile, kr), o_ref.dtype)
+
+    row_tiles(0, live_tiles, write)
+    row_tiles(live_tiles, rows // row_tile, blank)
+
+
+# The paged latent kernel's tiles: cache positions a compute tile (several
+# pages) and query rows a row tile.  On the v5e at 32 heads x 16 lanes and
+# a 640-wide arena in pages of 16, under a load like the serving cell's
+# (45 of 64 slots live, 62k positions): 256 / 512 / 1024 positions read
+# 0.55 / 0.45 / 0.43 ms a call, 128 rows beat 256 (0.45 against 0.53) and
+# 512 (0.72): a decode slot has one live lane of 16 (PERF.md section 6).
+_PAGED_TILE = 512
+_PAGED_ROW_TILE = 128
+
+
+# jitted so that a model's layers share one trace and one lowering of the
+# kernel (tracing it costs the set-up 0.6 s a layer otherwise); what is
+# read when it is traced is therefore an argument
+@functools.partial(jax.jit, static_argnames=("scale", "kr", "interpret",
+                                             "pages", "row_tile"))
+def _paged_latent_pallas(qf, arena, block_table, fill, n_new, scale, kr,
+                         interpret, pages=None, row_tile=None):
+    _bind_pallas()
+    S, C, H, W = qf.shape
+    NB, BS, _ = arena.shape
+    max_blocks = block_table.shape[1]
+    rows = C * H
+    if pages is None:
+        pages = max(1, min(max_blocks, _PAGED_TILE // BS))
+    if row_tile is None:
+        row_tile = _PAGED_ROW_TILE if rows % _PAGED_ROW_TILE == 0 else rows
+    i32 = lambda x: x.astype(jnp.int32)
+    ol, walked = pl.pallas_call(
+        functools.partial(_paged_latent_kernel, scale=scale, kr=kr, heads=H,
+                          bs=BS, pages=pages, max_blocks=max_blocks,
+                          row_tile=row_tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, rows, W), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, rows, kr), lambda s, *_: (s, 0, 0)),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.VMEM((2, pages * BS, W), arena.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((rows, kr), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32)]),
+        out_shape=[sds((S, rows, kr), qf.dtype, qf, arena),
+                   sds((S,), jnp.int32, qf, arena)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_latent_attention",
+        interpret=interpret,
+    )(i32(block_table).reshape(-1), i32(fill), i32(n_new),
+      qf.reshape(S, rows, W), arena)
+    return ol.reshape(S, C, H, kr), walked
+
+
+def _paged_latent_ok(qf, arena, kr) -> bool:
+    if not _cfg.use_pallas():
+        return False
+    if _cfg.INTERPRET:
+        return True
+    # Mosaic: whole 128-lane tiles across, whole sublane tiles a page
+    sublanes = 8 * 4 // arena.dtype.itemsize
+    return (arena.shape[2] % 128 == 0 and kr % 128 == 0
+            and arena.shape[1] % sublanes == 0
+            and (qf.shape[1] * qf.shape[2]) % sublanes == 0)
+
+
+def paged_latent_attention(qf, arena, block_table, fill, n_new, *, scale, kr):
+    """Absorbed latent attention of one serve tick against the paged arena.
+
+    qf: (S, C, H, W) queries already in the latent space (``q_nope W_UK^T``
+    beside the rotated ``q_rope``, pad lanes zero); arena: (NB, BS, W), one
+    row a cached token, key in all ``W`` columns and value in the first
+    ``kr``; block_table: (S, max_blocks) block ids, entries past a slot's
+    live blocks anything (−1 included); fill, n_new: (S,) tokens cached
+    before this tick and lanes that are real.  Lane ``j`` of slot ``s``
+    attends positions ``<= fill[s] + j``.  Returns ``(ol, walked)``: ol
+    (S, C, H, kr) in qf's dtype, zeros for lanes ``>= n_new`` (so every row
+    is finite), and walked (S,) int32, the cache positions the
+    implementation read for each slot.
+
+    Scores, softmax and accumulation are float32; probabilities are cast
+    to the arena's dtype for the second product.  On TPU (and under the
+    interpreter) a Pallas kernel walks each slot's live blocks where they
+    lie, ``ceil((fill + n_new) / BS)`` of them and none for ``n_new == 0``;
+    elsewhere the XLA form gathers and scores all ``max_blocks * BS``
+    positions of every slot."""
+    if _paged_latent_ok(qf, arena, kr):
+        with jax.named_scope("latent_attention"):
+            return _paged_latent_pallas(qf, arena, block_table, fill, n_new,
+                                        scale, kr, _cfg.INTERPRET)
+    return paged_latent_attention_reference(qf, arena, block_table, fill,
+                                            n_new, scale, kr)

@@ -178,6 +178,7 @@ def _kernel_cases():
     import jax.numpy as jnp
 
     from apex_example_tpu import ops
+    from apex_example_tpu.ops import attention
     from apex_example_tpu.ops.fused_optim import adagrad_update_leaf
 
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 256))
@@ -221,6 +222,28 @@ def _kernel_cases():
             with_vjp(lambda q, k, v, bias=bias, causal=causal:
                      ops.flash_attention(q, k, v, bias=bias, causal=causal)),
             (do, q, k, v)))
+
+    # Paged latent attention as serve.py --arch xing4_29b_a4b_cut reaches
+    # it: 32 heads x 16 lanes against a 640-wide bf16 arena in blocks of
+    # 16 whose first 512 columns are the values; slots in prefill, in
+    # decode (block edges on and off), part-way and dead; the slots' blocks
+    # in shuffled places, -1 behind them.  (What each form read, the second
+    # output, differs by design and is not compared.)
+    S, NB, BS, MB = 8, 512, 16, 64
+    fill = jnp.asarray([0, 333, 512, 1008, 15, 16, 700, 90], jnp.int32)
+    n_new = jnp.asarray([16, 1, 0, 16, 1, 1, 5, 16], jnp.int32)
+    blocks = jnp.where(n_new > 0, -(-(fill + n_new) // BS), 0)
+    first = jnp.cumsum(blocks) - blocks
+    place = jax.random.permutation(next(keys), NB)
+    col = jnp.arange(MB)[None, :]
+    table = jnp.where(col < blocks[:, None],
+                      place[(first[:, None] + col) % NB], -1).astype(jnp.int32)
+    qf = (0.5 * rnd((S, 16, 32, 640))).astype(jnp.bfloat16)
+    cases.append((
+        "paged_latent_attention S8 C16 H32 W640 bf16, blocks of 16",
+        lambda qf, arena, table, fill, n_new: attention.paged_latent_attention(
+            qf, arena, table, fill, n_new, scale=0.1, kr=512)[0],
+        (qf, rnd((NB, BS, 640), jnp.bfloat16), table, fill, n_new)))
 
     # Optimizer leaves, smallest BN vector to the embedding table.
     hp = dict(beta1=0.9, beta2=0.999, eps=1e-6, weight_decay=0.01,

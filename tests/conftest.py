@@ -82,3 +82,27 @@ def compile_events():
 
     counts.gate = gate
     return counts
+
+
+@pytest.fixture
+def step_traced_with():
+    """``with step_traced_with(xla=...)``: the serve engine's step traced
+    afresh inside the block, on the kernels' XLA forms (``xla=True``) or on
+    the kernels.  FORCE_XLA is read when a step is traced and is not part
+    of any cache key, so the engine's cached steps are dropped on both
+    sides of the pinned stretch."""
+    import contextlib
+
+    from apex_example_tpu.ops import _config
+    from apex_example_tpu.serve import engine
+
+    @contextlib.contextmanager
+    def pinned(xla: bool):
+        engine._slot_step.cache_clear()
+        try:
+            with _config.force_xla(xla):
+                yield
+        finally:
+            engine._slot_step.cache_clear()
+
+    return pinned

@@ -162,6 +162,48 @@ def test_tpu_tick_updates_the_latent_arena_in_place(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
 
 
+@pytest.fixture(scope="module", params=["kernel", "xla"])
+def latent_tick(request, one_chip):
+    """The latent tick compiled as the chip compiles it, on either form of
+    paged latent attention (ops/attention.py).  The ops' dispatch keys on
+    the live backend (the CPU here) and on the test rig's interpreter
+    switch, so the test steers both for the length of the lowering; the
+    engine's cached step is dropped on either side."""
+    from apex_example_tpu.ops import _config
+    saved = _config.INTERPRET, _config.use_pallas
+    _config.INTERPRET = False
+    _config.use_pallas = lambda: not _config.FORCE_XLA
+    engine_lib._slot_step.cache_clear()
+    try:
+        with _config.force_xla(request.param == "xla"):
+            lowered, arena_bytes, _ = _lowered(False, False, one_chip,
+                                               _latent_model())
+        return request.param, lowered.compile(), arena_bytes
+    finally:
+        _config.INTERPRET, _config.use_pallas = saved
+        engine_lib._slot_step.cache_clear()
+
+
+def test_tpu_tick_walks_the_latent_arena_in_a_kernel(latent_tick):
+    """ISSUE 28: with the kernel the tick holds no [S, H, C, L] float32
+    score tensor and no gathered [S, L, W] view, calls the kernel once a
+    layer and still updates the arena in place; the XLA form of the same
+    op holds both tensors and no such call."""
+    form, compiled, arena_bytes = latent_tick
+    text = compiled.as_text()
+    scores = f"f32[{SLOTS},32,{BS},{MAX_LEN}]"
+    view = f"bf16[{SLOTS},{MAX_LEN},{LATENT_WIDTH}]"
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "paged_latent_attention" in line]
+    if form == "kernel":
+        assert scores not in text and view not in text
+        assert len(calls) == 2                       # one a layer
+    else:
+        assert scores in text and view in text and not calls
+    assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
+
+
 def test_tpu_tick_aliases_the_int8_arena(one_chip):
     lowered, arena_bytes, _ = _lowered(False, True, one_chip)
     mem = lowered.compile().memory_analysis()

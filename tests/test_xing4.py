@@ -141,8 +141,24 @@ def test_paged_prefill_and_decode_match_the_references_one_pass(model,
         np.testing.assert_allclose(row, want[pos], atol=5e-5)
 
 
-def test_served_tokens_are_the_references_first_places(model, params):
-    done = _run(_engine(model, params), _requests(7, seed=2))
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_served_tokens_are_the_references_first_places(model, params, form,
+                                                       step_traced_with):
+    """On both forms of paged latent attention: the Pallas kernel (the
+    interpreter here, Mosaic on the TPU) and the XLA gather form."""
+    with step_traced_with(xla=form == "xla"):
+        eng = _engine(model, params)
+        done = _run(eng, _requests(7, seed=2))
+    # the XLA form scores every position of every slot, the kernel the
+    # live blocks alone
+    walked = np.stack([np.asarray(tree["attn_positions_walked"])
+                       for _, tree in eng.counter_log])
+    assert walked.shape[1:] == (2, SLOTS)
+    if form == "xla":
+        assert (walked == MAX_LEN).all()
+    else:
+        assert 0 < walked.max() <= MAX_LEN and (walked % BS == 0).all()
+        assert walked.mean() < MAX_LEN / 2
     assert len(done) == 7 and all(c.status == "ok" for c in done.values())
     ids = np.zeros((7, MAX_LEN), np.int32)          # one shape, one compile
     for r, c in enumerate(done.values()):
