@@ -9,9 +9,11 @@ Arrivals start ``ramp_s`` before the window so that it opens at steady
 occupancy; only requests due inside the window are counted.  After the
 window the engine is stepped until those have finished (``drain_grace_s``
 at most); one that has not is a failed request.  Judged is the median
-over the window's requests of the time per output token; the time to first
-token, the wait for a slot and how late the generator ran go to the run's
-notes (at this load they swing with every burst: PERF.md section 2).
+over the window's requests of every gap between two consecutive tokens of
+one request (``time_per_token`` below); the per-request means, the time to
+first token, the wait for a slot and how late the generator ran go to the
+run's notes (at this load they swing with every burst or stall: PERF.md
+section 2).
 
 ``correct``: once the window has closed, a sample of the requests it
 finished, drawn from the seed with the longest in it, goes through the
@@ -196,6 +198,103 @@ def pick_sample(done_ok, seed: int, n: int):
     return [longest] + [rest[i] for i in sorted(take)]
 
 
+def request_ticks(starts: np.ndarray, ends: np.ndarray, t_first: float,
+                  t_finish: float, n: int, tolerance: float):
+    """(i0, i1): the ticks that delivered the first and the last of one
+    request's ``n`` tokens.  Tick ``i`` ran over ``[starts[i], ends[i]]`` on
+    the harness's clock and the engine stamps a token inside the tick that
+    delivers it, so a stamp belongs to the tick that holds it (an end lies a
+    little after the stamps taken in its tick: never matched by ``>``), and
+    ticks ``i0 + 1 .. i1`` deliver one token each: the request's gaps are
+    ``diff(ends[i0:i1 + 1])``.  None where that does not hold (a stamp no
+    tick holds; another count of ticks than ``n - 1``: preempted, migrated,
+    several tokens a tick) or where those gaps do not sum to ``t_finish -
+    t_first`` within ``tolerance``."""
+    def holding(t):
+        i = int(np.searchsorted(ends, t, side="left"))
+        return i if i < len(ends) and starts[i] <= t else None
+
+    i0, i1 = holding(t_first), holding(t_finish)
+    if i0 is None or i1 is None or i1 - i0 != n - 1:
+        return None
+    if abs((ends[i1] - ends[i0]) - (t_finish - t_first)) > tolerance:
+        return None
+    return i0, i1
+
+
+def time_per_token(ticks, requests, t_end: float) -> Dict[str, Any]:
+    """Both readings of the time per output token, in ms, over ``requests``
+    = (due instant, tokens asked for, completion or None where it failed or
+    did not finish); ``ticks`` are all of ``drive``'s, ramp and drain
+    included, ``t_end`` the drain's end.
+
+    ``gaps`` (judged by its median): every gap between two consecutive
+    tokens of one request, each one whole tick-to-tick interval of the
+    harness's clock (``request_ticks``).  Nothing is dropped and nothing
+    estimated: a request whose ticks do not match its tokens one for one
+    gives ``n - 1`` copies of its own mean, a failed one ``asked - 1``
+    copies of the drain's end less its due instant.  ``per_request``: each
+    request's mean ``(t_finish - t_first_token) / (n - 1)`` on the engine's
+    stamps, a failed one once at the drain's end less its due instant (the
+    reading judged before PR 30; ``tpot_ms_p95`` still reads it).
+    ``matched`` requests gave gaps of their own, ``stalled`` of them held
+    one of over twice the median gap, ``stamp_to_end_ms`` is how long after
+    the engine's stamp of a last token its tick ended, at most, and ``p50``
+    is the judged number: the nearest-rank median of ``gaps``."""
+    ends = np.array([t[0] for t in ticks], np.float64)
+    starts = ends - np.array([t[1] for t in ticks], np.float64)
+    tolerance = float(np.median(ends - starts)) / 2 if len(ticks) else 0.0
+    per_request, gaps, own, lag = [], [], [], 0.0
+    for due, asked, c in requests:
+        if c is None:
+            per_request.append((t_end - due) * 1e3)
+            gaps.append(np.full(max(asked - 1, 1), per_request[-1]))
+            continue
+        n = len(c.tokens)
+        if n < 2:
+            continue
+        per_request.append((c.t_finish - c.t_first_token) / (n - 1) * 1e3)
+        found = request_ticks(starts, ends, c.t_first_token, c.t_finish, n,
+                              tolerance)
+        if found is None:
+            gaps.append(np.full(n - 1, per_request[-1]))
+            continue
+        own.append(np.diff(ends[found[0]:found[1] + 1]) * 1e3)
+        gaps.append(own[-1])
+        lag = max(lag, (ends[found[1]] - c.t_finish) * 1e3)
+    gaps = np.concatenate(gaps) if gaps else np.zeros(0)
+    long = 2 * float(np.median(gaps)) if gaps.size else 0.0
+    return {"per_request": per_request, "gaps": gaps, "matched": len(own),
+            "p50": harness.quantile(gaps.tolist(), 50) if gaps.size else None,
+            "stalled": sum(bool((g > long).any()) for g in own),
+            "stamp_to_end_ms": lag}
+
+
+def note_time_per_token(per_token: Dict[str, Any], in_window) -> None:
+    """Both medians side by side, and what the window's tick-to-tick
+    interval does with the live slots (the rest of a spread: PERF.md)."""
+    means = per_token["per_request"]
+    harness.note(
+        f"time per output token, ms: median of {per_token['gaps'].size} "
+        f"gaps {per_token['p50'] or 0.0:.4f} (judged), median of "
+        f"{len(means)} requests' means "
+        f"{harness.quantile(means or [0.0], 50):.4f}; "
+        f"{per_token['matched']} requests' ticks match their tokens, "
+        f"{per_token['stalled']} of them held a gap of over twice the "
+        f"median; a tick ends {per_token['stamp_to_end_ms']:.3f} after the "
+        "engine's stamp at most")
+    if len(in_window) < 3:
+        return
+    every = np.diff([t[0] for t in in_window]) * 1e3
+    live = np.array([t[2] for t in in_window[1:]], np.float64)
+    slope, alone = np.polyfit(live, every, 1) if np.ptp(live) \
+        else (0.0, every.mean())
+    harness.note(f"tick to tick in the window, ms: median "
+                 f"{np.median(every):.4f}, mean {every.mean():.4f} at "
+                 f"{live.mean():.2f} live slots; least squares "
+                 f"{alone:.3f} + {slope:.4f} a live slot")
+
+
 def run(cell, cfg, trf, limits, args, devices, t_process, spans,
         compiles, break_step=None) -> Dict[str, Any]:
     key = harness.seed_key(args.seed)
@@ -219,22 +318,22 @@ def run(cell, cfg, trf, limits, args, devices, t_process, spans,
     due = {f"r{p.index}": origin + p.due_s for p in plan}
     asked = {f"r{p.index}": p.max_new for p in plan}
     comps = {c.request.uid: c for c in eng.completions}
-    ok, ttft, tpot, wait, miscount = [], [], [], [], 0
+    ok, ttft, wait, requests, miscount = [], [], [], [], 0
     for uid in sorted(out["counted"]):
         c = comps.get(uid)
         if c is None or c.status != "ok":
             # failed or unfinished: it misses both latencies; the drain's
             # end is the least either can have been
             ttft.append((out["t_end"] - due[uid]) * 1e3)
-            tpot.append(ttft[-1])
+            requests.append((due[uid], asked[uid], None))
             continue
         ok.append(c)
+        requests.append((due[uid], asked[uid], c))
         miscount += int(len(c.tokens) != asked[uid])
         ttft.append((c.t_first_token - due[uid]) * 1e3)
         wait.append((c.t_admitted - due[uid]) * 1e3)
-        if len(c.tokens) > 1:
-            tpot.append((c.t_finish - c.t_first_token)
-                        / (len(c.tokens) - 1) * 1e3)
+    per_token = time_per_token(out["ticks"], requests, out["t_end"])
+    tpot = per_token["per_request"]
     in_window = [t for t in out["ticks"] if w0 <= t[0] <= w1]
     failed = len(out["counted"]) - len(ok)
     late = [out["late"][u] * 1e3 for u in out["counted"] if u in out["late"]]
@@ -246,6 +345,7 @@ def run(cell, cfg, trf, limits, args, devices, t_process, spans,
     slow = sorted(in_window, key=lambda t: -t[1])[:3]
     harness.note("slowest ticks (ms, at s into the window): " + ", ".join(
         f"{t[1] * 1e3:.0f} at {t[0] - t[1] - w0:.1f}" for t in slow))
+    note_time_per_token(per_token, in_window)
 
     del eng
     t_ref = time.perf_counter()
@@ -266,9 +366,10 @@ def run(cell, cfg, trf, limits, args, devices, t_process, spans,
         "device": device,
         "trace": trace,
         "end_to_end": {
-            "tpot_ms_p50": harness.quantile(tpot, 50) if tpot else None,
+            "tpot_ms_p50": per_token["p50"],
             "setup_s": w0 - t_process},
         "facts": {"ticks": in_window, "tpot_ms": tpot,
+                  "token_gaps": int(per_token["gaps"].size),
                   "slots": trf["engine"]["slots"],
                   "pool_tokens": out["pool_tokens"], "reference_s": ref_s,
                   "checked_tokens": got["served_tokens"],
