@@ -119,23 +119,22 @@ class BertSelfAttention(nn.Module):
     # against the filled prefix.  models/gpt.generate drives it.
     decode: bool = False
     # Block-paged slot decode (with decode=True): instead of a dense
-    # [B, max_len, H, D] page per row, K/V live in one shared arena of
-    # shape [kv_num_blocks, kv_block_size, H*D] per layer (heads and
-    # head dim merged: see the paged branch of __call__).  Each batch
-    # row is an independent request slot whose logical sequence is
-    # scattered across arena blocks named by a per-slot block table —
-    # the ``paged`` call argument carries the table plus per-slot fill
-    # levels, new-token counts and copy-on-write pairs, all host-owned
-    # (serve/slots.py is the allocator; there is no device-side index
-    # state).  One compiled step advances every live slot by up to
-    # kv_block_size tokens (chunked prefill) or one token (decode) —
-    # the geometry is static, so the program compiles exactly once.
+    # [B, max_len, H, D] page per row, K/V live in one shared arena a
+    # layer, kv_num_blocks blocks of kv_block_size tokens
+    # (ops/paged_cache.py: the layout and the tick's operations on it).
+    # Each batch row is an independent request slot whose sequence is
+    # scattered across blocks named by a per-slot block table — the
+    # ``paged`` call argument carries the table plus per-slot fill levels,
+    # new-token counts and copy-on-write pairs, all host-owned
+    # (serve/slots.py allocates; there is no device-side index state).
+    # One compiled step advances every live slot by up to kv_block_size
+    # tokens (chunked prefill) or one (decode); the geometry is static.
     slot_decode: bool = False
     kv_num_blocks: int = 0
     kv_block_size: int = 0
     # Quantized paged KV (ISSUE 13, with slot_decode): the arenas store
-    # int8 K/V with bf16 PER-TOKEN BLOCK SCALES ([NB, BS] per arena) —
-    # quantized on the scatter write, dequantized (scale-fused) in the
+    # int8 K/V with bf16 PER-TOKEN scales (a scale table beside each
+    # arena) — quantized on the write, dequantized (scale-fused) in the
     # gathered attention, scale rows copied with their payload rows on
     # COW so prefix-sharing semantics carry over unchanged.  Geometry
     # stays static; the program still compiles exactly once.  The
@@ -190,40 +189,21 @@ class BertSelfAttention(nn.Module):
             from jax import lax as _lax
             cache_ready = self.has_variable("cache", "cached_key")
             if self.slot_decode:
-                # Block-paged arena: one [NB, BS, H*D] K and V buffer
-                # per layer, shared by every slot through per-slot block
-                # tables.  Allocation/refcounts/COW policy are host-side
-                # (serve/slots.py); the compiled step only executes the
-                # table the host hands it.  Heads and head dim are ONE
-                # stored dimension so that the COW block copy, the
-                # per-token write (through the flat [NB*BS, H*D] view,
-                # a bitcast of the same tiles) and the block gather
-                # all index the leading dimension of one tiled layout.
-                # With separate [.., H, D] dimensions each of the three
-                # gets a tiling of its own on the TPU and XLA converts
-                # the whole arena between every pair.  One layout plus
-                # a donated cache (serve/engine.py) lets the step
-                # update the arena in place; [S, L, H, D] exists only
-                # on the gathered view (tests/test_arena_inplace.py).
+                # [NB, BS, H*D] K and V leaves a layer: ops/paged_cache.py
+                # holds the layout and every operation on it; the compiled
+                # step only executes the table the host hands it.
+                from apex_example_tpu.ops import paged_cache
                 NB, BS = self.kv_num_blocks, self.kv_block_size
-                if NB < 1 or BS < 1:
-                    raise ValueError(
-                        "slot_decode is block-paged: clone the model "
-                        "with kv_num_blocks/kv_block_size >= 1 "
-                        f"(got {NB}/{BS})")
                 kv_store = jnp.int8 if self.kv_quant else k.dtype
-                ck = self.variable("cache", "cached_key", jnp.zeros,
-                                   (NB, BS, d), kv_store)
-                cv = self.variable("cache", "cached_value", jnp.zeros,
-                                   (NB, BS, d), kv_store)
+                ck, cv = (paged_cache.variable(self, name, NB, BS, kv_store,
+                                               d)
+                          for name in ("cached_key", "cached_value"))
                 if self.kv_quant:
                     from apex_example_tpu.quant import kv as kv_quant
-                    cks = self.variable("cache", "cached_key_scale",
-                                        jnp.zeros, (NB, BS),
-                                        kv_quant.KV_SCALE_DTYPE)
-                    cvs = self.variable("cache", "cached_value_scale",
-                                        jnp.zeros, (NB, BS),
-                                        kv_quant.KV_SCALE_DTYPE)
+                    cks, cvs = (paged_cache.variable(
+                        self, name, NB, BS, kv_quant.KV_SCALE_DTYPE)
+                        for name in ("cached_key_scale",
+                                     "cached_value_scale"))
             else:
                 if self.kv_quant:
                     raise ValueError("kv_quant quantizes the block-"
@@ -242,96 +222,51 @@ class BertSelfAttention(nn.Module):
                         "paged={'block_table', 'fill', 'n_new', "
                         "'cow_src', 'cow_dst'} (serve/engine.py builds "
                         "it each tick)")
-                NB, BS = self.kv_num_blocks, self.kv_block_size
-                S, C = x.shape[0], x.shape[1]
-                if self.tensor_parallel:
-                    # Under TP the [NB, BS, h*hd] arenas shard over
-                    # heads on 'model' exactly like the dense decode
-                    # cache (h is the outer factor of the merged
-                    # dimension, so a shard holds whole heads);
-                    # re-constraining after every in-place update
-                    # keeps GSPMD from gathering the arena through
-                    # the COW/scatter chain (the block tables, fills
-                    # and scale tables stay replicated — they are
-                    # host policy, not sharded state).
-                    arena = lambda t: constrain(t, None, None, "model")
-                else:
-                    arena = lambda t: t
+                # Under TP the payload leaves shard over heads on 'model'
+                # like the dense decode cache; tables, fills and scale
+                # tables stay replicated (host policy, not sharded state).
+                arena = (lambda t: constrain(t, None, None, "model")) \
+                    if self.tensor_parallel else None
                 table = paged["block_table"]          # [S, max_blocks]
-                fill = paged["fill"]                  # [S] tokens cached
-                n_new = paged["n_new"]                # [S] fed this tick
-                # 1. Copy-on-write: slots whose next write lands in a
-                # shared (immutable) block copy it first — dst -1 means
-                # no COW this tick and the scatter drops out of range.
-                with device_span("kv_cow"):
-                    src = jnp.clip(paged["cow_src"], 0, NB - 1)
-                    dst = jnp.where(paged["cow_dst"] >= 0, paged["cow_dst"],
-                                    NB)
-                    ck.value = arena(ck.value.at[dst].set(ck.value[src],
-                                                          mode="drop"))
-                    cv.value = arena(cv.value.at[dst].set(cv.value[src],
-                                                          mode="drop"))
-                    if self.kv_quant:
-                        # Scales are block-resident state: a COW must carry
-                        # them with the payload, or the copy dequantizes
-                        # under the zero scales of a fresh block.
-                        cks.value = cks.value.at[dst].set(cks.value[src],
-                                                          mode="drop")
-                        cvs.value = cvs.value.at[dst].set(cvs.value[src],
-                                                          mode="drop")
-                # 2. Scatter this tick's K/V through the block table:
-                # token j of slot s lands at logical position fill[s]+j,
-                # physical arena row table[s, pos//BS]*BS + pos%BS.
-                # Lanes past n_new[s] scatter out of range and drop —
-                # the host only maps exclusively-owned blocks for the
-                # write span, so no two slots write one block.
+                # 1. Copy-on-write, scale rows with their payload rows.
+                cow_src, cow_dst = paged["cow_src"], paged["cow_dst"]
+                ck.value, cv.value = paged_cache.cow(
+                    (ck.value, cv.value), cow_src, cow_dst, arena)
+                if self.kv_quant:
+                    cks.value, cvs.value = paged_cache.cow(
+                        (cks.value, cvs.value), cow_src, cow_dst)
+                # 2. This tick's K/V: token j of slot s at fill[s] + j.
                 with device_span("kv_write"):
-                    pos = fill[:, None] + jnp.arange(C)[None, :]
-                    blk = jnp.take_along_axis(
-                        table, jnp.clip(pos // BS, 0, table.shape[1] - 1),
-                        axis=1)
-                    flat = blk * BS + pos % BS
-                    valid = jnp.arange(C)[None, :] < n_new[:, None]
-                    flat = jnp.where(valid, flat, NB * BS).reshape(-1)
-                    if self.kv_quant:
-                        # Quantize on the write: one symmetric max-abs
-                        # scale per token over its [h, hd] vector, scale
-                        # rows scattered through the SAME flat indices as
-                        # the int8 payload (quant/kv.py).
+                    pos = paged["fill"][:, None] \
+                        + jnp.arange(x.shape[1])[None, :]
+                flat = paged_cache.write_rows(table, pos, paged["n_new"],
+                                              NB, BS)
+                if self.kv_quant:
+                    # Quantize on the write: one max-abs scale per token
+                    # over its [h, hd] vector, through the SAME flat rows.
+                    with device_span("kv_write"):
                         k, k_sc = kv_quant.quantize_write(k)
                         v, v_sc = kv_quant.quantize_write(v)
-                        cks.value = cks.value.reshape(NB * BS).at[flat].set(
-                            k_sc.reshape(S * C),
-                            mode="drop").reshape(NB, BS)
-                        cvs.value = cvs.value.reshape(NB * BS).at[flat].set(
-                            v_sc.reshape(S * C),
-                            mode="drop").reshape(NB, BS)
-                    ck.value = arena(
-                        ck.value.reshape(NB * BS, d).at[flat].set(
-                            k.reshape(S * C, d),
-                            mode="drop").reshape(NB, BS, d))
-                    cv.value = arena(
-                        cv.value.reshape(NB * BS, d).at[flat].set(
-                            v.reshape(S * C, d),
-                            mode="drop").reshape(NB, BS, d))
-                # 3. Gather each slot's logical K/V view back out of the
-                # arena ([S, max_blocks*BS, H, D], logical order) and
-                # attend under the per-slot causal live mask: query j
-                # (position fill+j) sees keys at positions <= fill+j —
-                # unwritten/stale arena rows sit beyond it and garbage
-                # lanes of dead slots are discarded by the host.
-                with device_span("kv_gather"):
-                    tbl = jnp.clip(table, 0, NB - 1)
-                    keys = ck.value[tbl].reshape(S, -1, h, hd)
-                    vals = cv.value[tbl].reshape(S, -1, h, hd)
-                    if self.kv_quant:
-                        # Scale-fused dequant of the gathered logical view:
-                        # attention (softmax included) runs at full
-                        # precision on the dequantized values.
-                        keys = kv_quant.dequantize_gather(
-                            keys, cks.value[tbl].reshape(S, -1), self.dtype)
-                        vals = kv_quant.dequantize_gather(
-                            vals, cvs.value[tbl].reshape(S, -1), self.dtype)
+                    cks.value, cvs.value = paged_cache.write(
+                        (cks.value, cvs.value), flat, (k_sc, v_sc))
+                ck.value, cv.value = paged_cache.write(
+                    (ck.value, cv.value), flat, (k, v), arena)
+                # 3. Each slot's logical view ([S, max_blocks*BS, H, D])
+                # and attention under the per-slot causal live mask: query
+                # j (position fill+j) sees keys at positions <= fill+j;
+                # stale rows sit beyond it, and the host discards dead
+                # slots' lanes.
+                keys, vals = paged_cache.gather((ck.value, cv.value), table,
+                                                heads=h)
+                if self.kv_quant:
+                    # scale-fused dequant: attention runs at full precision
+                    k_sc, v_sc = paged_cache.gather((cks.value, cvs.value),
+                                                    table)
+                    with device_span("kv_gather"):
+                        keys = kv_quant.dequantize_gather(keys, k_sc,
+                                                          self.dtype)
+                        vals = kv_quant.dequantize_gather(vals, v_sc,
+                                                          self.dtype)
                 with device_span("paged_attention"):
                     L = keys.shape[1]
                     live = jnp.arange(L)[None, None, :] <= pos[:, :, None]

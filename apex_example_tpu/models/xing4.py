@@ -25,8 +25,8 @@ and attends in the *absorbed*
 form: queries are carried into the latent space (``q_nope W_UK^T``), scores
 and the weighted sum run against the cached latents themselves, and the
 result is carried out through ``W_UV``.  The cache is never up-projected.
-The arena is updated in place exactly as models/bert.py's (one physical
-layout for copy-on-write, write and read; the cache donated).
+The leaf, its copy-on-write, write and gather are ops/paged_cache.py's, as
+models/bert.py's are (one layout, the cache donated: updated in place).
 
 Scores, mask, softmax and weighted sum of the paged path are one op,
 ``ops.attention.paged_latent_attention``, in two forms chosen by the
@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_example_tpu.obs.spans import device_span
+from apex_example_tpu.ops import paged_cache
 from apex_example_tpu.ops.attention import paged_latent_attention
 from apex_example_tpu.transformer.expert_parallel import (dropless_experts,
                                                           dropless_route,
@@ -312,26 +313,13 @@ class LatentAttention(nn.Module):
                 raise ValueError("this model decodes through the block-"
                                  "paged slot path only (slot_decode=True)")
             NB, BS = self.kv_num_blocks, self.kv_block_size
-            # stored width: kr + dr rounded up to whole 128-lane tiles.
-            # The TPU's tiled layout pads 576 to 640 lanes in memory
-            # either way, but with a logical width that is not a whole
-            # number of tiles XLA gives the arena another layout on the
-            # way out than on the way in and copies it twice a layer
-            # (AOT compile for v5e, PR 27); the pad lanes hold zeros.
-            W = -(-(kr + dr) // 128) * 128
-            if NB < 1 or BS < 1:
-                raise ValueError(
-                    "slot_decode is block-paged: clone the model with "
-                    f"kv_num_blocks/kv_block_size >= 1 (got {NB}/{BS})")
             cache_ready = self.has_variable("cache", "cached_latent")
-            # ONE head-less [NB, BS, W] leaf: c_kv (after the norm) and
-            # k_rope (after the rotation) side by side, so that the COW
-            # copy, the per-token write (flat [NB*BS, W] view) and the
-            # read (the kernel's page DMAs; the XLA form's block gather)
-            # all index the leading dimension of one layout and the
-            # donated arena is updated in place.
-            cl = self.variable("cache", "cached_latent", jnp.zeros,
-                               (NB, BS, W), self.dtype)
+            # ONE head-less [NB, BS, W] leaf (ops/paged_cache.py): c_kv
+            # (after the norm) and k_rope (after the rotation) side by
+            # side, kr + dr values stored in whole 128-lane tiles.
+            W = paged_cache.lane_tiles(kr + dr)
+            cl = paged_cache.variable(self, "cached_latent", NB, BS,
+                                      self.dtype, W)
             if cache_ready:
                 if paged is None:
                     raise ValueError(
@@ -340,24 +328,14 @@ class LatentAttention(nn.Module):
                         "'cow_dst'} (serve/engine.py builds it each tick)")
                 S, C = B, L
                 table, n_new = paged["block_table"], paged["n_new"]
-                with device_span("kv_cow"):
-                    src = jnp.clip(paged["cow_src"], 0, NB - 1)
-                    dst = jnp.where(paged["cow_dst"] >= 0, paged["cow_dst"],
-                                    NB)
-                    cl.value = cl.value.at[dst].set(cl.value[src],
-                                                    mode="drop")
+                cl.value = paged_cache.cow(cl.value, paged["cow_src"],
+                                           paged["cow_dst"])
+                flat = paged_cache.write_rows(table, pos, n_new, NB, BS)
                 with device_span("kv_write"):
-                    blk = jnp.take_along_axis(
-                        table, jnp.clip(pos // BS, 0, table.shape[1] - 1),
-                        axis=1)
-                    flat = blk * BS + pos % BS
-                    valid = jnp.arange(C)[None, :] < n_new[:, None]
-                    flat = jnp.where(valid, flat, NB * BS).reshape(-1)
                     lat = jnp.concatenate(
                         [ckv, k_rope,
                          jnp.zeros((S, C, W - kr - dr), self.dtype)], -1)
-                    cl.value = cl.value.reshape(NB * BS, W).at[flat].set(
-                        lat.reshape(S * C, W), mode="drop").reshape(NB, BS, W)
+                cl.value = paged_cache.write(cl.value, flat, lat)
                 with device_span("latent_attention"):
                     # absorbed: queries into the latent space, scores and
                     # the weighted sum against the cached latents, out

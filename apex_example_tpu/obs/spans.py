@@ -37,8 +37,9 @@ from apex_example_tpu.obs import trace as trace_lib
 # Canonical labels.  The host-side entries are emitted by the train
 # loop through span(); the device-side ones through device_span by
 # engine.make_train_step, the loss functions of workloads.py, the models'
-# heads, the paged branch of models/bert.py, models/xing4.py, the dropless
-# layer of transformer/expert_parallel.py and serve/engine._slot_step.
+# heads, ops/paged_cache.py (kv_cow, kv_write, kv_gather), the paged branch
+# of models/bert.py, models/xing4.py, the dropless layer of
+# transformer/expert_parallel.py and serve/engine._slot_step.
 # The serve tick's host phases are tickprof.ENGINE_PHASES (a jax-free
 # table).  Keep README's "Span naming" paragraph in sync.
 PHASES = (

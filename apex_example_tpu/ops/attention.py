@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_example_tpu.ops import _config as _cfg
+from apex_example_tpu.ops import paged_cache
 from apex_example_tpu.ops._vma import sds
 
 # Finite stand-in for -inf: exp(_MASK - anything_reasonable) == 0 in fp32,
@@ -555,9 +556,7 @@ def paged_latent_attention_reference(qf, arena, block_table, fill, n_new,
     softmaxed and multiplied back.  Same contract as
     :func:`paged_latent_attention`; ``walked`` is ``L`` for every slot."""
     S, C = qf.shape[:2]
-    NB, _, W = arena.shape
-    with jax.named_scope("kv_gather"):
-        view = arena[jnp.clip(block_table, 0, NB - 1)].reshape(S, -1, W)
+    view = paged_cache.gather(arena, block_table)
     with jax.named_scope("latent_attention"):
         L = view.shape[1]
         lane = jnp.arange(C)[None, :]

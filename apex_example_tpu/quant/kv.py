@@ -1,7 +1,7 @@
 """Quantized paged-KV helpers: the in-step scatter/gather numerics.
 
-The block arena (serve/slots.py geometry, models/bert.py execution)
-stores int8 K/V with BLOCK-RESIDENT scales: per layer, alongside each
+The block arena (ops/paged_cache.py layout and operations, serve/slots.py
+policy, models/bert.py attention) stores int8 K/V with BLOCK-RESIDENT scales: per layer, alongside each
 ``[NB, BS, H*D]`` int8 arena sits a ``[NB, BS]`` bf16 scale table —
 one symmetric max-abs scale per cached token (the [H, D] vector a
 block row holds, stored as one merged dimension).  Scales live AT

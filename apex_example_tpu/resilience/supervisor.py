@@ -84,7 +84,7 @@ from typing import Any, Dict, List, Optional
 # Keep in sync with apex_example_tpu/obs/schema.py (SCHEMA_VERSION) and
 # resilience/preemption.py (EX_TEMPFAIL) — this module must not import
 # either (jax-free contract; same for obs/trace.py's APEX_TRACE_ID).
-SCHEMA = 17
+SCHEMA = 18
 EX_TEMPFAIL = 75
 TRACE_ID_ENV = "APEX_TRACE_ID"
 

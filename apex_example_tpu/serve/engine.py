@@ -113,37 +113,51 @@ def _pct_dict(vals_ms: List[float]) -> Dict[str, float]:
 
 
 @functools.lru_cache(maxsize=8)
-def _slot_step(dec, dequant_weights: bool = False):
+def _slot_step(dec, dequant_weights: bool = False, lanes: bool = False):
     """One compiled decode step for a PAGED slot-decode model clone
-    (cached on the frozen module config — block geometry included —
-    with params as an argument, the same contract as
-    models/gpt._decode_loop).  ``tok`` is [SLOTS, C] with C =
-    kv_block_size: a prefill chunk for slots inside their prompt, one
-    token (lane 0) for decoding slots; ``n_new`` says how many lanes
-    are real per slot, and sampling reads the logits AFTER each slot's
-    last real token (a model whose paged head runs on that lane alone
-    hands back ``[SLOTS, 1, V]`` logits and the take is skipped).  COW
-    copies, the block-table K/V scatter and the gathered-attention live
-    mask all run inside this one program (models/bert.py,
-    models/xing4.py).  A model that sows into a ``counters`` collection
-    (an expert layer's per-tick load) gets it back as a fourth output.
-    ``cache`` is DONATED: the arena leaves keep one
-    physical layout from argument to result, so the program updates
-    them in place and the leaves passed in are deleted by the call —
-    the caller must rebind its cache from the first output (ServeEngine
-    does, straight after the call) and hold the old leaves nowhere
-    else.  Besides the sampled tokens it returns a per-slot
-    logits-finite mask: argmax/categorical over NaN logits yield an
-    IN-RANGE index, so a token-range check alone can never see real NaN
-    fallout — the finiteness of the logits themselves is the signal,
-    and computing it here fuses it into the decode program.
+    (cached on the frozen module config, block geometry included, with
+    params as an argument: models/gpt._decode_loop's contract).  ``tok``
+    is [SLOTS, C]: a prefill chunk for slots inside their prompt, one
+    token (lane 0) for decoding slots; ``n_new`` says how many lanes are
+    real per slot, and sampling reads the logits AFTER each slot's last
+    real token (a model whose paged head runs on that lane alone hands
+    back ``[SLOTS, 1, V]`` logits and the take is skipped).  COW copies,
+    the block-table K/V scatter and the gathered-attention live mask all
+    run inside this one program (ops/paged_cache.py, called from the
+    models).  A model that sows into a ``counters`` collection (an expert
+    layer's per-tick load) gets it back as a fourth output.
+    ``cache`` is DONATED: the arena leaves keep one physical layout from
+    argument to result, so the program updates them in place and the
+    leaves passed in are deleted by the call — the caller must rebind its
+    cache from the first output (ServeEngine does, straight after the
+    call) and hold the old leaves nowhere else.  Besides the sampled
+    tokens it returns a per-slot logits-finite mask: argmax/categorical
+    over NaN logits yield an IN-RANGE index, so only the finiteness of
+    the logits themselves can see NaN fallout, and computing it here
+    fuses it into the decode program.
 
     ``dequant_weights`` (ISSUE 13): params arrive as quant/weights.py's
     int8/fp8 {qvalue, scale} leaves and the dequant is the step's FIRST
     traced op — the low-bit bytes are the step's arguments (what HBM
-    streams), and XLA fuses the scale multiply into each consuming
-    matmul.  Part of the lru_cache key: arming quantization builds ONE
-    new program; re-running either variant reuses its compile."""
+    streams), and XLA fuses the scale multiply into each consuming matmul.
+
+    ``lanes`` (ISSUE 18): the speculative engine's program; two more
+    outputs for the accept/reject harvest are its one static difference
+    (and no ``counters``) —
+
+      * ``lane_greedy`` [SLOTS, C]: argmax over every lane's logits.
+        Lane j's logits condition on lanes 0..j (causal live mask), so
+        lane_greedy[s, j] is the model's greedy continuation after the
+        j-th fed token; comparing it against the NEXT draft lane is the
+        whole accept rule, on logits chunked prefill computes anyway.
+      * ``lane_finite`` [SLOTS, C]: per-lane logits-finiteness, so NaN
+        fallout in ANY verified lane poisons the slot.
+
+    ``nxt`` samples from the last REAL lane either way, so sampled-
+    temperature slots in a speculative batch behave token-identically to
+    the plain path.  Both flags are lru_cache keys: arming quantization
+    or --speculate K (geometry [SLOTS, max(BS, K+1)]) builds ONE new
+    program; re-running a variant reuses its compile."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, cache, tok, block_table, fill, n_new, cow_src,
@@ -154,9 +168,10 @@ def _slot_step(dec, dequant_weights: bool = False):
                 params = _qw.dequantize_tree(params)
         paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
                  "cow_src": cow_src, "cow_dst": cow_dst}
-        logits, mut = dec.apply({"params": params, "cache": cache}, tok,
-                                train=False, paged=paged,
-                                mutable=["cache", "counters"])
+        logits, mut = dec.apply(
+            {"params": params, "cache": cache}, tok, train=False,
+            paged=paged,
+            mutable=["cache"] if lanes else ["cache", "counters"])
         with device_span("sample"):
             if logits.shape[1] == tok.shape[1]:
                 idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
@@ -167,60 +182,15 @@ def _slot_step(dec, dequant_weights: bool = False):
                 last = logits[:, 0]
             nxt = sample_tokens(rng, last, temperature, top_k)
             finite = jnp.all(jnp.isfinite(last), axis=-1)
+            out = (mut["cache"], nxt, finite)
+            if lanes:
+                out += (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                        jnp.all(jnp.isfinite(logits), axis=-1))
         if mut.get("counters"):
             # what the model counted this tick (an expert layer's load):
             # a fourth output, kept unfetched by the engine
-            return mut["cache"], nxt, finite, mut["counters"]
-        return mut["cache"], nxt, finite
-
-    return step
-
-
-@functools.lru_cache(maxsize=8)
-def _slot_step_spec(dec, dequant_weights: bool = False):
-    """The speculative variant of _slot_step (ISSUE 18): identical
-    multi-lane dispatch — [SLOTS, C] tokens, per-slot n_new lane counts,
-    COW + scatter + live mask all inside the one program, the cache
-    donated and updated in place — plus two
-    extra outputs the accept/reject harvest needs host-side:
-
-      * ``lane_greedy`` [SLOTS, C]: argmax over every lane's logits.
-        Lane j's logits condition on lanes 0..j (causal live mask), so
-        lane_greedy[s, j] is the model's greedy continuation after the
-        j-th fed token — comparing it against the NEXT draft lane is
-        the whole accept rule, and it reuses the same all-lane logits
-        the chunked-prefill path already computes and discards.
-      * ``lane_finite`` [SLOTS, C]: per-lane logits-finiteness, so NaN
-        fallout in ANY verified lane poisons the slot, not just the
-        last one.
-
-    ``nxt`` still samples from the last REAL lane exactly like
-    _slot_step, so sampled-temperature slots riding in the same batch
-    behave token-identically to the plain path.  Cached per (module
-    config, dequant flag): arming --speculate K builds exactly ONE new
-    program for the [SLOTS, max(BS, K+1)] geometry."""
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def step(params, cache, tok, block_table, fill, n_new, cow_src,
-             cow_dst, rng, temperature, top_k):
-        if dequant_weights:
-            from apex_example_tpu.quant import weights as _qw
-            with device_span("dequant_weights"):
-                params = _qw.dequantize_tree(params)
-        paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
-                 "cow_src": cow_src, "cow_dst": cow_dst}
-        logits, mut = dec.apply({"params": params, "cache": cache}, tok,
-                                train=False, paged=paged,
-                                mutable=["cache"])
-        with device_span("sample"):
-            idx = jnp.clip(n_new - 1, 0, tok.shape[1] - 1)
-            last = jnp.take_along_axis(logits, idx[:, None, None],
-                                       axis=1)[:, 0]
-            nxt = sample_tokens(rng, last, temperature, top_k)
-            finite = jnp.all(jnp.isfinite(last), axis=-1)
-            lane_greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            lane_finite = jnp.all(jnp.isfinite(logits), axis=-1)
-        return mut["cache"], nxt, finite, lane_greedy, lane_finite
+            out += (mut["counters"],)
+        return out
 
     return step
 
@@ -508,17 +478,12 @@ class ServeEngine:
         # prefill role instruments under its own name: its program is
         # [SLOTS, block_size]-wide while the decode role's is
         # [SLOTS, 1]-wide — one program per role, each compiling once.
-        if self.speculate:
-            self._step_fn = costmodel_lib.instrument(
-                "serve_spec_step",
-                _slot_step_spec(self.pool.dec,
-                                dequant_weights=weight_quant != "none"))
-        else:
-            self._step_fn = costmodel_lib.instrument(
-                "serve_prefill_step" if role == "prefill"
-                else "serve_decode_step",
-                _slot_step(self.pool.dec,
-                           dequant_weights=weight_quant != "none"))
+        self._step_fn = costmodel_lib.instrument(
+            "serve_spec_step" if self.speculate
+            else "serve_prefill_step" if role == "prefill"
+            else "serve_decode_step",
+            _slot_step(self.pool.dec, dequant_weights=weight_quant != "none",
+                       lanes=bool(self.speculate)))
         self._t0 = time.perf_counter()
         self._tokens_out = 0
         self._occupancy_sum = 0

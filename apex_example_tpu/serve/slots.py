@@ -1,17 +1,15 @@
-"""Block-paged KV cache: arena + free-list allocator + per-slot tables.
+"""Block-paged KV cache, the host's side: free-list allocator, per-slot
+block tables, admission and reservation.
 
 The dense layout this replaces pinned a [SLOTS, max_len, H, D] page per
 slot, so HBM cost scaled with ``max_len`` regardless of request length
 (PR 6's gauges measured ~92% ``kv_waste_pct`` on the smoke workload).
-Here every layer owns shared arena leaves of shape
-``[num_blocks, block_size, W]`` (``W`` is the model's: merged heads times
-head size for per-head K and V, latent rank plus rotary size for a latent
-cache) and a request maps only the blocks
-its sequence actually touches, through a per-slot block table
-(``[SLOTS, max_blocks]`` int32) the attention layers gather through
-inside the one compiled decode step (models/bert.py).  Geometry stays
-static — table CONTENTS are data, so the program still compiles exactly
-once.
+Here a request maps only the arena blocks its sequence actually touches,
+through a per-slot block table (``[SLOTS, max_blocks]`` int32) that the
+attention layers read inside the one compiled decode step.  The arena's
+layout, the tick's operations on it and which leaves of a cache tree are
+block-resident are ops/paged_cache.py's; this module holds policy and
+knows no leaf by name or shape.  Table CONTENTS are data: one compile.
 
 Host-side policy (this module, no jax in the allocator):
 
@@ -45,7 +43,6 @@ produces those logits.
 
 from __future__ import annotations
 
-import functools
 import math
 import time
 from collections import OrderedDict
@@ -56,45 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex_example_tpu.ops import paged_cache
 from apex_example_tpu.serve.queue import Request
-
-
-def _block_leaf(leaf, num_blocks: int, block_size: int) -> int:
-    """Is this cache leaf block-resident state, by shape alone?  2 for an
-    arena payload ``[num_blocks, block_size, W]`` (whatever the model
-    keeps a token a layer: merged K or V heads, a latent), 1 for a
-    per-token scale table ``[num_blocks, block_size]`` that rides along
-    under kv_quant (ISSUE 13: accounting sums BOTH so the byte gauges stay
-    honest about the quantized layout's true footprint), 0 for anything
-    else.  The pool knows no leaf by name."""
-    if leaf.shape[:2] != (num_blocks, block_size):
-        return 0
-    return {3: 2, 2: 1}.get(leaf.ndim, 0)
-
-
-def _path_str(path) -> str:
-    """Stable string key for one cache leaf path — the identity KV
-    handoff payloads are keyed by on both sides of the transport."""
-    return "/".join(getattr(p, "key", getattr(p, "name", str(p)))
-                    for p in path)
-
-
-@functools.lru_cache(maxsize=8)
-def _fused_block_scatter(shapes):
-    """ONE jitted scatter writing a handoff payload into every arena
-    leaf in a single dispatch (cached per geometry — ``shapes`` is the
-    arena leaf shape tuple, so every admission at one geometry reuses
-    one executable).  Out-of-range pad lanes drop.  The leaves are
-    DONATED (an admission writes a few blocks in place, it does not
-    copy every arena): the caller rebinds its cache from the result."""
-    del shapes                        # cache key only; shapes ride args
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def scatter(leaves, idx, rows):
-        return tuple(l.at[idx].set(r, mode="drop")
-                     for l, r in zip(leaves, rows))
-
-    return scatter
 
 
 @dataclass
@@ -328,11 +288,9 @@ class BlockPool:
     ``model`` is the plain (training) module; the pool derives the
     paged slot-decode clone and allocates the per-layer arenas via an
     abstract init trace (no real forward runs), exactly like
-    models/gpt.generate.  Every cache leaf shaped ``[num_blocks,
-    block_size, W]`` is an arena page leaf, whatever its name and width
-    (``_block_leaf``): handoff, migration and the byte accounting carry
-    whatever page leaves the model allocates.  ``num_blocks`` defaults
-    to the dense
+    models/gpt.generate.  Handoff, migration and the byte accounting
+    carry whatever block-resident leaves the model allocates
+    (``paged_cache.block_leaves``).  ``num_blocks`` defaults to the dense
     layout's capacity (``num_slots * ceil(max_len / block_size)``), so
     the default arena reserves the same HBM the old [SLOTS, max_len]
     pages did — the win is that admission now shares and packs it.
@@ -380,18 +338,25 @@ class BlockPool:
             jnp.zeros((num_slots, max_len), jnp.int32))["cache"]
         self.cache = jax.tree_util.tree_map(
             lambda t: jnp.zeros(t.shape, t.dtype), shapes)
-        if self.kv_quant and not any(
-                k == 1 for _, _, k in self._block_leaves()):
+        # what the byte gauges need, fixed with the geometry (scale tables
+        # counted: the quantized layout's true footprint)
+        leaves = paged_cache.block_leaves(shapes, num_blocks, block_size)
+        payload = [leaf for _, leaf, kind in leaves
+                   if kind == paged_cache.PAYLOAD]
+        if self.kv_quant and len(payload) == len(leaves):
             raise ValueError(
                 "kv_quant: the model allocated no per-token scale table "
                 "beside its arena leaves — quantized paged KV is not built "
                 "for this cache layout")
+        self._kv_reserved = sum(leaf.size * leaf.dtype.itemsize
+                                for _, leaf, _ in leaves)
+        self._kv_elems = sum(leaf.size for leaf in payload)
+        self._kv_dtype = str(payload[0].dtype) if payload else "none"
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.table = np.zeros((num_slots, self.max_blocks), np.int32)
         self.slots: List[Optional[Slot]] = [None] * num_slots
         self._free: List[int] = list(range(num_slots))[::-1]  # pop()=slot 0
         self._reserved_total = 0
-        self._kv_reserved: Optional[int] = None
         self.cow_copies = 0
         self._shared_tokens = 0
         self._prompt_tokens = 0
@@ -399,27 +364,12 @@ class BlockPool:
 
     # --------------------------------------------------------- sharding
 
-    def _block_leaves(self):
-        """``(path, leaf, kind)`` of every block-resident cache leaf
-        (kind 2 payload, 1 scale table: ``_block_leaf``)."""
-        out = []
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.cache)[0]:
-            kind = _block_leaf(leaf, self.num_blocks, self.block_size)
-            if kind:
-                out.append((path, leaf, kind))
-        return out
-
     def shard(self, mesh) -> None:
-        """TP-shard the arenas over the mesh's ``model`` axis: every
-        [NB, BS, W] payload leaf is placed head-sharded (heads are
-        the outer factor of the merged last dimension, so a shard holds
-        whole heads: the same split the dense decode cache uses under
-        TP), scale tables replicated.  The block tables, free list and
-        admission logic stay host-side and replicated — sharding is a
-        placement of the SAME geometry, so allocation/COW/refcount
-        policy is untouched and the compiled step lowers once with
-        GSPMD shardings."""
+        """TP-shard the arenas over the mesh's ``model`` axis
+        (``paged_cache.shard``).  Tables, free list and admission stay
+        host-side and replicated: a placement of the SAME geometry, so
+        policy is untouched and the step lowers once with GSPMD
+        shardings."""
         from apex_example_tpu.parallel.mesh import MODEL_AXIS
         if mesh.shape.get(MODEL_AXIS, 1) > 1 \
                 and not self.dec.tensor_parallel:
@@ -428,20 +378,8 @@ class BlockPool:
                 "but the model was built without tensor_parallel=True: "
                 "its arena leaves have no head axis to shard")
         self._mesh = mesh
-        self.cache = jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(leaf, self._leaf_sharding(leaf)),
-            self.cache)
-
-    def _leaf_sharding(self, leaf):
-        """The NamedSharding one cache leaf gets under the registered
-        mesh: heads over 'model' for arena payloads, replicated for
-        scale tables (and anything else)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from apex_example_tpu.parallel.mesh import MODEL_AXIS
-        if _block_leaf(leaf, self.num_blocks, self.block_size) == 2:
-            return NamedSharding(self._mesh, P(None, None, MODEL_AXIS))
-        return NamedSharding(self._mesh, P())
+        self.cache = paged_cache.shard(self.cache, mesh, self.num_blocks,
+                                       self.block_size)
 
     # ------------------------------------------------------------ state
 
@@ -560,12 +498,8 @@ class BlockPool:
         if slot is None:
             raise RuntimeError(f"slot {idx} is free — nothing to hand off")
         n = slot.n_mapped
-        bids = jnp.asarray(np.ascontiguousarray(self.table[idx, :n]))
-        # np.array (not asarray): an OWNED writable host copy —
-        # np.asarray of a jax array is a read-only view that would pin
-        # the gather buffer across the transport.
-        payload = {_path_str(path): np.array(leaf[bids])
-                   for path, leaf, _ in self._block_leaves()}
+        payload = paged_cache.extract(self.cache, self.table[idx, :n],
+                                      self.num_blocks, self.block_size)
         return slot.cursor, n, payload
 
     def blocks_needed_prefilled(self, request: Request) -> int:
@@ -606,7 +540,11 @@ class BlockPool:
                 f"{request.uid}: payload covers {n_pay} blocks but the "
                 f"clamped sequence only needs {total}")
         bids = [self.alloc.alloc() for _ in range(n_pay)]
-        self._scatter_payload(bids, n_pay, payload)
+        # one jitted scatter whatever the handoff's size: admission sits
+        # inside the decode worker's TPOT window.  The leaves are donated.
+        self.cache = paged_cache.insert(
+            self.cache, bids, payload, self.num_blocks, BS,
+            pad_to=self.max_blocks, mesh=self._mesh)
         idx = self._free.pop()
         self.table[idx, :] = 0
         self.table[idx, :n_pay] = bids
@@ -620,58 +558,6 @@ class BlockPool:
         self._reserved_total += total - n_pay
         self._prompt_tokens += len(request.prompt)
         return idx
-
-    def _scatter_payload(self, bids: List[int], n_pay: int,
-                         payload: Dict[str, "np.ndarray"]) -> None:
-        """Scatter handoff payload rows into this pool's arenas at the
-        freshly allocated ``bids``.  Indices and rows are padded to
-        ``max_blocks`` so ONE jitted scatter (all arena leaves fused
-        into a single dispatch — admission latency sits inside the
-        decode worker's TPOT window) serves every handoff size: pad
-        lanes index row NB and drop.  Under a registered mesh the
-        leaves are placed back on their arena shardings afterwards."""
-        pad = max(self.max_blocks, n_pay)
-        idx = np.full((pad,), self.num_blocks, np.int32)
-        idx[:n_pay] = bids
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        NB, BS = self.num_blocks, self.block_size
-        arena, rows_in, out = [], [], []
-        for path, leaf in leaves:
-            if not _block_leaf(leaf, NB, BS):
-                continue
-            key = _path_str(path)
-            if key not in payload:
-                raise ValueError(
-                    f"handoff payload missing arena leaf {key!r} — "
-                    "prefill/decode geometry or kv_quant mismatch")
-            rows = payload[key]
-            if rows.shape[0] != n_pay or rows.shape[1:] != leaf.shape[1:]:
-                raise ValueError(
-                    f"handoff payload {key!r} shape {tuple(rows.shape)} "
-                    f"does not fit arena {tuple(leaf.shape)} "
-                    f"({n_pay} blocks)")
-            if str(rows.dtype) != str(leaf.dtype):
-                raise ValueError(
-                    f"handoff payload {key!r} dtype {rows.dtype} vs "
-                    f"arena {leaf.dtype} — the transport is "
-                    "storage-dtype-exact (int8 stays int8)")
-            padded = np.zeros((pad,) + tuple(rows.shape[1:]),
-                              dtype=rows.dtype)
-            padded[:n_pay] = rows
-            arena.append(leaf)
-            rows_in.append(padded)
-        new = _fused_block_scatter(tuple(a.shape for a in arena))(
-            tuple(arena), jnp.asarray(idx),
-            tuple(jnp.asarray(r) for r in rows_in))
-        it = iter(new)
-        for _, leaf in leaves:
-            if _block_leaf(leaf, NB, BS):
-                leaf = next(it)
-                if self._mesh is not None:
-                    leaf = jax.device_put(leaf,
-                                          self._leaf_sharding(leaf))
-            out.append(leaf)
-        self.cache = jax.tree_util.tree_unflatten(treedef, out)
 
     def _alloc_for(self, slot: Slot) -> int:
         if slot.reserved < 1:
@@ -738,10 +624,6 @@ class BlockPool:
         ``num_blocks`` makes this equal to the dense layout's
         reservation — the paged win shows up in the per-tick committed/
         live gauges, not here."""
-        if self._kv_reserved is None:       # geometry is fixed; compute once
-            self._kv_reserved = sum(
-                leaf.size * leaf.dtype.itemsize
-                for _, leaf, _ in self._block_leaves())
         return self._kv_reserved
 
     def kv_bytes_per_token(self) -> int:
@@ -757,17 +639,12 @@ class BlockPool:
         arena of this geometry (2 bytes per K/V element, no scales) —
         the bf16-equivalent baseline the quant compression ratio and
         the ci_gate ``--quant-stream`` floor are computed against."""
-        elems = sum(leaf.size for _, leaf, kind in self._block_leaves()
-                    if kind == 2)
-        return elems * 2 // (self.num_blocks * self.block_size)
+        return self._kv_elems * 2 // (self.num_blocks * self.block_size)
 
     @property
     def kv_dtype(self) -> str:
         """The arena payload dtype name ("int8" under kv_quant)."""
-        for _, leaf, kind in self._block_leaves():
-            if kind == 2:
-                return str(leaf.dtype)
-        return "none"                        # zero-layer model; untestable
+        return self._kv_dtype
 
     def kv_bytes_live(self) -> int:
         """Bytes of KV the live slots logically hold (per-slot fill

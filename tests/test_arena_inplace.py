@@ -102,8 +102,7 @@ def _lowered(speculative: bool, kv_quant: bool, sharding, model=None):
             sds((SLOTS,), i32), sds((SLOTS,), i32), sds((SLOTS,), i32),
             sds((SLOTS,), i32), sds((2,), jnp.uint32),
             sds((SLOTS,), jnp.float32), sds((SLOTS,), i32))
-    step = (engine_lib._slot_step_spec if speculative
-            else engine_lib._slot_step)(dec)
+    step = engine_lib._slot_step(dec, lanes=speculative)
     leaves = jax.tree_util.tree_leaves(shapes["cache"])
     arena_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
     arena_elems = max(l.size for l in leaves)

@@ -426,9 +426,9 @@ _ARENA_MODES = {"float32": {}, "kv_quant": {"kv_quant": True},
 
 def _arena_leaves(pool):
     """Leaves by the path string handoff payloads are keyed by."""
-    from apex_example_tpu.serve.slots import _path_str
-    return {_path_str(path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(pool.cache)[0]}
+    from apex_example_tpu.ops import paged_cache
+    return {path: leaf for path, leaf, _ in paged_cache.block_leaves(
+        pool.cache, pool.num_blocks, pool.block_size)}
 
 
 @pytest.mark.parametrize("mode", sorted(_ARENA_MODES) + ["cost_model"])

@@ -44,8 +44,8 @@ from apex_example_tpu.models.gpt import generate, gpt_tiny
 from apex_example_tpu.obs import schema as obs_schema
 from apex_example_tpu.resilience import EX_TEMPFAIL, FaultPlan
 from apex_example_tpu.resilience.faults import SERVE_KINDS
-from apex_example_tpu.serve import (BlockPool, Request, RequestQueue,
-                                    ServeEngine, parse_range,
+from apex_example_tpu.serve import (STATUSES, BlockPool, Request,
+                                    RequestQueue, ServeEngine, parse_range,
                                     synthetic_requests)
 
 pytestmark = pytest.mark.serve
@@ -602,9 +602,8 @@ def test_deadline_expires_queued_request_without_admitting(
             for i in range(SLOTS)]
     late = Request(prompt=[5, 6], max_new_tokens=4, deadline_step=5)
     eng = _run_engine_res(model, params, hogs + [late])
-    assert eng.counts == {"ok": SLOTS, "timeout": 1, "shed": 0,
-                          "cancelled": 0, "failed": 0, "drained": 0,
-                          "rejected": 0, "handoff": 0}
+    assert eng.counts == {s: 0 for s in STATUSES} | {"ok": SLOTS,
+                                                     "timeout": 1}
     comp = next(c for c in eng.completions if c.request is late)
     assert comp.status == "timeout" and comp.finish_reason == "timeout"
     assert comp.slot == -1 and comp.admitted_step == -1
@@ -886,9 +885,7 @@ def test_real_nan_params_trip_nonfinite_logits_guard(model_and_params):
                                  params)
     eng = _run_engine_res(model, bad,
                           [Request(prompt=[1, 2, 3], max_new_tokens=4)])
-    assert eng.counts == {"ok": 0, "timeout": 0, "shed": 0,
-                          "cancelled": 0, "failed": 1, "drained": 0,
-                          "rejected": 0, "handoff": 0}
+    assert eng.counts == {s: 0 for s in STATUSES} | {"failed": 1}
     comp = eng.completions[0]
     assert comp.status == "failed" and comp.tokens == []
     assert "non-finite logits" in comp.error

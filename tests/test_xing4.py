@@ -24,6 +24,7 @@ if REPO not in sys.path:
 
 from apex_example_tpu.models import xing4  # noqa: E402
 from apex_example_tpu.models.gpt import gpt_tiny  # noqa: E402
+from apex_example_tpu.ops import paged_cache  # noqa: E402
 from apex_example_tpu.serve import Request, ServeEngine  # noqa: E402
 from apex_example_tpu.serve.slots import BlockPool  # noqa: E402
 from apex_example_tpu.transformer import expert_parallel as ep  # noqa: E402
@@ -372,8 +373,9 @@ def test_expert_shares_add_up_to_the_whole_layer():
 
 def test_latent_arena_is_one_headless_leaf_a_layer(model):
     pool = BlockPool(model, SLOTS, MAX_LEN, block_size=BS, num_blocks=40)
-    leaves = pool._block_leaves()
-    assert len(leaves) == 2 and all(kind == 2 for _, _, kind in leaves)
+    leaves = paged_cache.block_leaves(pool.cache, 40, BS)
+    assert len(leaves) == 2 and all(kind == paged_cache.PAYLOAD
+                                    for _, _, kind in leaves)
     # kv_lora_rank + qk_rope_head_dim = 40 values, stored in whole
     # 128-lane tiles
     assert {tuple(leaf.shape) for _, leaf, _ in leaves} == {(40, BS, 128)}
