@@ -39,7 +39,10 @@ score tensor exists.  On the CPU and under ``FORCE_XLA`` the XLA form
 gathers every slot's whole row of the block table (``kv_gather``) and
 scores all ``L`` positions: the same function, the tests' golden.  The
 model sows what either read, ``attn_positions_walked [layers, S]``, beside
-``expert_load`` in the ``counters`` collection.
+``expert_load`` in the ``counters`` collection, and beside
+``expert_weight_visits [layers, E]`` where the expert layers' grouped
+products ran in their kernel (``ops/grouped_matmul.py``; the XLA form,
+``lax.ragged_dot``, counts nothing).
 
 In the paged path the vocabulary head runs on each slot's *sampled lane*
 only (``[SLOTS, 1, V]`` logits): at a vocabulary of 131072 the all-lane
@@ -207,8 +210,10 @@ class RoutedExperts(nn.Module):
     selection-only bias, plus one shared expert.  ``experts_held = (first,
     count)``: the routed experts whose weights live here; the router always
     has its ``n_experts`` outputs, and what the other experts would add is
-    left out (another chip's share).  Returns ``(y, load)`` with ``load
-    [n_experts]`` the live lanes routed to each expert."""
+    left out (another chip's share).  Returns ``(y, load, visits)``: ``load
+    [n_experts]`` the live lanes routed to each expert, ``visits
+    [n_experts]`` the row tiles the grouped kernel visited for each (0 for
+    one not held or not touched), None from the XLA form."""
 
     hidden_size: int
     width: int
@@ -235,13 +240,16 @@ class RoutedExperts(nn.Module):
         idx, gates = dropless_route(flat, router, bias, self.top_k,
                                     self.scale)
         live = None if live is None else live.reshape(-1)
-        y = dropless_experts(flat, idx, gates, w_gate, w_up, w_down,
-                             self.experts_held, live)
+        y, visits = dropless_experts(flat, idx, gates, w_gate, w_up, w_down,
+                                     self.experts_held, live)
+        if visits is not None:
+            first = self.experts_held[0]
+            visits = jnp.pad(visits, (first, E - first - count))
         with device_span("shared_expert"):
             y = y + SwiGLU(d, f, self.dtype, self.param_dtype,
                            name="shared")(flat)
         load = expert_load(idx, E, live)
-        return y.reshape(x.shape), load
+        return y.reshape(x.shape), load, visits
 
 
 class LatentAttention(nn.Module):
@@ -403,17 +411,17 @@ class Xing4Layer(nn.Module):
         hc = hyper("ffn_hc")
         u, coeff = hc.mix_in(X)
         u = rms_norm(u, norm("ffn_norm"), eps)
-        load = None
+        load = visits = None
         if self.dense:
             y = SwiGLU(d, c["intermediate_size"], dtype, pd, name="mlp")(u)
         else:
             E = c["n_routed_experts"]
-            y, load = RoutedExperts(
+            y, load, visits = RoutedExperts(
                 d, c["moe_intermediate_size"], E, c["num_experts_per_tok"],
                 float(c["routed_scaling_factor"]),
                 tuple(c["experts_held"] or (0, E)), dtype, pd,
                 name="moe")(u, live)
-        return hc.mix_out(X, y, coeff), load, walked
+        return hc.mix_out(X, y, coeff), load, visits, walked
 
 
 class Xing4ForCausalLM(nn.Module):
@@ -489,21 +497,24 @@ class Xing4ForCausalLM(nn.Module):
                            (self.vocab_size, d), self.param_dtype)
         x = embed[input_ids].astype(self.dtype)
         X = jnp.repeat(x[:, :, None, :], self.hc_mult, axis=2)  # [B,L,n,d]
-        loads, walks = [], []
+        loads, visits, walks = [], [], []
         for i in range(self.num_layers):
-            X, load, walked = Xing4Layer(
+            X, load, visited, walked = Xing4Layer(
                 cfg, i < self.first_k_dense, name=f"layer_{i}")(
                     X, pos, paged, live)
-            if load is not None:
-                loads.append(load)
-            if walked is not None:
-                walks.append(walked)
+            for rows, row in ((loads, load), (visits, visited),
+                              (walks, walked)):
+                if row is not None:
+                    rows.append(row)
         # what the layers counted this call — live lanes routed to each
-        # expert of each expert layer [expert layers, E]; cache positions
-        # paged attention read for each slot [layers, S] — read by the
-        # engine when the "counters" collection is mutable, dropped
+        # expert of each expert layer [expert layers, E]; row tiles the
+        # grouped kernel visited for each expert, one fetch of its weights
+        # a product [expert layers, E] (nothing from the XLA form); cache
+        # positions paged attention read for each slot [layers, S] — read
+        # by the engine when the "counters" collection is mutable, dropped
         # otherwise
         for name, rows in (("expert_load", loads),
+                           ("expert_weight_visits", visits),
                            ("attn_positions_walked", walks)):
             if rows:
                 self.sow("counters", name, jnp.stack(rows),

@@ -35,13 +35,16 @@ axis the way DeepSpeed-MoE does).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from apex_example_tpu.ops.grouped_matmul import (grouped_matmul,
+                                                 grouped_swiglu)
 
 EXPERT_AXIS = "expert"
 
@@ -310,30 +313,30 @@ def expert_load(idx: jnp.ndarray, n_experts: int,
     return jnp.zeros((n_experts,), jnp.int32).at[idx].add(w[:, None])
 
 
-def ragged_dot_f32(a, w, sizes):
-    """Grouped product ``a[rows of group g] @ w[g]``, float32 out."""
-    return lax.ragged_dot(a, w.astype(a.dtype), sizes,
-                          preferred_element_type=jnp.float32)
-
-
 def dropless_experts(x: jnp.ndarray, idx: jnp.ndarray, gates: jnp.ndarray,
                      w_gate: jnp.ndarray, w_up: jnp.ndarray,
                      w_down: jnp.ndarray,
                      experts_held: Tuple[int, int],
-                     live: jnp.ndarray = None) -> jnp.ndarray:
+                     live: jnp.ndarray = None
+                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """What the experts held here add to tokens ``x [T, d]``:
     ``sum_i gates[t, i] * E_idx[t, i](x[t])`` over the chosen experts in
     ``experts_held = (first, count)``, each ``E(x) = w_down(silu(w_gate x)
     * w_up x)``.  The stacked weights hold the ``count`` experts from
     ``first`` on; pairs routed elsewhere add nothing (another chip's
     share).  The ``T * k`` pairs are sorted by expert, multiplied group by
-    group (``lax.ragged_dot``: each expert's weights are read once, no
+    group (``ops.grouped_matmul``: each expert's weights are read once, no
     padding to a capacity; lanes that ``live [T]`` marks dead — the padding
     of a static serving batch — belong to no group, so the products' work
-    follows the live tokens and not what the padding happens to hold; on
-    the TPU XLA lowers it to its own grouped
-    Mosaic kernels, which the device trace names ``ragged-dot-*`` and puts
-    under no scope of ours) and summed back per token."""
+    follows the live tokens and not what the padding happens to hold) and
+    summed back per token.  On the TPU the products are two Pallas calls
+    under the ``moe_experts`` scope, ``grouped_swiglu`` (gate, up and the
+    activation) and ``grouped_matmul`` (down), which never compute the
+    rows past the last live pair: those hold anything until the combine
+    selects them away.  Elsewhere, and under ``FORCE_XLA``, they are
+    ``lax.ragged_dot``.  Returns ``(y, visits)``: ``visits [count]`` the
+    row tiles the kernel visited for each expert held (one fetch of its
+    weights a product), None from the XLA form."""
     T, k = idx.shape
     first, count = experts_held
     with jax.named_scope("moe_dispatch"):
@@ -347,9 +350,8 @@ def dropless_experts(x: jnp.ndarray, idx: jnp.ndarray, gates: jnp.ndarray,
         sizes = jnp.zeros((count,), jnp.int32).at[group].add(
             1, mode="drop")
     with jax.named_scope("moe_experts"):
-        h = (jax.nn.silu(ragged_dot_f32(xs, w_gate, sizes))
-             * ragged_dot_f32(xs, w_up, sizes)).astype(x.dtype)
-        ys = ragged_dot_f32(h, w_down, sizes)        # [T*k, d] float32
+        h, _ = grouped_swiglu(xs, w_gate, w_up, sizes)
+        ys, visits = grouped_matmul(h, w_down, sizes)  # [T*k, d] float32
     with jax.named_scope("moe_combine"):
         # back to token order by a gather through the inverse permutation
         # (a row scatter runs its rows one after another on the TPU)
@@ -357,4 +359,4 @@ def dropless_experts(x: jnp.ndarray, idx: jnp.ndarray, gates: jnp.ndarray,
             jnp.arange(T * k, dtype=jnp.int32))
         g = jnp.where(held, gates.reshape(-1), 0.0)
         y = jnp.where(g[:, None] != 0, ys[inverse] * g[:, None], 0.0)
-        return y.reshape(T, k, -1).sum(axis=1).astype(x.dtype)
+        return y.reshape(T, k, -1).sum(axis=1).astype(x.dtype), visits

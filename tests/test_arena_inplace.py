@@ -78,7 +78,8 @@ def _latent_model():
                             moe_intermediate_size=256, n_routed_experts=8)
 
 
-def _lowered(speculative: bool, kv_quant: bool, sharding, model=None):
+def _lowered(speculative: bool, kv_quant: bool, sharding, model=None,
+             slots=SLOTS):
     """The tick's program lowered from shapes alone (no array is made):
     the model clone ``BlockPool`` builds, the step ``ServeEngine``
     calls, every argument a ShapeDtypeStruct on ``sharding``."""
@@ -87,7 +88,7 @@ def _lowered(speculative: bool, kv_quant: bool, sharding, model=None):
                          fused_attention=False, kv_num_blocks=NB,
                          kv_block_size=BS, kv_quant=kv_quant)
     shapes = jax.eval_shape(dec.init, jax.random.PRNGKey(0),
-                            jnp.zeros((SLOTS, MAX_LEN), jnp.int32))
+                            jnp.zeros((slots, MAX_LEN), jnp.int32))
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -98,10 +99,10 @@ def _lowered(speculative: bool, kv_quant: bool, sharding, model=None):
     lanes = max(BS, SPEC_K + 1) if speculative else BS
     i32 = jnp.int32
     args = (tree(shapes["params"]), tree(shapes["cache"]),
-            sds((SLOTS, lanes), i32), sds((SLOTS, MAX_LEN // BS), i32),
-            sds((SLOTS,), i32), sds((SLOTS,), i32), sds((SLOTS,), i32),
-            sds((SLOTS,), i32), sds((2,), jnp.uint32),
-            sds((SLOTS,), jnp.float32), sds((SLOTS,), i32))
+            sds((slots, lanes), i32), sds((slots, MAX_LEN // BS), i32),
+            sds((slots,), i32), sds((slots,), i32), sds((slots,), i32),
+            sds((slots,), i32), sds((2,), jnp.uint32),
+            sds((slots,), jnp.float32), sds((slots,), i32))
     step = engine_lib._slot_step(dec, lanes=speculative)
     leaves = jax.tree_util.tree_leaves(shapes["cache"])
     arena_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
@@ -201,6 +202,66 @@ def test_tpu_tick_walks_the_latent_arena_in_a_kernel(latent_tick):
     else:
         assert scores in text and view in text and not calls
     assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
+
+
+EXPERTS, EXPERT_IN, EXPERT_WIDTH, EXPERT_SLOTS = 64, 3584, 1024, 64
+
+
+@pytest.fixture(scope="module", params=["kernel", "xla"])
+def expert_tick(request, one_chip):
+    """The latent tick with one expert layer at the published widths (64
+    experts of 3584 x 1024, 4 a token) and the cell's 64 slots of 16
+    lanes, so the grouped products see the served ``[4096, 3584]`` rows;
+    everything that is not the expert layer small.  Steered as
+    ``latent_tick`` is."""
+    from apex_example_tpu.models.xing4 import Xing4ForCausalLM
+    from apex_example_tpu.ops import _config
+    model = Xing4ForCausalLM(
+        vocab_size=512, hidden_size=EXPERT_IN, num_layers=2, first_k_dense=1,
+        intermediate_size=512, moe_intermediate_size=EXPERT_WIDTH,
+        n_routed_experts=EXPERTS)
+    saved = _config.INTERPRET, _config.use_pallas
+    _config.INTERPRET = False
+    _config.use_pallas = lambda: not _config.FORCE_XLA
+    engine_lib._slot_step.cache_clear()
+    try:
+        with _config.force_xla(request.param == "xla"):
+            lowered, _, _ = _lowered(False, False, one_chip, model,
+                                     slots=EXPERT_SLOTS)
+        return request.param, lowered.compile().as_text()
+    finally:
+        _config.INTERPRET, _config.use_pallas = saved
+        engine_lib._slot_step.cache_clear()
+
+
+def test_tpu_tick_multiplies_the_experts_in_a_kernel(expert_tick):
+    """ISSUE 33: at the published expert widths the tick's grouped
+    products are the two named Pallas calls (gate, up and the activation
+    in one; down), bfloat16 in and float32 out of the second, with no
+    ``ragged-dot`` left and no copy or concatenation as large as a weight
+    stack; the XLA form of the same ops holds XLA's ragged-dot kernels
+    and no such call."""
+    form, text = expert_tick
+    rows = EXPERT_SLOTS * BS * 4
+    stack = EXPERTS * EXPERT_IN * EXPERT_WIDTH
+    calls = {name: [line for line in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in line
+                    and f"%{name}." in line.split("=")[0]]
+             for name in ("grouped_swiglu", "grouped_matmul")}
+    moved = [(op, dims) for dims, op in re.findall(
+        r"= \w+\[([\d,]*)\]\S* (copy|concatenate)\(", text)
+        if math.prod(int(d) for d in dims.split(",") if d) >= stack]
+    assert moved == []
+    if form == "kernel":
+        assert "ragged-dot" not in text
+        assert [len(c) for c in calls.values()] == [1, 1]  # one expert layer
+        assert f"= bf16[{rows},{EXPERT_WIDTH}]" in calls["grouped_swiglu"][0]
+        assert f"= f32[{rows},{EXPERT_IN}]" in calls["grouped_matmul"][0]
+        # the weight stacks go in as they are held: bfloat16 parameters
+        assert f"bf16[{EXPERTS},{EXPERT_IN},{EXPERT_WIDTH}]" in text \
+            and f"f32[{EXPERTS},{EXPERT_IN},{EXPERT_WIDTH}]" not in text
+    else:
+        assert "ragged-dot" in text and not any(calls.values())
 
 
 def test_tpu_tick_aliases_the_int8_arena(one_chip):

@@ -24,7 +24,7 @@ if REPO not in sys.path:
 
 from apex_example_tpu.models import xing4  # noqa: E402
 from apex_example_tpu.models.gpt import gpt_tiny  # noqa: E402
-from apex_example_tpu.ops import paged_cache  # noqa: E402
+from apex_example_tpu.ops import grouped_matmul, paged_cache  # noqa: E402
 from apex_example_tpu.serve import Request, ServeEngine  # noqa: E402
 from apex_example_tpu.serve.slots import BlockPool  # noqa: E402
 from apex_example_tpu.transformer import expert_parallel as ep  # noqa: E402
@@ -182,7 +182,11 @@ def _emulate_mxu(monkeypatch):
                         lambda a, b: jnp.matmul(up(a), up(b)))
     monkeypatch.setattr(xing4, "einsum_f32",
                         lambda s, a, b: jnp.einsum(s, up(a), up(b)))
-    monkeypatch.setattr(ep, "ragged_dot_f32",
+    # the grouped products: the kernel's dot (the interpreter runs it
+    # here) and the XLA form
+    monkeypatch.setattr(grouped_matmul, "_dot_f32",
+                        lambda a, b: jnp.matmul(up(a), up(b)))
+    monkeypatch.setattr(grouped_matmul, "ragged_dot_f32",
                         lambda a, w, sizes: jax.lax.ragged_dot(
                             up(a), up(w), sizes))
 
@@ -295,8 +299,9 @@ def test_dropless_keeps_every_token_where_gshard_drops():
     idx, gates = ep.dropless_route(x, p["router"], bias, 1, 2.0)
     assert np.all(np.asarray(idx) == 3)
     np.testing.assert_allclose(gates, 2.0, rtol=1e-6)   # a lone gate is 1
-    y = ep.dropless_experts(x, idx, gates, p["w_gate"], p["w_up"],
-                            p["w_down"], (0, E))
+    y, visits = ep.dropless_experts(x, idx, gates, p["w_gate"], p["w_up"],
+                                    p["w_down"], (0, E))
+    assert np.asarray(visits).tolist() == [0, 0, 0, 1, 0, 0, 0, 0]
     want = 2.0 * (jax.nn.silu(x @ p["w_gate"][3]) * (x @ p["w_up"][3])) \
         @ p["w_down"][3]
     np.testing.assert_allclose(y, want, atol=1e-5)
@@ -322,7 +327,7 @@ def test_dead_lanes_belong_to_no_group():
     live = jnp.arange(6)[None, :] < jnp.asarray([6, 1, 0, 3])[:, None]
     layer = xing4.RoutedExperts(d, f, E, k, 2.0, (0, E), jnp.float32,
                                 jnp.float32)
-    y, load = layer.apply({"params": p}, x, live)
+    y, load, _ = layer.apply({"params": p}, x, live)
     whole = REF.xing4_moe(x, p, cfg)
     shared = REF._swiglu(x, p["shared"], "highest")
     np.testing.assert_allclose(y[live], whole[live], atol=1e-5)
@@ -348,10 +353,16 @@ def test_expert_shares_add_up_to_the_whole_layer():
                                     jnp.float32, jnp.float32)
         held = {n: p[n][first:first + count]
                 for n in ("w_gate", "w_up", "w_down")}
-        y, load = layer.apply({"params": dict(
+        y, load, visits = layer.apply({"params": dict(
             p, **held, shared=jax.tree_util.tree_map(jnp.zeros_like,
                                                      p["shared"]))}, x)
         assert int(np.asarray(load).sum()) == 40 * k    # router keeps E
+        # the kernel visits the experts held here that got a token, and
+        # none of the others
+        seen = np.asarray(visits) > 0
+        assert seen.shape == (E,) and not seen[:first].any() \
+            and not seen[first + count:].any()
+        assert (seen == (np.asarray(load) > 0))[first:first + count].all()
         return y
 
     shared_once = REF._swiglu(x, p["shared"], "highest")
