@@ -22,8 +22,22 @@ the device trace reads it by (benchmarks/layer_metrics/
 kv_relayout_time_pct.py).  What a model does to its rows on the way
 (quantize, pad, dequantize) is the model's, under the same scope name.
 
-**The pool's side**: :func:`block_leaves`, :func:`shard`, :func:`extract`,
-:func:`insert`.  That discovery goes by shape is private to this module.
+**The second kind: per-slot leaves.**  A recurrent layer keeps a state of
+fixed size for each request *slot* (a state-space layer's ``[H, P, N]``
+state and its few convolution rows): ``[num_slots, ...]``, row ``s`` slot
+``s``'s.  It is not paged, cannot be shared by block and is not addressed
+through the block table; the model resets it inside the tick where a slot
+starts (``fill == 0``), and a handoff carries the slot's row beside its
+blocks.  Such a leaf is *declared* where it is created
+(:func:`slot_variable`: the declaration is the key the leaf is stored
+under) and never recognised by its shape, which may well coincide with a
+block leaf's (``num_slots == num_blocks``).  A cache tree that holds one
+cannot share prefixes: the state at a prefix boundary is held nowhere
+(serve/slots.BlockPool reads :func:`slot_leaves` for that).
+
+**The pool's side**: :func:`block_leaves`, :func:`slot_leaves`,
+:func:`shard`, :func:`extract`, :func:`insert`.  That discovery of block
+leaves goes by shape is private to this module.
 """
 
 from __future__ import annotations
@@ -35,7 +49,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-PAYLOAD, SCALE = "payload", "scale"
+PAYLOAD, SCALE, PER_SLOT = "payload", "scale", "per_slot"
+# a per-slot leaf's key in its module's ``cache`` dict: the declaration
+_SLOT_KEY = "slot:"
 
 
 def lane_tiles(width: int) -> int:
@@ -56,8 +72,27 @@ def variable(module, name: str, num_blocks: int, block_size: int, dtype,
             "slot_decode is block-paged: clone the model with "
             "kv_num_blocks/kv_block_size >= 1 "
             f"(got {num_blocks}/{block_size})")
+    if name.startswith(_SLOT_KEY):
+        raise ValueError(f"{name!r}: the {_SLOT_KEY!r} keys are the per-slot "
+                         "leaves' (slot_variable)")
     shape = (num_blocks, block_size) + (() if width is None else (width,))
     return module.variable("cache", name, jnp.zeros, shape, dtype)
+
+
+def slot_variable(module, name: str, num_slots: int, shape: Tuple[int, ...],
+                  dtype):
+    """``module``'s per-slot ``cache`` variable ``name``: a zeroed
+    ``[num_slots, *shape]`` leaf, row ``s`` the state slot ``s`` carries
+    from tick to tick.  Declared per-slot by the key it is stored under;
+    the model zeroes a slot's row itself where the slot starts."""
+    return module.variable("cache", _SLOT_KEY + name, jnp.zeros,
+                           (num_slots,) + tuple(shape), dtype)
+
+
+def has_slot_variable(module, name: str) -> bool:
+    """Does ``module`` hold the per-slot leaf ``name`` already (a tick), or
+    is this the init trace that allocates it?"""
+    return module.has_variable("cache", _SLOT_KEY + name)
 
 
 # ------------------------------------------------------- device operations
@@ -131,17 +166,25 @@ def gather(leaves, table, heads: Optional[int] = None):
 
 # ------------------------------------------------------------ pool's side
 
-def _kind(leaf, num_blocks: int, block_size: int) -> Optional[str]:
-    """By shape alone: the first two dimensions are the geometry's, three
-    dimensions a payload, two a scale table."""
-    if leaf.shape[:2] != (num_blocks, block_size):
-        return None
-    return {3: PAYLOAD, 2: SCALE}.get(leaf.ndim)
-
-
 def _path_str(path) -> str:
     return "/".join(getattr(p, "key", getattr(p, "name", str(p)))
                     for p in path)
+
+
+def _declared_per_slot(path) -> bool:
+    return bool(path) and str(getattr(path[-1], "key", "")).startswith(
+        _SLOT_KEY)
+
+
+def _kind(path, leaf, num_blocks: int, block_size: int) -> Optional[str]:
+    """A per-slot leaf by its declaration (the key ``slot_variable`` stored
+    it under); a block leaf by shape alone: the first two dimensions are
+    the geometry's, three dimensions a payload, two a scale table."""
+    if _declared_per_slot(path):
+        return PER_SLOT
+    if leaf.shape[:2] != (num_blocks, block_size):
+        return None
+    return {3: PAYLOAD, 2: SCALE}.get(leaf.ndim)
 
 
 def block_leaves(cache, num_blocks: int,
@@ -152,16 +195,24 @@ def block_leaves(cache, num_blocks: int,
     the leaf under on both sides of the transport."""
     out = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        kind = _kind(leaf, num_blocks, block_size)
-        if kind:
+        kind = _kind(path, leaf, num_blocks, block_size)
+        if kind in (PAYLOAD, SCALE):
             out.append((_path_str(path), leaf, kind))
     return out
+
+
+def slot_leaves(cache) -> List[Tuple[str, object]]:
+    """``(path, leaf)`` of every per-slot leaf of a cache tree (none for a
+    model of attention layers alone)."""
+    return [(_path_str(path), leaf) for path, leaf
+            in jax.tree_util.tree_flatten_with_path(cache)[0]
+            if _declared_per_slot(path)]
 
 
 def _sharding(mesh, kind: Optional[str]):
     """Heads are the outer factor of a payload's merged dimension, so a
     shard over 'model' holds whole heads (the dense decode cache's split
-    under TP); scale tables and anything else replicate."""
+    under TP); scale tables, per-slot leaves and anything else replicate."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from apex_example_tpu.parallel.mesh import MODEL_AXIS
@@ -171,46 +222,60 @@ def _sharding(mesh, kind: Optional[str]):
 
 def shard(cache, mesh, num_blocks: int, block_size: int):
     """The cache tree placed on ``mesh``."""
-    return jax.tree_util.tree_map(lambda leaf: jax.device_put(
-        leaf, _sharding(mesh, _kind(leaf, num_blocks, block_size))), cache)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jax.device_put(leaf, _sharding(
+            mesh, _kind(path, leaf, num_blocks, block_size))), cache)
 
 
-def extract(cache, block_ids, num_blocks: int,
-            block_size: int) -> Dict[str, np.ndarray]:
+def extract(cache, block_ids, num_blocks: int, block_size: int,
+            slot: Optional[int] = None) -> Dict[str, np.ndarray]:
     """Blocks ``block_ids`` of every block-resident leaf as host arrays
     in the leaf's STORAGE dtype (a handoff moves the low-bit bytes), keyed
-    by path.  ``np.array``, not ``np.asarray``: an owned, writable copy
-    that does not pin the gather's buffer across the transport."""
+    by path; with ``slot``, that slot's row ``[1, ...]`` of every per-slot
+    leaf as well, so that a request's state travels with its blocks.
+    ``np.array``, not ``np.asarray``: an owned, writable copy that does
+    not pin the gather's buffer across the transport."""
     ids = jnp.asarray(np.ascontiguousarray(block_ids))
-    return {path: np.array(leaf[ids])
-            for path, leaf, _ in block_leaves(cache, num_blocks,
-                                              block_size)}
+    out = {path: np.array(leaf[ids])
+           for path, leaf, _ in block_leaves(cache, num_blocks, block_size)}
+    if slot is not None:
+        out.update((path, np.array(leaf[slot:slot + 1]))
+                   for path, leaf in slot_leaves(cache))
+    return out
 
 
 @functools.lru_cache(maxsize=8)
 def _fused_block_scatter(shapes):
     """ONE jitted scatter for every leaf of a handoff payload, cached per
-    geometry.  Pad lanes are out of range and drop.  The leaves are
-    DONATED: an admission writes a few blocks in place."""
+    geometry: blocks at ``idx`` of the block leaves and, where the tree has
+    per-slot leaves, row ``slot`` of those.  Pad lanes are out of range and
+    drop.  The leaves are DONATED: an admission writes a few blocks in
+    place."""
     del shapes                        # cache key only; shapes ride args
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def scatter(leaves, idx, rows):
+    def scatter(leaves, idx, rows, slot, state_rows):
+        n = len(rows)
         return tuple(l.at[idx].set(r, mode="drop")
-                     for l, r in zip(leaves, rows))
+                     for l, r in zip(leaves[:n], rows)) \
+            + tuple(l.at[slot].set(r, mode="drop")
+                    for l, r in zip(leaves[n:], state_rows))
 
     return scatter
 
 
 def insert(cache, block_ids: Sequence[int], payload: Dict[str, np.ndarray],
-           num_blocks: int, block_size: int, pad_to: int, mesh=None):
+           num_blocks: int, block_size: int, pad_to: int, mesh=None,
+           slot: Optional[int] = None):
     """The cache tree with ``payload`` (:func:`extract`'s) written at
-    ``block_ids``.  The block-resident leaves passed in are donated: the
-    caller rebinds its cache from the result.  Indices and rows are padded
-    to ``pad_to`` blocks, so one compiled scatter serves every handoff
-    size.  A payload that does not match leaf for leaf in shape and
-    storage dtype is refused.  With ``mesh`` the written leaves go back
-    onto their shardings."""
+    ``block_ids`` and, where the tree has per-slot leaves, at row ``slot``
+    of each (a payload that lacks one, or no ``slot``, is refused: the
+    request would go on from another request's state).  The leaves written
+    are donated: the caller rebinds its cache from the result.  Indices and
+    rows are padded to ``pad_to`` blocks, so one compiled scatter serves
+    every handoff size.  A payload that does not match leaf for leaf in
+    shape and storage dtype is refused.  With ``mesh`` the written leaves
+    go back onto their shardings."""
     n = len(block_ids)
     pad = max(pad_to, n)
     idx = np.full((pad,), num_blocks, np.int32)
@@ -232,12 +297,36 @@ def insert(cache, block_ids: Sequence[int], payload: Dict[str, np.ndarray],
         padded = np.zeros((pad,) + tuple(rows.shape[1:]), dtype=rows.dtype)
         padded[:n] = rows
         rows_in.append(jnp.asarray(padded))
-    arena = tuple(leaf for _, leaf, _ in found)
+    states = slot_leaves(cache)
+    state_rows = []
+    for key, leaf in states:
+        rows = payload.get(key)
+        if rows is None or slot is None:
+            raise ValueError(
+                f"handoff payload missing per-slot leaf {key!r} — the "
+                "sender's cache tree has no such state, or it was "
+                "extracted without its slot")
+        if rows.shape != (1,) + leaf.shape[1:] \
+                or str(rows.dtype) != str(leaf.dtype):
+            raise ValueError(
+                f"handoff payload {key!r} {rows.dtype}{tuple(rows.shape)} "
+                f"does not fit one slot's row of {leaf.dtype}"
+                f"{tuple(leaf.shape)}")
+        state_rows.append(jnp.asarray(rows))
+    arena = tuple(leaf for _, leaf, _ in found) \
+        + tuple(leaf for _, leaf in states)
+    # ``slot`` None and no rows for a tree of block leaves alone
     new = _fused_block_scatter(tuple(a.shape for a in arena))(
-        arena, jnp.asarray(idx), tuple(rows_in))
+        arena, jnp.asarray(idx), tuple(rows_in),
+        jnp.asarray([slot], jnp.int32) if states else None,
+        tuple(state_rows))
+    new, new_states = new[:len(found)], new[len(found):]
     if mesh is not None:
         new = [jax.device_put(leaf, _sharding(mesh, kind))
                for leaf, (_, _, kind) in zip(new, found)]
+        new_states = [jax.device_put(leaf, _sharding(mesh, PER_SLOT))
+                      for leaf in new_states]
     written = dict(zip((key for key, _, _ in found), new))
+    written.update(zip((key for key, _ in states), new_states))
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: written.get(_path_str(path), leaf), cache)

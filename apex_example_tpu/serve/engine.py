@@ -369,15 +369,18 @@ class ServeEngine:
         if speculate and speculate + 1 > max_len:
             raise ValueError(f"speculate {speculate} exceeds max_len "
                              f"{max_len} lanes")
+        # the pool refuses speculation first where the cache tree holds
+        # per-slot recurrent state (no rollback): the reason that stays
+        # true whatever lanes the model's head runs on
+        self.pool = BlockPool(model, num_slots, max_len,
+                              block_size=block_size,
+                              num_blocks=num_blocks, kv_quant=kv_quant,
+                              spec_slack=speculate)
         if speculate and not getattr(model, "all_lane_logits", True):
             raise ValueError(
                 "speculate verifies draft lanes against every lane's "
                 f"logits; {type(model).__name__}'s paged head runs on the "
                 "sampled lane only (ROADMAP: self-drafting)")
-        self.pool = BlockPool(model, num_slots, max_len,
-                              block_size=block_size,
-                              num_blocks=num_blocks, kv_quant=kv_quant,
-                              spec_slack=speculate)
         # weight_quant names the mode ``params`` ALREADY carries (the
         # caller quantized at restore time — serve.py); the engine's
         # job is to dequantize inside the compiled step.
@@ -1306,7 +1309,6 @@ class ServeEngine:
         pool = self.pool
         slot = pool.slots[idx]
         req = slot.request
-        fill, n_mapped, payload = pool.extract_blocks(idx)
         BS = pool.block_size
         # The satellite bugfix (ISSUE 20): under --speculate,
         # stage_writes maps blocks for draft lanes the accept decision
@@ -1316,9 +1318,8 @@ class ServeEngine:
         # inside this engine.  Ship exactly the blocks the cursor
         # covers; admit_prefilled allocates ceil(fill/BS) on the
         # destination and rejects a longer payload as malformed.
-        n_ship = (fill + BS - 1) // BS
-        if n_ship < n_mapped:
-            payload = {k: v[:n_ship] for k, v in payload.items()}
+        n_ship = (slot.cursor + BS - 1) // BS
+        fill, _, payload = pool.extract_blocks(idx, n_ship)
         # Same invariant on the token list: everything past tokens[fill]
         # (the one pending next-feed token of a decoding slot) was never
         # verified against committed KV and must not resume elsewhere.

@@ -352,6 +352,22 @@ class BlockPool:
                                 for _, leaf, _ in leaves)
         self._kv_elems = sum(leaf.size for leaf in payload)
         self._kv_dtype = str(payload[0].dtype) if payload else "none"
+        # The second kind of cache (ops/paged_cache.py): state a recurrent
+        # layer keeps per SLOT.  A property of the tree, not a model's
+        # name: such a tree cannot share prefixes (the state at a prefix
+        # boundary is held nowhere, so a request started at fill > 0 on
+        # another's blocks would compute from the wrong state) and cannot
+        # roll rejected draft lanes back.  Counted apart from K/V.
+        states = paged_cache.slot_leaves(shapes)
+        self.per_slot_state = bool(states)
+        self._state_per_slot = sum(
+            leaf.size // num_slots * leaf.dtype.itemsize
+            for _, leaf in states)
+        if self.per_slot_state and self.spec_slack:
+            raise ValueError(
+                "speculate: this model keeps a recurrent state per slot, "
+                "and a state advanced over rejected draft lanes cannot be "
+                "rolled back without a snapshot (ROADMAP M4)")
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.table = np.zeros((num_slots, self.max_blocks), np.int32)
         self.slots: List[Optional[Slot]] = [None] * num_slots
@@ -428,10 +444,17 @@ class BlockPool:
         sharing) is coverable by unreserved blocks right now."""
         if not self._free:
             return False
-        shared, bids, _ = self.alloc.match_prefix(request.prompt)
+        shared, bids, _ = self._match_prefix(request.prompt)
         need = self.blocks_needed(request, shared)
         return self.alloc.available(tuple(bids)) \
             - self._reserved_total >= need
+
+    def _match_prefix(self, prompt) -> Tuple[int, List[int], List[Tuple]]:
+        """``alloc.match_prefix``, or no match at all for a cache tree
+        with per-slot state."""
+        if self.per_slot_state:
+            return 0, [], []
+        return self.alloc.match_prefix(prompt)
 
     # -------------------------------------------------------- lifecycle
 
@@ -449,7 +472,7 @@ class BlockPool:
                 f"{request.uid}: prompt length {n_prompt} must be < "
                 f"cache max_len {self.max_len} (admission should have "
                 "rejected this request)")
-        shared, bids, keys = self.alloc.match_prefix(request.prompt)
+        shared, bids, keys = self._match_prefix(request.prompt)
         need = self.blocks_needed(request, shared)
         idx = self._free.pop()
         for b in bids:
@@ -483,12 +506,15 @@ class BlockPool:
 
     # ------------------------------------------------------- KV handoff
 
-    def extract_blocks(self, idx: int) -> Tuple[int, int, Dict[str, "np.ndarray"]]:
-        """Gather slot ``idx``'s mapped arena blocks for a KV handoff:
+    def extract_blocks(self, idx: int, n_blocks: Optional[int] = None
+                       ) -> Tuple[int, int, Dict[str, "np.ndarray"]]:
+        """Gather slot ``idx``'s mapped arena blocks (its first
+        ``n_blocks``; all by default) for a KV handoff:
         ``(fill, n_blocks, payload)`` where payload maps each arena
         leaf's path string to a host ``[n_blocks, BS, ...]`` array in
         the leaf's STORAGE dtype (int8 payload + bf16 scales under
-        kv_quant — the handoff moves low-bit bytes, never dequantizes).
+        kv_quant — the handoff moves low-bit bytes, never dequantizes),
+        and each per-slot leaf's to the slot's own ``[1, ...]`` row.
 
         The copy is deep by construction (``np.asarray`` of a device
         gather): a payload built from COW-shared prefix blocks shares
@@ -497,9 +523,10 @@ class BlockPool:
         slot = self.slots[idx]
         if slot is None:
             raise RuntimeError(f"slot {idx} is free — nothing to hand off")
-        n = slot.n_mapped
+        n = slot.n_mapped if n_blocks is None else n_blocks
         payload = paged_cache.extract(self.cache, self.table[idx, :n],
-                                      self.num_blocks, self.block_size)
+                                      self.num_blocks, self.block_size,
+                                      slot=idx)
         return slot.cursor, n, payload
 
     def blocks_needed_prefilled(self, request: Request) -> int:
@@ -544,7 +571,7 @@ class BlockPool:
         # inside the decode worker's TPOT window.  The leaves are donated.
         self.cache = paged_cache.insert(
             self.cache, bids, payload, self.num_blocks, BS,
-            pad_to=self.max_blocks, mesh=self._mesh)
+            pad_to=self.max_blocks, mesh=self._mesh, slot=self._free[-1])
         idx = self._free.pop()
         self.table[idx, :] = 0
         self.table[idx, :n_pay] = bids
@@ -607,6 +634,8 @@ class BlockPool:
         immutable; its chain key hashes the whole token prefix)."""
         slot = self.slots[idx]
         slot.cursor += n_new
+        if self.per_slot_state:
+            return                 # nothing is shared, so nothing is indexed
         BS = self.block_size
         for b in range(slot.n_mapped):
             if slot.block_keys[b] is None and (b + 1) * BS <= slot.cursor:
@@ -654,6 +683,17 @@ class BlockPool:
         per_token = self.kv_bytes_per_token()
         return sum(s.cursor for s in self.slots if s is not None) \
             * per_token
+
+    def state_bytes_reserved(self) -> int:
+        """HBM bytes the per-slot leaves pin (a recurrent layer's state and
+        convolution rows, every slot's, every layer's): 0 for a model of
+        attention layers alone.  Apart from ``kv_bytes_*``, which keep
+        meaning paged K/V."""
+        return self._state_per_slot * self.num_slots
+
+    def state_bytes_live(self) -> int:
+        """Per-slot state bytes of the slots that hold a request."""
+        return self._state_per_slot * (self.num_slots - len(self._free))
 
     def blocks_live(self) -> int:
         """Arena blocks physically held by live slots right now."""

@@ -31,6 +31,11 @@ Phases (``chip_smoke_out/`` holds each phase's log, stream and checkpoint):
   serve      serve.py on gpt_base restored from that checkpoint: 16
              requests through chunked prefill + paged decode
   txl        c5: Transformer-XL (clip_grad_norm kernels)
+  granite_hybrid
+             models/granite_hybrid.py through ServeEngine at the tiny and
+             the published widths (granite-4.0-h-micro whole): a request
+             completes, every Mamba layer's slot moved, the per-slot state
+             leaves keep their device buffers (updated in place)
   ddp4 tp4 serve4
              with >= 4 chips: DDP+SyncBN ResNet-50, dp2 x tp2 BERT-base,
              --mesh 2,2 gpt_base serve; skipped, saying so, on fewer
@@ -69,7 +74,7 @@ BUDGET_S = 1150.0
 # phase -> the phase whose output it reads (a failed need fails the phase)
 ONE_CHIP = {"device": None, "kernels": "device", "resnet50": "device",
             "bert_base": "device", "gpt_base": "device", "serve": "gpt_base",
-            "txl": "device"}
+            "txl": "device", "granite_hybrid": "device"}
 FOUR_CHIP = {"ddp4": "resnet50", "tp4": "bert_base", "serve4": "gpt_base"}
 
 _TAIL = ["--epochs", "1", "--print-freq", "1"]
@@ -483,11 +488,72 @@ def phase_serve(name: str):
         _check_all_devices_used()
 
 
+def phase_granite_hybrid():
+    """serve.py --arch granite_*'s model through ServeEngine at the tiny
+    and at the published widths (granite-4.0-h-micro whole: 6.4 GB of
+    bfloat16 weights, 64 slots of per-slot state): a prompt of two chunks
+    and a part, then decode.  Asserted: the request completes (the engine's
+    guard saw finite logits every tick), every Mamba layer's slot moved,
+    and the per-slot state leaf is the same device buffer after the ticks
+    (the cache is donated: updated in place, never copied)."""
+    _require_tpu()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from apex_example_tpu.models import granite_hybrid as gh
+    from apex_example_tpu.ops import paged_cache
+    from apex_example_tpu.serve import Request, ServeEngine
+
+    def seeded(model):
+        init = jax.jit(lambda k: model.init(
+            k, jnp.zeros((1, 8), jnp.int32))["params"])
+        return init(jax.random.PRNGKey(0))
+
+    for name, model, slots, max_len in (
+            ("granite_hybrid_tiny", gh.granite_hybrid_tiny(), 4, 64),
+            ("granite_4_0_h_micro", gh.granite_4_0_h_micro(), 64, 1024)):
+        t0 = time.monotonic()
+        params = seeded(model)
+        eng = ServeEngine(model, params, num_slots=slots, max_len=max_len,
+                          block_size=16)
+        states = paged_cache.slot_leaves(eng.pool.cache)
+        where = [leaf.unsafe_buffer_pointer() for _, leaf in states]
+        eng.submit(Request(prompt=list(range(1, 40)), max_new_tokens=6,
+                           uid="a"))
+        eng.queue.close()
+        done = eng.run(max_steps=64)
+        if [c.status for c in done] != ["ok"] or len(done[0].tokens) != 6:
+            _fail(f"{name}: the request did not complete: {done}")
+        after = [leaf.unsafe_buffer_pointer()
+                 for _, leaf in paged_cache.slot_leaves(eng.pool.cache)]
+        if after != where:
+            _fail(f"{name}: a per-slot leaf moved to another buffer "
+                  "(the tick copied it)")
+        moved = np.stack([np.asarray(t["ssm_slots_advanced"])
+                          for _, t in eng.counter_log])
+        if not (moved[:, :, 0] == 1).all() or moved[:, :, 1:].any():
+            _fail(f"{name}: ssm_slots_advanced is not slot 0 alone")
+        state = np.asarray(
+            paged_cache.slot_leaves(eng.pool.cache)[-1][1][0], np.float32)
+        if not np.isfinite(state).all() or not np.abs(state).max() > 0:
+            _fail(f"{name}: the slot's state is not finite and non-zero")
+        stats = jax.devices()[0].memory_stats() or {}
+        print(f"{name}: 6 tokens {list(done[0].tokens)} in "
+              f"{eng.compute_steps} ticks, {len(states)} per-slot leaves in "
+              f"place ({eng.pool.state_bytes_reserved()} bytes), peak "
+              f"{stats.get('peak_bytes_in_use')} bytes, "
+              f"{time.monotonic() - t0:.0f} s", flush=True)
+        del eng, params
+
+
 def run_phase(name: str):
     if name == "device":
         phase_device()
     elif name == "kernels":
         phase_kernels()
+    elif name == "granite_hybrid":
+        phase_granite_hybrid()
     elif name in TRAIN_ARGV:
         phase_train(name)
     else:

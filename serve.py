@@ -141,7 +141,8 @@ def build_parser() -> argparse.ArgumentParser:
         description="continuous-batching GPT inference")
     p.add_argument("--arch", default="gpt_tiny",
                    choices=["gpt_tiny", "gpt_base", "xing4_tiny",
-                            "xing4_29b_a4b_cut"],
+                            "xing4_29b_a4b_cut", "granite_hybrid_tiny",
+                            "granite_4_0_h_micro"],
                    help="gpt_*: the post-LN decoder (float32). xing4_*: "
                         "latent attention, dropless experts, hyper-"
                         "connected residual (models/xing4.py): the tiny "
@@ -149,7 +150,16 @@ def build_parser() -> argparse.ArgumentParser:
                         "layers in bfloat16 — the architecture sets the "
                         "dtype; --kv-quant, --weight-quant, --speculate "
                         "and a model-sharded --mesh are refused where the "
-                        "model, pool or engine cannot build them")
+                        "model, pool or engine cannot build them. "
+                        "granite_*: Mamba-2 layers whose recurrent state "
+                        "lives per slot beside the paged K/V of its GQA "
+                        "attention layers (models/granite_hybrid.py): the "
+                        "tiny preset in float32, granite-4.0-h-micro whole "
+                        "(40 layers, bfloat16); no prefix is shared "
+                        "(prefix_hit_rate reads 0: the state at a prefix "
+                        "boundary is held nowhere), and --speculate (a "
+                        "state cannot be rolled back), --kv-quant and a "
+                        "model-sharded --mesh (not built) are refused")
     p.add_argument("--checkpoint-dir", default=None,
                    help="CheckpointManager directory to restore params "
                         "from (omit = random init, smoke mode)")
@@ -586,6 +596,8 @@ def run_serve(args):
 
     from apex_example_tpu import obs
     from apex_example_tpu.models.gpt import gpt_base, gpt_tiny
+    from apex_example_tpu.models.granite_hybrid import (granite_4_0_h_micro,
+                                                        granite_hybrid_tiny)
     from apex_example_tpu.models.xing4 import (xing4_29b_a4b_cut,
                                                xing4_tiny)
     from apex_example_tpu.parallel.mesh import (parse_serve_mesh,
@@ -618,6 +630,8 @@ def run_serve(args):
     model = {"gpt_tiny": gpt_tiny, "gpt_base": gpt_base,
              "xing4_tiny": xing4_tiny,
              "xing4_29b_a4b_cut": xing4_29b_a4b_cut,
+             "granite_hybrid_tiny": granite_hybrid_tiny,
+             "granite_4_0_h_micro": granite_4_0_h_micro,
              }[args.arch](tensor_parallel=tp > 1)
     max_len = args.max_len
     if max_len is None:

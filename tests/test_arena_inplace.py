@@ -264,6 +264,72 @@ def test_tpu_tick_multiplies_the_experts_in_a_kernel(expert_tick):
         assert "ragged-dot" in text and not any(calls.values())
 
 
+def _hybrid_lowered(sharding):
+    """The tick of models/granite_hybrid.py lowered from shapes: two Mamba
+    layers at the published state widths (64 heads of 64, 128 columns:
+    ``[slots, 64, 64, 128]`` float32 a layer) round one GQA layer (8 K/V
+    heads of 64: ``[NB, BS, 512]`` leaves), everything else small; 16
+    slots so that no leaf is padded."""
+    from apex_example_tpu.models.granite_hybrid import \
+        GraniteHybridForCausalLM
+    slots = 16
+    dec = GraniteHybridForCausalLM(
+        vocab_size=512, hidden_size=512, num_layers=3, attention_period=3,
+        attention_offset=1, intermediate_size=512).clone(
+            decode=True, slot_decode=True, fused_attention=False,
+            kv_num_blocks=NB, kv_block_size=BS)
+    shapes = jax.eval_shape(dec.init, jax.random.PRNGKey(0),
+                            jnp.zeros((slots, MAX_LEN), jnp.int32))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def tree(t):
+        return jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), t)
+
+    i32 = jnp.int32
+    args = (tree(shapes["params"]), tree(shapes["cache"]),
+            sds((slots, BS), i32), sds((slots, MAX_LEN // BS), i32),
+            sds((slots,), i32), sds((slots,), i32), sds((slots,), i32),
+            sds((slots,), i32), sds((2,), jnp.uint32),
+            sds((slots,), jnp.float32), sds((slots,), i32))
+    leaves = jax.tree_util.tree_leaves(shapes["cache"])
+    assert {l.shape for l in leaves} == {
+        (slots, 3 * 4352), (slots, 64, 64, 128), (NB, BS, 512)}
+    return (engine_lib._slot_step(dec).lower(*args),
+            sum(l.size * l.dtype.itemsize for l in leaves),
+            {l.size for l in leaves})
+
+
+def test_tpu_tick_updates_both_kinds_of_cache_in_place(one_chip):
+    """ISSUE 34: a Mamba layer's per-slot state is read and written where
+    it lies, like the arena beside it: the tick compiled for the chip
+    holds no copy of a state leaf's or an arena leaf's size, nothing of
+    ``[slots, lanes, H, P, N]`` (the lane-by-lane form's carry), and
+    aliases every cache byte from argument to result.  (The three
+    convolution rows a slot are kept flat, ``[slots, 3 * 4352]``, so that
+    no tile is padded, and are laid out as ``[slots, 3, 4352]`` for the
+    convolution and back: a copy of that leaf's 1/300 of the state's
+    bytes, let be.)"""
+    lowered, cache_bytes, sizes = _hybrid_lowered(one_chip)
+    sizes.discard(16 * 3 * 4352)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    copies = [(dtype, dims) for dtype, dims
+              in arena_sized_copies(text, min(sizes))
+              if math.prod(int(d) for d in dims.split(",")) in sizes]
+    assert copies == []
+    assert f"[16,{BS},64,64,128]" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+
+
+def test_cpu_tick_aliases_both_kinds_of_cache():
+    cpu = SingleDeviceSharding(jax.devices("cpu")[0])
+    lowered, cache_bytes, _ = _hybrid_lowered(cpu)
+    assert lowered.compile().memory_analysis().alias_size_in_bytes \
+        == cache_bytes
+
+
 def test_tpu_tick_aliases_the_int8_arena(one_chip):
     lowered, arena_bytes, _ = _lowered(False, True, one_chip)
     mem = lowered.compile().memory_analysis()

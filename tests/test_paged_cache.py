@@ -241,3 +241,124 @@ def test_insert_refuses_a_payload_that_does_not_match_leaf_for_leaf(fault):
     with pytest.raises(ValueError, match="missing arena leaf"
                        if fault == "missing" else "does not fit arena"):
         paged_cache.insert(cache, [3, 4], payload, NB, BS, pad_to=4)
+
+
+# ------------------------------------------------- the per-slot kind
+
+def _hybrid_pool(slots, num_blocks):
+    from apex_example_tpu.models.granite_hybrid import granite_hybrid_tiny
+    return BlockPool(granite_hybrid_tiny(), num_slots=slots, max_len=16,
+                     block_size=BS, num_blocks=num_blocks)
+
+
+@pytest.mark.parametrize("slots,num_blocks", [(3, NB), (NB, NB), (BS, BS)],
+                         ids=["apart", "slots_eq_blocks",
+                              "slots_eq_blocks_eq_block_size"])
+def test_per_slot_leaves_are_found_by_declaration_never_by_shape(slots,
+                                                                 num_blocks):
+    """Four Mamba layers' state and convolution rows are per-slot, one
+    attention layer's K and V block-resident, whatever the geometry: with
+    ``num_slots == num_blocks`` (== ``block_size``) a per-slot leaf's
+    leading dimensions are a block leaf's."""
+    pool = _hybrid_pool(slots, num_blocks)
+    per_slot = paged_cache.slot_leaves(pool.cache)
+    assert [path for path, _ in per_slot] == [
+        f"layer_{i}/mixer/slot:{name}" for i in (0, 1, 3, 4)
+        for name in ("conv_rows", "ssm_state")]
+    assert all(leaf.shape[0] == slots for _, leaf in per_slot)
+    assert [(path, kind) for path, _, kind in paged_cache.block_leaves(
+        pool.cache, num_blocks, BS)] == [
+        (f"layer_2/mixer/cached_{name}", paged_cache.PAYLOAD)
+        for name in ("key", "value")]
+    # a model of attention layers alone has none, and shares prefixes
+    gpt = BlockPool(gpt_tiny(), num_slots=NB, max_len=16, block_size=BS,
+                    num_blocks=NB)
+    assert paged_cache.slot_leaves(gpt.cache) == []
+    assert not gpt.per_slot_state and pool.per_slot_state
+    assert gpt.state_bytes_reserved() == 0
+
+
+def test_a_block_leaf_may_not_take_a_per_slot_key():
+    import flax.linen as nn
+
+    class Wrong(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            paged_cache.variable(self, "slot:k", NB, BS, jnp.float32, 8)
+            return x
+
+    with pytest.raises(ValueError, match="per-slot"):
+        Wrong().init(jax.random.PRNGKey(0), jnp.zeros((1,)))
+
+
+def _hybrid_cache(slots=NB):
+    """A tree with both kinds at ``num_slots == num_blocks``, every leaf
+    full of distinct values."""
+    rng = np.random.default_rng(7)
+    fill = lambda shape, dtype: jnp.asarray(
+        rng.standard_normal(shape), dtype)
+    return {"layer_0": {"mixer": {
+                "slot:ssm_state": fill((slots, 2, 4, 4), jnp.float32),
+                "slot:conv_rows": fill((slots, BS, 8), jnp.bfloat16)}},
+            "layer_1": {"mixer": {
+                "cached_key": fill((NB, BS, 8), jnp.bfloat16)}}}
+
+
+def test_extract_and_insert_carry_a_slots_rows_with_its_blocks():
+    src, dst = _hybrid_cache(), jax.tree_util.tree_map(
+        jnp.zeros_like, _hybrid_cache())
+    want = jax.tree_util.tree_map(np.asarray, src)
+    payload = paged_cache.extract(src, [5, 2], NB, BS, slot=7)
+    assert {k: v.shape for k, v in payload.items()} == {
+        "layer_0/mixer/slot:ssm_state": (1, 2, 4, 4),
+        "layer_0/mixer/slot:conv_rows": (1, BS, 8),
+        "layer_1/mixer/cached_key": (2, BS, 8)}
+    assert all(v.flags.writeable for v in payload.values())
+    # without a slot only the blocks travel
+    assert sorted(paged_cache.extract(src, [5, 2], NB, BS)) \
+        == ["layer_1/mixer/cached_key"]
+    got = paged_cache.insert(dst, [9, 0], payload, NB, BS, pad_to=4, slot=3)
+    got = jax.tree_util.tree_map(np.asarray, got)
+    for name in ("slot:ssm_state", "slot:conv_rows"):
+        leaf = got["layer_0"]["mixer"][name]
+        assert leaf[3].tobytes() \
+            == want["layer_0"]["mixer"][name][7].tobytes()
+        assert not np.delete(leaf, 3, axis=0).any()   # slot 3's row alone
+    keys = got["layer_1"]["mixer"]["cached_key"]
+    assert keys[[9, 0]].tobytes() \
+        == want["layer_1"]["mixer"]["cached_key"][[5, 2]].tobytes()
+    assert not np.delete(keys, [9, 0], axis=0).any()
+
+
+@pytest.mark.parametrize("fault", ["missing", "no_slot", "shape", "dtype"])
+def test_insert_refuses_a_payload_without_the_slots_state(fault):
+    cache = _hybrid_cache()
+    payload = paged_cache.extract(cache, [1], NB, BS, slot=0)
+    slot, key = 2, "layer_0/mixer/slot:ssm_state"
+    if fault == "missing":
+        del payload[key]
+    elif fault == "no_slot":
+        slot = None
+    elif fault == "shape":
+        payload[key] = payload[key][:, :1]
+    else:
+        payload[key] = payload[key].astype(np.float16)
+    with pytest.raises(ValueError, match="does not fit one slot's row"
+                       if fault in ("shape", "dtype")
+                       else "missing per-slot leaf"):
+        paged_cache.insert(cache, [3], payload, NB, BS, pad_to=4, slot=slot)
+
+
+def test_shard_replicates_a_per_slot_leaf_and_splits_a_payload():
+    from jax.sharding import Mesh, PartitionSpec as P
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
+                ("data", "model"))
+    placed = paged_cache.shard(_hybrid_cache(), mesh, NB, BS)
+    mixer = placed["layer_0"]["mixer"]
+    # [NB, BS, 8] bfloat16 conv rows look like a payload; they are not one
+    assert mixer["slot:conv_rows"].sharding.spec == P()
+    assert mixer["slot:ssm_state"].sharding.spec == P()
+    assert placed["layer_1"]["mixer"]["cached_key"].sharding.spec \
+        == P(None, None, "model")
