@@ -32,6 +32,19 @@ tick* where a slot starts a request (``fill == 0`` and ``n_new > 0``): the
 host zeroes nothing.  A slot with ``n_new == 0`` keeps state and rows bit
 for bit.
 
+**Packed lanes** (``packed_lanes = True``; ``ops/lane_pack.py``).  The
+paged path's residual stream is not ``[SLOTS, C, d]`` but the tick's live
+lanes as dense rows ``[R, d]``, ``R = lane_pack.rows(SLOTS, C)`` static: the
+embedding, every norm and residual add, ``W_in``, the gate with its norm,
+``W_out``, the MLP, ``wq/wk/wv/wo`` and the head's pick are token-wise and
+run on ``R`` rows; the convolution with the scan and the attention over a
+slot's paged K/V see ``[SLOTS, C, ...]`` through the map's ``unpack`` and
+hand back through ``pack`` (under the device span ``lane_pack``, outside
+``ssm_scan`` and ``shared_mlp``).  K and V are written to the arena from their packed
+rows.  The engine reads the attribute and grants no more multi-lane chunks
+a tick than the rows hold.  A subclass with ``packed_lanes = False`` runs
+the same layers over ``[SLOTS, C, d]`` (the tests' other side).
+
 The paged head runs on each slot's sampled lane only (``[SLOTS, 1, V]``
 float32 logits; ``all_lane_logits = False``).  Refused, with the reason:
 speculation (by the pool: a state advanced over rejected lanes cannot be
@@ -41,8 +54,9 @@ Weights and activations are ``dtype``/``param_dtype`` (bfloat16 as
 served); the state and its recurrence, softplus, ``exp``, norm statistics,
 softmax and logits are float32; convolution rows and K/V are ``dtype``.
 The model sows ``ssm_slots_advanced [mamba layers, slots]`` (1 where a
-slot's state moved this tick) and ``lanes_live [1, slots]`` (``n_new``)
-into the ``counters`` collection.
+slot's state moved this tick), ``lanes_live [1, slots]`` (``n_new``) and
+``rows_dense [1, 1]`` (the rows its token-wise products ran on: ``R``
+packed, ``SLOTS * C`` not) into the ``counters`` collection.
 """
 
 from __future__ import annotations
@@ -55,7 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_example_tpu.obs.spans import device_span
-from apex_example_tpu.ops import paged_cache, ssd
+from apex_example_tpu.ops import lane_pack, paged_cache, ssd
 
 F32 = jnp.float32
 
@@ -112,7 +126,10 @@ def _a_log_init(key, shape, dtype):
 
 class MambaMixer(nn.Module):
     """Returns ``(y, advanced)``: ``advanced [S]`` 1 where the paged path
-    moved a slot's state, None from the plain forward."""
+    moved a slot's state, None from the plain forward.  ``h`` is ``[S, L,
+    d]``, or with ``lanes`` (a ``lane_pack.LaneMap``) its packed rows ``[R,
+    d]``: the projections, the gate and its norm run on what they are
+    given, the convolution and the scan on ``[S, L, ...]``."""
 
     hidden_size: int
     n_heads: int
@@ -126,7 +143,7 @@ class MambaMixer(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, h, paged=None):
+    def __call__(self, h, paged=None, lanes=None):
         d, H, P, N, K = (self.hidden_size, self.n_heads, self.d_head,
                          self.d_state, self.d_conv)
         di, ch = H * P, H * P + 2 * N
@@ -140,7 +157,7 @@ class MambaMixer(nn.Module):
         norm = self.param("norm", nn.initializers.ones, (di,), pd)
         w_out = self.param("out_proj", _fan_in(di), (di, d), pd)
 
-        S, L = h.shape[:2]
+        S, L = h.shape[:2] if lanes is None else (lanes.slots, lanes.chunk)
         state = rows = reset = n_new = None
         carried = False
         if self.decode:
@@ -167,11 +184,14 @@ class MambaMixer(nn.Module):
             n_new = jnp.full((S,), L, jnp.int32)
         live = jnp.arange(L)[None, :] < n_new[:, None]
 
-        zxd = matmul_f32(h, w_in)                     # [S, L, di + ch + H]
+        zxd = matmul_f32(h, w_in)                     # [.., di + ch + H]
         z = zxd[..., :di].astype(self.dtype)
         xbc = zxd[..., di:di + ch].astype(self.dtype)
+        dt = zxd[..., di + ch:]
+        if lanes is not None:
+            xbc, dt = lanes.unpack(xbc), lanes.unpack(dt)
         with device_span("ssm_scan"):
-            dt = jax.nn.softplus(zxd[..., di + ch:] + dt_bias)
+            dt = jax.nn.softplus(dt + dt_bias)
             xbc, rows = ssd.causal_conv(rows, xbc, conv_w, conv_b, n_new,
                                         reset)
             xbc = jax.nn.silu(xbc)
@@ -182,7 +202,10 @@ class MambaMixer(nn.Module):
             if carried:
                 sv.value = state
                 cv.value = rows.reshape(S, (K - 1) * ch)
-        y = y.reshape(S, L, di) * jax.nn.silu(z.astype(F32))
+        y = y.reshape(S, L, di)
+        if lanes is not None:
+            y = lanes.pack(y)
+        y = y * jax.nn.silu(z.astype(F32))
         y = rms_norm(y, norm, self.rms_norm_eps).astype(self.dtype)
         out = matmul_f32(y, w_out).astype(self.dtype)
         return out, (n_new > 0).astype(jnp.int32) if carried else None
@@ -201,7 +224,10 @@ class GQAttention(nn.Module):
     kv_block_size: int = 0
 
     @nn.compact
-    def __call__(self, h, pos, paged=None):
+    def __call__(self, h, pos, paged=None, lanes=None):
+        """``h`` is ``[S, L, d]``, or with ``lanes`` its packed rows ``[R,
+        d]``: the four projections run on what they are given, K and V go
+        to the arena from their rows, the scores see ``[S, L, ...]``."""
         d, Hq, Hk, hd = (self.hidden_size, self.num_heads, self.num_kv_heads,
                          self.head_dim)
         pd, g = self.param_dtype, self.num_heads // self.num_kv_heads
@@ -210,9 +236,11 @@ class GQAttention(nn.Module):
         wv = self.param("wv", _fan_in(d), (d, Hk * hd), pd)
         wo = self.param("wo", _fan_in(Hq * hd), (Hq * hd, d), pd)
         mm = lambda a, w: matmul_f32(a, w).astype(self.dtype)
-        S, L = h.shape[:2]
-        q = mm(h, wq).reshape(S, L, Hk, g, hd)
-        k, v = mm(h, wk), mm(h, wv)                        # [S, L, Hk * hd]
+        S, L = pos.shape
+        q, k, v = mm(h, wq), mm(h, wk), mm(h, wv)          # [.., Hk * hd]
+        if lanes is not None:
+            q = lanes.unpack(q)
+        q = q.reshape(S, L, Hk, g, hd)
         keys = vals = None
         if self.decode:
             NB, BS = self.kv_num_blocks, self.kv_block_size
@@ -228,6 +256,10 @@ class GQAttention(nn.Module):
                     paged["cow_dst"])
                 flat = paged_cache.write_rows(table, pos, paged["n_new"],
                                               NB, BS)
+                if lanes is not None:
+                    # the rows of k and v are the packed ones: so are
+                    # their places in the arena (a dead row drops)
+                    flat = lanes.pack(flat.reshape(S, L), fill=NB * BS)
                 ck.value, cv.value = paged_cache.write(
                     (ck.value, cv.value), flat, (k, v))
                 # each slot's logical view [S, max_blocks * BS, Hk, hd];
@@ -243,8 +275,11 @@ class GQAttention(nn.Module):
             seen = kpos[:, None, None, None, :] <= pos[:, None, None, :, None]
             probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
             o = einsum_f32("skgql,slkd->sqkgd", probs.astype(self.dtype),
-                           vals).astype(self.dtype)
-            return mm(o.reshape(S, L, Hq * hd), wo)
+                           vals).astype(self.dtype).reshape(S, L, Hq * hd)
+        if lanes is not None:
+            o = lanes.pack(o)
+        with device_span("gqa_attention"):
+            return mm(o, wo)
 
 
 class SharedMLP(nn.Module):
@@ -271,7 +306,7 @@ class GraniteHybridLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, pos, paged):
+    def __call__(self, x, pos, paged, lanes=None):
         c = dict(self.cfg)
         d, eps, r = c["hidden_size"], c["rms_norm_eps"], \
             c["residual_multiplier"]
@@ -286,13 +321,13 @@ class GraniteHybridLayer(nn.Module):
                     d, c["mamba_n_heads"], c["mamba_d_head"],
                     c["mamba_d_state"], c["mamba_d_conv"],
                     c["mamba_chunk_size"], eps, dtype, pd, c["decode"],
-                    name="mixer")(h, paged)
+                    name="mixer")(h, paged, lanes)
         else:
             y = GQAttention(
                 d, c["num_heads"], c["num_kv_heads"], c["head_dim"],
                 c["attention_multiplier"], dtype, pd, c["decode"],
                 c["kv_num_blocks"], c["kv_block_size"],
-                name="mixer")(h, pos, paged)
+                name="mixer")(h, pos, paged, lanes)
         x = (x.astype(F32) + r * y.astype(F32)).astype(dtype)
         y = SharedMLP(d, c["intermediate_size"], dtype, pd,
                       name="mlp")(norm("norm2", x))
@@ -338,6 +373,9 @@ class GraniteHybridForCausalLM(nn.Module):
 
     # the paged head runs on the sampled lane only
     all_lane_logits = False
+    # the paged program's token-wise sublayers take the tick's live lanes
+    # as lane_pack.rows(SLOTS, C) dense rows: the engine budgets to that
+    packed_lanes = True
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """``layer_types`` of the published config."""
@@ -367,8 +405,12 @@ class GraniteHybridForCausalLM(nn.Module):
                     if f not in ("parent", "name"))
         B, L = input_ids.shape
         pos = jnp.broadcast_to(jnp.arange(L)[None, :], (B, L))
+        lanes = None
         if paged is not None:
             pos = paged["fill"][:, None] + pos       # for the masks only
+            if self.packed_lanes:
+                lanes = lane_pack.LaneMap(paged["n_new"], L)
+                input_ids = lanes.pack(input_ids)                   # [R]
         # seeded so that x_0 = embedding_multiplier E[ids] has a projection's
         # scale: at 1/sqrt(d) a tied head echoes its input token
         embed = self.param("embed",
@@ -376,10 +418,13 @@ class GraniteHybridForCausalLM(nn.Module):
                            (self.vocab_size, d), self.param_dtype)
         x = (embed[input_ids].astype(F32)
              * self.embedding_multiplier).astype(self.dtype)
+        if lanes is not None:
+            # a dead row holds zeros from here on (lane_pack's promise)
+            x = jnp.where(lanes.row_live[:, None], x, 0)
         moves = []
         for i, kind in enumerate(self.layer_kinds()):
             x, moved = GraniteHybridLayer(cfg, kind, name=f"layer_{i}")(
-                x, pos, paged)
+                x, pos, paged, lanes)
             if moved is not None:
                 moves.append(moved)
         if paged is not None:
@@ -391,9 +436,14 @@ class GraniteHybridForCausalLM(nn.Module):
                          **keep)
             self.sow("counters", "lanes_live", paged["n_new"][None, :],
                      **keep)
+            self.sow("counters", "rows_dense",
+                     jnp.full((1, 1), x.size // d, jnp.int32), **keep)
             # the head on each slot's sampled lane only
-            lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)
-            x = jnp.take_along_axis(x, lane[:, None, None], axis=1)
+            if lanes is not None:
+                x = lanes.last(x)[:, None]
+            else:
+                lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)
+                x = jnp.take_along_axis(x, lane[:, None, None], axis=1)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones, (d,),
                                    self.param_dtype),
                      self.rms_norm_eps).astype(self.dtype)

@@ -816,6 +816,11 @@ OPTIONAL: Dict[str, Dict[str, Any]] = {
                                        #   or adopted lease
         "migration_bytes": int,     # payload bytes moved, both sides
         "migration_ms": dict,       # in side: transit percentiles
+        # lane packing's token budget (ops/lane_pack.py): present only
+        # where the engine of a model with packed lanes left a chunk
+        # waiting — every other stream is byte-identical.
+        "prefill_chunks_deferred": int,  # chunks left waiting a tick
+        "prefill_ticks_deferring": int,  # ticks that left any waiting
     },
     "preemption": {
         "run_id": str,

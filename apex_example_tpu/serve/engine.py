@@ -90,6 +90,7 @@ from apex_example_tpu.obs.metrics import Histogram, nearest_rank
 from apex_example_tpu.obs.slo import SloTracker
 from apex_example_tpu.obs.spans import Phases, device_span
 from apex_example_tpu.obs.tickprof import ENGINE_PHASES, ENGINE_TICK
+from apex_example_tpu.ops import lane_pack
 from apex_example_tpu.resilience.faults import FaultInjected
 from apex_example_tpu.serve.queue import (STATUSES, Completion, Request,
                                           RequestQueue)
@@ -411,6 +412,18 @@ class ServeEngine:
             self.proposer = NgramProposer()
         if self.speculate:
             self.chunk = max(self.chunk, self.speculate + 1)
+        # Lane packing (ops/lane_pack.py): a model that declares
+        # ``packed_lanes`` runs its token-wise sublayers on the tick's
+        # live lanes as dense rows, which hold every slot's lane 0 and
+        # this many multi-lane chunks; the marshal loop grants no more.
+        # None — every other model — is no budget: each lane of
+        # [SLOTS, C] is a row of the program, and the marshal is what it
+        # was.  A decode-role engine has C = 1, budget 0 and no slot
+        # that asks for more than a lane: packing is the identity there.
+        self._chunk_budget = lane_pack.groups(num_slots, self.chunk) \
+            if getattr(model, "packed_lanes", False) else None
+        self.prefill_chunks_deferred = 0
+        self.prefill_ticks_deferring = 0
         self.tokens_drafted = 0
         self.tokens_accepted = 0
         self.tokens_sampled = 0
@@ -719,8 +732,27 @@ class ServeEngine:
         temps = np.zeros((S,), np.float32)
         ks = np.zeros((S,), np.int32)
         drafts: Dict[int, List[int]] = {}
+        # The token budget of chunked prefill, for a model whose rows are
+        # packed: a chunk of more than one lane is granted whole or not
+        # at all, oldest admission first; a slot granted nothing has
+        # n_new = 0 this tick, stages no write and keeps state and rows
+        # bit for bit.  Decoding slots (and a prompt's last single
+        # token) keep their one lane: the rows always hold those.
+        deferred = frozenset()
+        if self._chunk_budget is not None:
+            slots = pool.slots
+            asking = sorted(
+                (i for i in live
+                 if min(C, slots[i].n_prompt - slots[i].cursor) > 1),
+                key=lambda i: (slots[i].admitted_step, slots[i].t_admitted))
+            deferred = frozenset(asking[self._chunk_budget:])
+            self.prefill_chunks_deferred += len(deferred)
+            self.prefill_ticks_deferring += bool(deferred)
         for i in live:
             slot = pool.slots[i]
+            fill[i] = slot.cursor
+            if i in deferred:
+                continue
             # Chunked prefill: up to one block of prompt tokens per
             # tick; decode feeds the single previously-sampled token.
             n = min(C, slot.n_prompt - slot.cursor) if slot.prefilling \
@@ -737,7 +769,6 @@ class ServeEngine:
                 tok[i, :n] = [slot.tokens[slot.cursor]] + draft
             else:
                 tok[i, :n] = slot.tokens[slot.cursor:slot.cursor + n]
-            fill[i] = slot.cursor
             n_new[i] = n
             # Map/COW the blocks this slot writes this tick (draws from
             # the budget reserved at admission, so it cannot OOM).
@@ -825,7 +856,8 @@ class ServeEngine:
                         int(n_new[i]), now)
                 else:
                     pool.commit_writes(i, int(n_new[i]))
-                    if tracer is not None and was_prefilling:
+                    if tracer is not None and was_prefilling \
+                            and i not in deferred:
                         # Buffer the chunk window (the tick's dispatch
                         # span) on the request; its tree is emitted
                         # whole, in timestamp order, at terminal time.
@@ -894,6 +926,12 @@ class ServeEngine:
         self._committed_hist.observe(
             self.pool.blocks_committed() * per_block)
         if counted:
+            if self._chunk_budget is not None:
+                # beside what the model counted, on the host already
+                # (every tick, like the model's own: a reader takes a
+                # counter some ticks lack for one the window lacks)
+                counted[0] = dict(counted[0], prefill_chunks_deferred=np
+                                  .full((1, 1), len(deferred), np.int32))
             self.counter_log.append((now, counted[0]))
         if self.registry is not None:
             self.registry.gauge("serve.slots_live").set(live_slots)
@@ -1703,6 +1741,12 @@ class ServeEngine:
             rec["migration_bytes"] = self._migration_bytes
         if self._migration_ms:
             rec["migration_ms"] = _pct_dict(self._migration_ms)
+        # Lane packing's token budget: chunks left waiting a tick because
+        # the packed rows were spent, and the ticks that left any.  Gated
+        # on its having happened, like the ledgers above.
+        if self.prefill_chunks_deferred:
+            rec["prefill_chunks_deferred"] = self.prefill_chunks_deferred
+            rec["prefill_ticks_deferring"] = self.prefill_ticks_deferring
         if self.compute_steps:
             rec["occupancy"] = round(
                 self._occupancy_sum / (self.compute_steps

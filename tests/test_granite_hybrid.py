@@ -348,3 +348,279 @@ def test_serve_cli_serves_the_tiny_arch_end_to_end(capsys):
         with pytest.raises(err, match=match):
             serve.main(["--arch", "granite_hybrid_tiny", "--requests", "2"]
                        + flag)
+
+
+# ------------------------------------------------------------ packed lanes
+# The paged program's token-wise sublayers run on the tick's live lanes as
+# dense rows (ops/lane_pack.py); the engine grants no more multi-lane
+# chunks a tick than the rows hold.  The other side of every comparison is
+# the same model with ``packed_lanes = False``: the [SLOTS, C] form, and an
+# engine with no budget.
+
+class UnpackedGranite(gh.GraniteHybridForCausalLM):
+    packed_lanes = False
+
+
+def _unpacked(model):
+    fields = {f: getattr(model, f) for f in model.__dataclass_fields__
+              if f not in ("parent", "name")}
+    return UnpackedGranite(**fields)
+
+
+def test_rows_follow_from_the_ticks_geometry_alone():
+    from apex_example_tpu.ops import lane_pack
+    # the served cell: every slot's lane 0 and 12 whole chunks, a quarter
+    # of the tick's 1024 lanes
+    assert lane_pack.groups(64, 16) == 12 and lane_pack.rows(64, 16) == 256
+    # never fewer than one chunk (a prompt must advance); C = 1 (a
+    # decode-role engine) packs nothing
+    assert lane_pack.groups(3, 8) == 1 and lane_pack.rows(3, 8) == 11
+    assert lane_pack.groups(8, 16) == 1 and lane_pack.rows(8, 16) == 24
+    assert lane_pack.groups(64, 1) == 0 and lane_pack.rows(64, 1) == 64
+
+
+@pytest.mark.parametrize("n_new", [
+    [1, 0, 5, 1, 8, 0, 1, 3],       # decode, idle, chunks whole and part
+    [1, 1, 1, 1, 1, 1, 1, 1],       # decode only: the first S rows
+    [0, 0, 0, 0, 0, 0, 0, 0],       # nothing live
+    [8, 1, 8, 0, 8, 1, 0, 1],       # the budget spent (3 groups of 3)
+], ids=["mixed", "decode_only", "idle", "budget_spent"])
+def test_lane_map_packs_and_unpacks_every_live_lane_once(
+        n_new, monkeypatch):
+    from apex_example_tpu.ops import lane_pack
+    monkeypatch.setattr(lane_pack, "groups", lambda s, c: 3)
+    S, C = 8, 8
+    n = jnp.asarray(n_new, jnp.int32)
+    m = lane_pack.LaneMap(n, C)
+    assert m.rows == S + 3 * C
+    x = np.arange(1, S * C * 2 + 1, dtype=np.float32).reshape(S, C, 2)
+    live = np.arange(C)[None, :] < np.asarray(n_new)[:, None]
+    rows = np.asarray(m.pack(jnp.asarray(x)))
+    # every live lane is exactly one row, a dead row holds zeros
+    assert sorted(rows[:, 0][rows[:, 0] > 0]) == sorted(x[live][:, 0])
+    assert int(np.asarray(m.row_live).sum()) == int(live.sum())
+    assert not rows[~np.asarray(m.row_live)].any()
+    assert (np.asarray(m.pack(jnp.asarray(x), fill=-7))[
+        ~np.asarray(m.row_live)] == -7).all()
+    # there and back; dead lanes read zero whatever the dead rows hold
+    dirty = np.where(np.asarray(m.row_live)[:, None], rows, np.nan)
+    back = np.asarray(m.unpack(jnp.asarray(dirty)))
+    np.testing.assert_array_equal(back, np.where(live[..., None], x, 0))
+    last = np.asarray(m.last(jnp.asarray(dirty)))
+    for s, k in enumerate(n_new):
+        np.testing.assert_array_equal(last[s], x[s, k - 1] if k else 0)
+
+
+MIXES = {
+    # more requests than slots (a reused slot), prompts that are no
+    # multiple of the chunk, and with one group a tick two of the three
+    # slots sit at n_new = 0 while the oldest prefills
+    "chunks_decode_reuse": dict(lens=[29, 5, 17, 20, 9],
+                                new=[6, 9, 4, 7, 5], num_slots=3, seed=0),
+    "one_slot_reused": dict(lens=[21, 13], new=[5, 6], num_slots=1, seed=3),
+    "long_prompts_at_once": dict(lens=[40, 33, 25, 18], new=[3, 4, 5, 6],
+                                 num_slots=4, seed=7),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_packed_rows_give_the_unpacked_forms_logits_and_tokens(
+        model, params, ref_logits, mix):
+    kw = dict(MIXES[mix])
+    slots = kw.pop("num_slots")
+    runs = []
+    for m in (model, _unpacked(model)):
+        eng = _engine(m, params, num_slots=slots)
+        seen = _record_logits(eng)
+        runs.append((eng, seen, _run(eng, _requests(**kw))))
+    (packed, seen, done), (plain, seen_plain, done_plain) = runs
+    assert packed._chunk_budget == 1 and plain._chunk_budget is None
+    assert sorted(done) == sorted(done_plain)
+    for uid, c in done.items():
+        assert c.status == "ok"
+        assert list(c.tokens) == list(done_plain[uid].tokens)
+        assert sorted(seen[uid]) == sorted(seen_plain[uid])
+        for pos, row in seen[uid].items():
+            assert np.abs(row - seen_plain[uid][pos]).max() < TOL
+    assert _worst(done, seen, ref_logits) < TOL
+    # the products ran on the packed rows, and a slot did sit a tick out
+    dense = {int(np.asarray(t["rows_dense"]).sum())
+             for _, t in packed.counter_log}
+    assert dense == {slots + BS}
+    assert {int(np.asarray(t["rows_dense"]).sum())
+            for _, t in plain.counter_log} == {slots * BS}
+    if slots > 1:
+        assert packed.prefill_chunks_deferred > 0
+    assert plain.prefill_chunks_deferred == 0
+
+
+def test_a_nan_in_a_dead_row_reaches_no_live_lane_and_no_finite_check(
+        model, params, monkeypatch):
+    """Every pack plants NaN in its dead rows (so from the first Mamba
+    layer on every dead row of the residual stream is NaN through every
+    projection, norm and MLP): the logits are the clean run's bit for bit,
+    the program's finite mask holds for every slot, empty and deferred ones
+    too, and nothing in the cache turns NaN."""
+    from apex_example_tpu.ops import lane_pack
+    reqs = dict(lens=[29, 5, 17, 20], new=[4, 6, 3, 5], seed=2)
+    clean = _engine(model, params)
+    want = _record_logits(clean)
+    done = _run(clean, _requests(**reqs))
+
+    pack = lane_pack.LaneMap.pack
+    planted = []
+
+    def dirty(self, x, fill=0):
+        out = pack(self, x, fill)
+        if not jnp.issubdtype(out.dtype, jnp.floating):
+            return out
+        planted.append(out.shape)
+        return jnp.where(self.row_live.reshape(
+            (-1,) + (1,) * (out.ndim - 1)), out, jnp.nan)
+    monkeypatch.setattr(lane_pack.LaneMap, "pack", dirty)
+    eng = _engine(model, params)
+    got = _record_logits(eng)
+    finite = []
+    step = eng._step_fn
+
+    def watching(*a):
+        out = step(*a)
+        finite.append(np.asarray(out[2]))
+        return out
+    eng._step_fn = watching
+    done_dirty = _run(eng, _requests(**reqs))
+    assert len(planted) == 5                 # 4 Mamba layers' y, 1 layer's o
+    assert np.concatenate(finite).all()
+    for uid, c in done.items():
+        assert list(done_dirty[uid].tokens) == list(c.tokens)
+        for pos, row in want[uid].items():
+            assert got[uid][pos].tobytes() == row.tobytes()
+    for leaf in jax.tree_util.tree_leaves(eng.pool.cache):
+        assert np.isfinite(np.asarray(leaf)).all()
+
+
+# -- the engine's budget: 8 slots x 16 lanes, one multi-lane chunk a tick
+
+BUDGET_LENS = [70, 64, 49, 37, 33, 30, 21, 17]
+
+
+@pytest.fixture(scope="module")
+def budgeted(model, params):
+    """Eight long prompts at once through the packed engine, with every
+    tick's marshal and the cache before and after it kept, and through an
+    engine with no budget.  Admission order never defers a slot that has
+    begun its prompt, so after its second chunk the oldest request is
+    given the youngest stamp: it then waits, mid-prompt, with a state and
+    K/V blocks of its own, until the others are through."""
+    reqs = lambda: _requests(BUDGET_LENS, [4, 3, 5, 2, 6, 3, 4, 5], seed=13)
+    eng = ServeEngine(model, params, num_slots=8, max_len=96, block_size=16)
+    assert eng._chunk_budget == 1 and eng.chunk == 16
+    seen = _record_logits(eng)
+    ticks = []
+    step = eng._step_fn
+
+    def keeping(*a):
+        slots = eng.pool.slots
+        before = jax.tree_util.tree_map(np.asarray, a[1])
+        order = sorted((i for i, s in enumerate(slots)
+                        if s is not None and s.prefilling
+                        and s.n_prompt - s.cursor > 1),
+                       key=lambda i: slots[i].t_admitted)
+        out = step(*a)
+        ticks.append(dict(
+            tok=np.asarray(a[2]), table=np.asarray(a[3]).copy(),
+            fill=np.asarray(a[4]), n_new=np.asarray(a[5]), asking=order,
+            uid={i: s.request.uid for i, s in enumerate(slots)
+                 if s is not None},
+            before=before, after=jax.tree_util.tree_map(np.asarray, out[0])))
+        if len(ticks) == 2:
+            next(s for s in slots if s.request.uid == "r0").t_admitted \
+                = float("inf")
+        return out
+    eng._step_fn = keeping
+    done = _run(eng, reqs())
+    free = ServeEngine(_unpacked(model), params, num_slots=8, max_len=96,
+                       block_size=16)
+    assert free._chunk_budget is None
+    return dict(eng=eng, ticks=ticks, done=done, seen=seen,
+                free=free, done_free=_run(free, reqs()))
+
+
+def test_budget_every_requests_tokens_equal_an_unthrottled_engines(budgeted):
+    done, free = budgeted["done"], budgeted["done_free"]
+    assert sorted(done) == sorted(free) and len(done) == 8
+    for uid, c in done.items():
+        assert c.status == "ok" == free[uid].status
+        assert list(c.tokens) == list(free[uid].tokens)
+    # the budget only delays: more ticks, the same work
+    assert budgeted["eng"].compute_steps > budgeted["free"].compute_steps
+    assert budgeted["free"].prefill_chunks_deferred == 0
+    assert "prefill_chunks_deferred" not in budgeted["free"].summary_record()
+
+
+def test_budget_every_prompt_token_is_fed_exactly_once(budgeted):
+    fed = {}
+    for t in budgeted["ticks"]:
+        for i, uid in t["uid"].items():
+            for j in range(int(t["n_new"][i])):
+                at = int(t["fill"][i]) + j
+                assert at not in fed.setdefault(uid, {})
+                fed[uid][at] = int(t["tok"][i, j])
+    for uid, c in budgeted["done"].items():
+        seq = list(c.request.prompt) + list(c.tokens)
+        assert [fed[uid][p] for p in range(len(seq) - 1)] == seq[:-1]
+    lanes = sum(len(c.request.prompt) + len(c.tokens) - 1
+                for c in budgeted["done"].values())
+    assert sum(int(t["n_new"].sum()) for t in budgeted["ticks"]) == lanes
+
+
+def test_budget_grants_whole_chunks_oldest_admission_first(budgeted):
+    eng, deferred, deferring = budgeted["eng"], 0, 0
+    for t in budgeted["ticks"]:
+        many = [i for i in np.flatnonzero(t["n_new"] > 1)]
+        assert many == t["asking"][:1]       # one chunk, the oldest asker's
+        for i in many:                       # granted whole
+            left = BUDGET_LENS[int(t["uid"][i][1:])] - int(t["fill"][i])
+            assert int(t["n_new"][i]) == min(16, left)
+        for i in t["asking"][1:]:
+            assert int(t["n_new"][i]) == 0
+        deferred += len(t["asking"][1:])
+        deferring += bool(t["asking"][1:])
+    assert deferred > 0
+    rec = eng.summary_record()
+    assert rec["prefill_chunks_deferred"] == deferred \
+        == eng.prefill_chunks_deferred
+    assert rec["prefill_ticks_deferring"] == deferring
+    from apex_example_tpu.obs import schema
+    assert not schema.validate_record(dict(rec, run_id="x"))
+    counted = sum(int(np.asarray(tree["prefill_chunks_deferred"]).sum())
+                  for _, tree in eng.counter_log
+                  if "prefill_chunks_deferred" in tree)
+    assert counted == deferred
+
+
+def test_budget_a_deferred_slot_keeps_state_rows_and_blocks_bit_for_bit(
+        budgeted):
+    checked = 0
+    for t in budgeted["ticks"]:
+        arenas = [(was, now) for (path, was), (_, now) in zip(
+            jax.tree_util.tree_flatten_with_path(t["before"])[0],
+            jax.tree_util.tree_flatten_with_path(t["after"])[0])
+            if "cached_" in jax.tree_util.keystr(path)]
+        assert len(arenas) == 2                   # one layer's K and V
+        for i in t["asking"][1:]:
+            assert int(t["n_new"][i]) == 0
+            for (name, was), (_, now) in zip(
+                    paged_cache.slot_leaves(t["before"]),
+                    paged_cache.slot_leaves(t["after"])):
+                assert was[i].tobytes() == now[i].tobytes(), name
+            fill = int(t["fill"][i])
+            for b in t["table"][i][:-(-fill // 16)]:
+                for was, now in arenas:
+                    assert was[b].tobytes() == now[b].tobytes()
+            if fill and t["uid"][i] == "r0":
+                state = paged_cache.slot_leaves(t["before"])[0][1][i]
+                assert np.abs(state).max() > 0 and np.abs(arenas[0][0][
+                    t["table"][i][0]]).max() > 0
+                checked += 1
+    assert checked > 3            # r0 waited mid-prompt, with state to lose

@@ -1093,3 +1093,67 @@ def test_loadgen_burst_and_deadlines():
         synthetic_requests(2, vocab_size=100, burst=0)
     with pytest.raises(ValueError, match="deadline_steps"):
         synthetic_requests(2, vocab_size=100, deadline_steps=0)
+
+
+# ------------------------------ lane packing's adapt path is inert here
+
+@pytest.mark.parametrize("what", ["marshal", "program"])
+def test_a_model_without_packed_lanes_keeps_its_marshal_and_its_program(
+        model_and_params, monkeypatch, what):
+    """The engine budgets prefill chunks for a model that declares
+    ``packed_lanes`` (ops/lane_pack.py).  GPT declares nothing: every slot
+    inside its prompt is fed ``min(C, prompt left)`` lanes every tick,
+    however many ask at once, and its program never meets the lane maps."""
+    from apex_example_tpu.ops import lane_pack
+    from apex_example_tpu.serve.engine import _slot_step
+    model, params = model_and_params
+    assert not hasattr(model, "packed_lanes")
+
+    def never(*a, **k):
+        raise AssertionError("the lane maps were asked for")
+    monkeypatch.setattr(lane_pack, "LaneMap", never)
+    monkeypatch.setattr(lane_pack, "groups", never)
+    eng = ServeEngine(model, params, num_slots=SLOTS, max_len=MAX_LEN,
+                      rng=jax.random.PRNGKey(0))
+    assert eng._chunk_budget is None
+    reqs = synthetic_requests(6, vocab_size=model.vocab_size, seed=5,
+                              prompt_len=(17, 24), max_new=(2, 4))
+    eng.queue.submit_all(reqs)
+    eng.queue.close()
+    if what == "program":
+        eng.step()
+        dec = eng.pool.dec
+        S, C = SLOTS, eng.chunk
+        z = lambda *s: jnp.zeros(s, jnp.int32)
+        text = str(jax.make_jaxpr(_slot_step(dec).__wrapped__)(
+            params, eng.pool.cache, z(S, C), jnp.asarray(eng.pool.table),
+            z(S), z(S), z(S) - 1, z(S) - 1, jax.random.PRNGKey(0),
+            jnp.zeros((S,), jnp.float32), z(S)))
+        assert "lane_pack" not in text
+        assert f"i32[{S},{C}]" in text          # tok, lane for lane
+        return
+    C, seen, step = eng.chunk, [], eng._step_fn
+
+    def keeping(*a):
+        # the marshal the engine has always made, from the slots as the
+        # tick found them
+        want_n = np.zeros((SLOTS,), np.int32)
+        want_fill = np.zeros((SLOTS,), np.int32)
+        want_tok = np.zeros((SLOTS, C), np.int32)
+        for i, s in enumerate(eng.pool.slots):
+            if s is None:
+                continue
+            n = min(C, s.n_prompt - s.cursor) if s.prefilling else 1
+            want_n[i], want_fill[i] = n, s.cursor
+            want_tok[i, :n] = s.tokens[s.cursor:s.cursor + n]
+        np.testing.assert_array_equal(np.asarray(a[5]), want_n)
+        np.testing.assert_array_equal(np.asarray(a[4]), want_fill)
+        np.testing.assert_array_equal(np.asarray(a[2]), want_tok)
+        seen.append(int((want_n > 1).sum()))
+        return step(*a)
+    eng._step_fn = keeping
+    comps = eng.run(max_steps=500)
+    assert len(comps) == 6 and all(c.status == "ok" for c in comps)
+    assert max(seen) == SLOTS         # every slot fed a chunk in one tick
+    assert eng.prefill_chunks_deferred == 0
+    assert "prefill_chunks_deferred" not in eng.summary_record()
