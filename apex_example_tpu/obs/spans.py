@@ -38,7 +38,8 @@ from apex_example_tpu.obs import trace as trace_lib
 # loop through span(); the device-side ones through device_span by
 # engine.make_train_step, the loss functions of workloads.py, the models'
 # heads, ops/paged_cache.py (kv_cow, kv_write, kv_gather), the paged branch
-# of models/bert.py, models/xing4.py, models/granite_hybrid.py, ops/lane_pack.py,
+# of models/bert.py, models/xing4.py, models/granite_hybrid.py,
+# models/pangu_moe.py, ops/lane_pack.py,
 # the dropless layer of
 # transformer/expert_parallel.py and serve/engine._slot_step.
 # The serve tick's host phases are tickprof.ENGINE_PHASES (a jax-free
@@ -70,6 +71,9 @@ PHASES = (
     "shared_mlp",       # device: the dense SwiGLU MLP of every hybrid layer
     "gqa_attention",    # device: grouped-query attention over the paged K/V
     "lane_pack",        # device: ops/lane_pack.py, packed rows <-> [slots, lanes]
+    "sandwich_norm",    # device: models/pangu_moe.py, the four norms a layer
+    "mtp",              # device: its next-token module whole (layer + head)
+    "draft_verify",     # device: serve step, compare-and-select after the head
 )
 
 device_span = jax.named_scope

@@ -711,6 +711,23 @@ def _paged_latent_kernel(table_ref, fill_ref, n_new_ref, q_ref, arena_ref,
 # 512 (0.72): a decode slot has one live lane of 16 (PERF.md section 6).
 _PAGED_TILE = 512
 _PAGED_ROW_TILE = 128
+# Mosaic's default scoped-VMEM limit on the v5e (of the chip's 128 MiB).
+_MOSAIC_SCOPED_VMEM_BYTES = 16 << 20
+
+
+def _paged_vmem_limit(rows: int, W: int, kr: int, tile: int, itemsize: int):
+    """The scoped-VMEM limit the kernel asks for: twice what one grid step
+    holds — a slot's whole ``[C x heads, W]`` query block and its output
+    block (both double-buffered), the float32 softmax state of every row
+    (``m`` and ``l`` a whole lane tile wide each) and two page buffers —
+    where that passes Mosaic's default, else None (the default).  At 16
+    lanes x 32 heads a step holds 4.3 MB and the call is what it always
+    was; at 16 x 128 heads (2048 rows: models/pangu_moe.py) it holds 16.25
+    MiB, which is also what the v5e's compiler counts, a quarter of a MiB
+    over the default, and the kernel is refused without a limit."""
+    held = (2 * rows * (W + kr) + 2 * tile * W) * itemsize \
+        + rows * (kr + 2 * 128) * 4
+    return 2 * held if 2 * held > _MOSAIC_SCOPED_VMEM_BYTES else None
 
 
 # jitted so that a model's layers share one trace and one lowering of the
@@ -750,7 +767,9 @@ def _paged_latent_pallas(qf, arena, block_table, fill, n_new, scale, kr,
         out_shape=[sds((S, rows, kr), qf.dtype, qf, arena),
                    sds((S,), jnp.int32, qf, arena)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_paged_vmem_limit(
+                rows, W, kr, pages * BS, arena.dtype.itemsize)),
         name="paged_latent_attention",
         interpret=interpret,
     )(i32(block_table).reshape(-1), i32(fill), i32(n_new),

@@ -196,6 +196,95 @@ def _slot_step(dec, dequant_weights: bool = False, lanes: bool = False):
     return step
 
 
+def draft_tick(dec, params, cache, tok, block_table, fill, n_new, cow_src,
+               cow_dst, rng, temperature, top_k, aux):
+    """One serve tick of a model that drafts for itself with its own
+    next-token module (``num_nextn_predict_layers``; models/pangu_moe.py):
+    verify this tick's draft, deliver one token or two, and make the next
+    draft.  Traced inside :func:`_draft_step`'s one program.
+
+    A greedy decoding slot feeds ``[t_p, d]`` (``aux[:, 0]``, ``n_draft``,
+    says how many of a slot's last lanes are drafts: 1 or 0).  The model's
+    first call returns the head's logits on each slot's verify lanes
+    ``[SLOTS, 2, V]`` (the lane before the draft and the draft's; the
+    sampled lane twice where there is none) and every lane's normed hidden
+    state.  ``n1`` is sampled from the first as :func:`_slot_step` samples
+    (so a sampled-temperature slot, which feeds no draft, behaves as on the
+    plain path); the draft is accepted where ``d == n1``, and ``n2``, the
+    greedy choice after it, is then delivered with it.  The second call
+    runs the module on every lane — lane ``j`` reads the token that
+    follows it: the next lane's inside a prompt chunk, ``aux[:, 1]`` (the
+    prompt's next token, from the host; -1 where the prompt ends here or
+    the slot decodes) or else ``n1`` after the verified lane, ``n2`` after
+    the draft's — so its cache leaf fills in step with the model's, and
+    reads the next draft at the last accepted lane.  A rejected lane's
+    rows lie past the cursor in every leaf and are overwritten next tick.
+
+    Returns ``(cache, picked [SLOTS, 3] int32 = n1, n2, next draft, finite
+    [SLOTS], counters, logits, draft_logits)``: ``counters`` holds the
+    model's (the module's rows after the layers') and ``drafts_verified``
+    / ``drafts_accepted`` ``[1, 1]``, every tick; the two logits are for
+    whoever compares them with a reference (the tests)."""
+    n_draft, next_tok = aux[:, 0], aux[:, 1]
+    paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
+             "cow_src": cow_src, "cow_dst": cow_dst, "n_draft": n_draft}
+    (logits, hidden), mut = dec.apply(
+        {"params": params, "cache": cache}, tok, train=False, paged=paged,
+        mutable=["cache", "counters"])
+    C = tok.shape[1]
+    with device_span("sample"):
+        n1 = sample_tokens(rng, logits[:, 0], temperature, top_k)
+        finite = jnp.all(jnp.isfinite(logits), axis=(1, 2))
+    with device_span("draft_verify"):
+        lane = jnp.arange(C)[None, :]
+        verified = jnp.clip(n_new - 1 - n_draft, 0, C - 1)[:, None]
+        following = jnp.concatenate(
+            [tok[:, 1:], jnp.zeros_like(tok[:, :1])], axis=1)
+        drafted = jnp.take_along_axis(following, verified, axis=1)[:, 0]
+        accepted = (n_draft > 0) & (n1 == drafted)
+        n2 = jnp.argmax(logits[:, 1], axis=-1).astype(jnp.int32)
+        after = jnp.where(next_tok >= 0, next_tok, n1)
+        next_ids = jnp.where(lane == verified, after[:, None], following)
+        next_ids = jnp.where(lane == verified + 1, n2[:, None], next_ids)
+        last = jnp.minimum(verified[:, 0] + accepted, C - 1)
+    draft_logits, drafted_mut = dec.apply(
+        {"params": params, "cache": mut["cache"]}, tok, train=False,
+        paged=paged, draft_from=(hidden, next_ids, last),
+        mutable=["cache", "counters"])
+    with device_span("draft_verify"):
+        picked = jnp.stack(
+            [n1, n2, jnp.argmax(draft_logits, axis=-1).astype(jnp.int32)],
+            axis=1)
+        # the module's rows after the layers', and the tick's verdicts
+        counters = dict(mut["counters"])
+        for name, rows in drafted_mut["counters"].items():
+            counters[name] = jnp.concatenate([counters[name], rows]) \
+                if name in counters else rows
+        one = lambda x: jnp.sum(x.astype(jnp.int32)).reshape(1, 1)
+        counters["drafts_verified"] = one(n_draft)
+        counters["drafts_accepted"] = one(accepted)
+    return (drafted_mut["cache"], picked, finite, counters, logits,
+            draft_logits)
+
+
+@functools.lru_cache(maxsize=8)
+def _draft_step(dec, dequant_weights: bool = False):
+    """:func:`_slot_step` for a self-drafting model: :func:`draft_tick` as
+    ONE compiled program, the cache donated.  Two fetches a tick, as the
+    plain step: the tokens (n1, n2 and the next draft of every slot in one
+    array) and the logits-finite mask; the counters stay on the device."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def step(params, cache, *rest):
+        if dequant_weights:
+            from apex_example_tpu.quant import weights as _qw
+            with device_span("dequant_weights"):
+                params = _qw.dequantize_tree(params)
+        return draft_tick(dec, params, cache, *rest)[:4]
+
+    return step
+
+
 def _current_mesh():
     """The registered parallel_state mesh, or None when serving runs
     unsharded (no mesh, or every axis trivial)."""
@@ -348,7 +437,7 @@ class ServeEngine:
                  handoff_sink=None, slo=None,
                  slo_window_s: Optional[float] = None,
                  slo_window_ticks: int = 0, tick_profiler=None,
-                 speculate: int = 0, proposer=None,
+                 speculate: Optional[int] = None, proposer=None,
                  tenants=None, tag_tenants: bool = False,
                  advertise_prefixes: int = 0):
         if weight_quant not in ("none", "int8", "fp8"):
@@ -361,8 +450,27 @@ class ServeEngine:
             raise ValueError("a prefill-role engine needs a "
                              "handoff_sink to ship finished prefills to "
                              "(serve/disagg.py transports)")
+        # Self-drafting: a model that carries a next-token module
+        # (``num_nextn_predict_layers``) is served with it — the module
+        # drafts that many tokens a tick on the device, in the tick's own
+        # program (_draft_step) — unless the caller says ``speculate=0``.
+        # Every other model defaults to no speculation, as before.
+        own = int(getattr(model, "num_nextn_predict_layers", 0))
+        if speculate is None:
+            speculate = own
         if speculate < 0:
             raise ValueError(f"speculate must be >= 0, got {speculate}")
+        self.self_draft = bool(speculate and own)
+        if self.self_draft and speculate > own:
+            raise ValueError(
+                f"speculate {speculate}: {type(model).__name__} drafts with "
+                f"its {own} next-token module(s), {own} token(s) a tick "
+                "(ROADMAP: K > 1)")
+        if self.self_draft and proposer is not None:
+            raise ValueError(
+                f"{type(model).__name__} drafts for itself; its paged head "
+                "runs on the verify lanes of its own draft only, so a host "
+                "proposer has nothing to be verified against")
         if speculate and role != "both":
             raise ValueError("--speculate needs the interleaved engine "
                              "(role 'both'); disaggregated roles keep "
@@ -376,8 +484,10 @@ class ServeEngine:
         self.pool = BlockPool(model, num_slots, max_len,
                               block_size=block_size,
                               num_blocks=num_blocks, kv_quant=kv_quant,
-                              spec_slack=speculate)
-        if speculate and not getattr(model, "all_lane_logits", True):
+                              spec_slack=speculate,
+                              rows_read_next_token=self.self_draft)
+        if speculate and not self.self_draft \
+                and not getattr(model, "all_lane_logits", True):
             raise ValueError(
                 "speculate verifies draft lanes against every lane's "
                 f"logits; {type(model).__name__}'s paged head runs on the "
@@ -407,7 +517,8 @@ class ServeEngine:
         # plain path untouched.
         self.speculate = int(speculate)
         self.proposer = proposer
-        if self.speculate and self.proposer is None:
+        if self.speculate and self.proposer is None \
+                and not self.self_draft:
             from apex_example_tpu.spec import NgramProposer
             self.proposer = NgramProposer()
         if self.speculate:
@@ -498,6 +609,8 @@ class ServeEngine:
             "serve_spec_step" if self.speculate
             else "serve_prefill_step" if role == "prefill"
             else "serve_decode_step",
+            _draft_step(self.pool.dec, weight_quant != "none")
+            if self.self_draft else
             _slot_step(self.pool.dec, dequant_weights=weight_quant != "none",
                        lanes=bool(self.speculate)))
         self._t0 = time.perf_counter()
@@ -732,6 +845,10 @@ class ServeEngine:
         temps = np.zeros((S,), np.float32)
         ks = np.zeros((S,), np.int32)
         drafts: Dict[int, List[int]] = {}
+        # self-drafting: per slot, how many of its last lanes are drafts,
+        # and the prompt token after a chunk that ends inside its prompt
+        # (-1: the module reads the token sampled this tick)
+        aux = np.tile(np.int32([0, -1]), (S, 1)) if self.self_draft else None
         # The token budget of chunked prefill, for a model whose rows are
         # packed: a chunk of more than one lane is granted whole or not
         # at all, oldest admission first; a slot granted nothing has
@@ -769,7 +886,11 @@ class ServeEngine:
                 tok[i, :n] = [slot.tokens[slot.cursor]] + draft
             else:
                 tok[i, :n] = slot.tokens[slot.cursor:slot.cursor + n]
+                if aux is not None and slot.cursor + n < slot.n_prompt:
+                    aux[i, 1] = slot.tokens[slot.cursor + n]
             n_new[i] = n
+            if aux is not None:
+                aux[i, 0] = len(drafts.get(i, ()))
             # Map/COW the blocks this slot writes this tick (draws from
             # the budget reserved at admission, so it cannot OOM).
             cow_src[i], cow_dst[i] = pool.stage_writes(i, n)
@@ -781,6 +902,8 @@ class ServeEngine:
                 jnp.asarray(n_new), jnp.asarray(cow_src),
                 jnp.asarray(cow_dst), key, jnp.asarray(temps),
                 jnp.asarray(ks))
+        if aux is not None:
+            args += (jnp.asarray(aux),)
         ph.enter("engine.enqueue")
         if self.mesh is not None:
             # Pallas custom calls are opaque to the SPMD partitioner;
@@ -797,9 +920,15 @@ class ServeEngine:
         # run and the device-to-host copy.  (On CPU jax dispatch is
         # synchronous, so the device time hides in engine.enqueue.)
         ph.enter("engine.sync")
-        lane_greedy = lane_finite = None
+        lane_greedy = lane_finite = next_draft = None
         counted = []
-        if self.speculate:
+        if self.self_draft:
+            # [n1, n2, the next draft] a slot in the one fetch of tokens
+            pool.cache, picked, finite, *counted = outs
+            picked = np.asarray(picked)
+            lane_greedy, next_draft, nxt = (picked[:, :2], picked[:, 2],
+                                            picked[:, 0])
+        elif self.speculate:
             pool.cache, nxt, finite, lane_greedy, lane_finite = outs
             lane_greedy = np.asarray(lane_greedy)
             lane_finite = np.asarray(lane_finite)
@@ -807,6 +936,8 @@ class ServeEngine:
             pool.cache, nxt, finite, *counted = outs
         nxt = np.asarray(nxt)          # the scheduler's host sync
         finite = np.asarray(finite)
+        if self.self_draft:
+            lane_finite = np.repeat(finite[:, None], 2, axis=1)
         now = t_dispatch_end = ph.enter("engine.harvest")
 
         fault = self.fault
@@ -900,6 +1031,11 @@ class ServeEngine:
             # would hit every record), and catching it above would both
             # mislabel it a slot failure and re-terminate an
             # already-evicted slot.
+            if self.self_draft:
+                # what the module drafted for the next tick, for a slot that
+                # goes on decoding greedily (any other keeps one lane)
+                slot.draft = int(next_draft[i]) if not slot.prefilling \
+                    and slot.request.temperature == 0 else None
             if reason is not None:
                 self._finish(i, reason, now)
             elif self.role == "prefill" and slot.n_generated == 1:
@@ -996,8 +1132,11 @@ class ServeEngine:
         k = min(self.speculate, remaining - 1, self.chunk - 1)
         if k <= 0:
             return []
-        draft = self.proposer.propose(req.uid, req.prompt,
-                                      slot.tokens[slot.n_prompt:], k)
+        if self.self_draft:
+            draft = [] if slot.draft is None else [slot.draft]
+        else:
+            draft = self.proposer.propose(req.uid, req.prompt,
+                                          slot.tokens[slot.n_prompt:], k)
         out: List[int] = []
         for t in list(draft)[:k]:
             t = int(t)
@@ -1037,6 +1176,10 @@ class ServeEngine:
                 f"degenerate greedy token {bonus} (vocab "
                 f"{self.vocab_size}) — poisoned sampling path")
         self.tokens_drafted += len(draft)
+        if self.self_draft:
+            # each draft with the output position it claimed and its verdict
+            slot.drafts.extend((slot.n_generated + j, d, j < m)
+                               for j, d in enumerate(draft))
         if slot.n_generated == 0:
             slot.t_first_token = now
         reason = None
@@ -1097,7 +1240,8 @@ class ServeEngine:
             t_first_token=slot.t_first_token,
             t_finish=now,
             status=status,
-            error=digest)
+            error=digest,
+            drafts=slot.drafts)
         self.completions.append(comp)
         self.counts[status] += 1
         if self.slo is not None and status not in ("handoff", "migrated"):
@@ -1801,7 +1945,8 @@ class ServeEngine:
         # sample — the bonus lane and plain/sampled-path tokens).
         if self.speculate:
             rec["speculate_k"] = self.speculate
-            rec["draft_kind"] = getattr(self.proposer, "name", "custom")
+            rec["draft_kind"] = "mtp" if self.self_draft \
+                else getattr(self.proposer, "name", "custom")
             rec["tokens_drafted"] = self.tokens_drafted
             rec["tokens_accepted"] = self.tokens_accepted
             rec["tokens_sampled"] = self.tokens_sampled

@@ -46,7 +46,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 _uid = itertools.count()
 
@@ -168,6 +168,9 @@ class Completion:
     t_finish: float
     status: str = "ok"
     error: Optional[str] = None  # traceback digest for status "failed"
+    # a self-drafting engine's verified drafts: (index in ``tokens`` the
+    # draft claimed, the drafted token, accepted)
+    drafts: List[Tuple[int, int, bool]] = field(default_factory=list)
 
     @property
     def ttft_s(self) -> Optional[float]:

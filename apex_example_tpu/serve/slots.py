@@ -259,6 +259,11 @@ class Slot:
     ``block_keys`` parallels the slot's mapped blocks: the chain key
     for registered (full, immutable) blocks, None for a mutable block
     still filling (registered by ``commit_writes`` when it fills).
+
+    ``draft`` is what a self-drafting model's module proposed for the
+    token after the newest (fed beside it next tick), ``drafts`` every
+    draft verified so far as ``(output index it claimed, token,
+    accepted)`` (serve/engine.py).
     """
 
     request: Request
@@ -272,6 +277,8 @@ class Slot:
     n_mapped: int = 0
     reserved: int = 0
     block_keys: List[Optional[Tuple]] = field(default_factory=list)
+    draft: Optional[int] = None
+    drafts: List[Tuple[int, int, bool]] = field(default_factory=list)
 
     @property
     def n_prompt(self) -> int:
@@ -298,7 +305,8 @@ class BlockPool:
 
     def __init__(self, model, num_slots: int, max_len: int,
                  block_size: int = 8, num_blocks: Optional[int] = None,
-                 kv_quant: bool = False, spec_slack: int = 0):
+                 kv_quant: bool = False, spec_slack: int = 0,
+                 rows_read_next_token: bool = False):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if max_len < 2:
@@ -328,6 +336,12 @@ class BlockPool:
         # cursor within a tick, so the worst-case reservation must cover
         # those in-flight positions or _alloc_for would fault mid-tick.
         self.spec_slack = int(spec_slack)
+        # A self-drafting model's module caches, at position i, a row
+        # computed from token i + 1 as well (models/pangu_moe.py): the
+        # last position of a shared prefix would hold the row of the
+        # OTHER request's next token, so such a pool shares one token
+        # less and the sharer writes that position itself (_match_prefix).
+        self.rows_read_next_token = bool(rows_read_next_token)
         self.dec = model.clone(decode=True, slot_decode=True,
                                fused_attention=False,
                                kv_num_blocks=num_blocks,
@@ -450,11 +464,16 @@ class BlockPool:
             - self._reserved_total >= need
 
     def _match_prefix(self, prompt) -> Tuple[int, List[int], List[Tuple]]:
-        """``alloc.match_prefix``, or no match at all for a cache tree
-        with per-slot state."""
+        """``alloc.match_prefix``; no match at all for a cache tree with
+        per-slot state, one token less where rows read the next token."""
         if self.per_slot_state:
             return 0, [], []
-        return self.alloc.match_prefix(prompt)
+        shared, bids, keys = self.alloc.match_prefix(prompt)
+        if self.rows_read_next_token and shared:
+            shared -= 1
+            n_mapped = math.ceil(shared / self.block_size)
+            bids, keys = bids[:n_mapped], keys[:n_mapped]
+        return shared, bids, keys
 
     # -------------------------------------------------------- lifecycle
 

@@ -142,7 +142,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--arch", default="gpt_tiny",
                    choices=["gpt_tiny", "gpt_base", "xing4_tiny",
                             "xing4_29b_a4b_cut", "granite_hybrid_tiny",
-                            "granite_4_0_h_micro"],
+                            "granite_4_0_h_micro", "pangu_moe_tiny",
+                            "openpangu_ultra_moe_718b_cut"],
                    help="gpt_*: the post-LN decoder (float32). xing4_*: "
                         "latent attention, dropless experts, hyper-"
                         "connected residual (models/xing4.py): the tiny "
@@ -159,7 +160,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "(prefix_hit_rate reads 0: the state at a prefix "
                         "boundary is held nowhere), and --speculate (a "
                         "state cannot be rolled back), --kv-quant and a "
-                        "model-sharded --mesh (not built) are refused")
+                        "model-sharded --mesh (not built) are refused. "
+                        "pangu_*/openpangu_*: sandwich-normed latent "
+                        "attention and dropless experts with ONE next-"
+                        "token module that drafts for the engine "
+                        "(models/pangu_moe.py): the tiny preset in "
+                        "float32, one chip's share of openPangu-Ultra-"
+                        "MoE-718B at its published widths in bfloat16 (16 "
+                        "of 256 experts, 1/8 of the vocabulary, 5 layers "
+                        "and the module); served drafting one token a "
+                        "tick with no flag (--speculate 0 turns it off, "
+                        "--speculate 2, --kv-quant and a model-sharded "
+                        "--mesh are refused)")
     p.add_argument("--checkpoint-dir", default=None,
                    help="CheckpointManager directory to restore params "
                         "from (omit = random init, smoke mode)")
@@ -342,7 +354,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "attention, scales copied with their blocks "
                         "under COW/prefix sharing (quant/kv.py) — "
                         "~1.9x the bf16 arena's bytes, ~3.9x fp32's")
-    p.add_argument("--speculate", type=int, default=0, metavar="K",
+    p.add_argument("--speculate", type=int, default=None, metavar="K",
                    help="speculative decoding (ISSUE 18): a host-side "
                         "proposer drafts up to K tokens per greedy slot "
                         "per tick and the engine verifies all lanes in "
@@ -353,7 +365,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "while tokens/tick rises above 1.0; rejected "
                         "lanes roll back for free (the cursor simply "
                         "does not advance).  0 = off, bit-identical to "
-                        "the plain path")
+                        "the plain path; not given = what the model "
+                        "carries: 0, or 1 for a model with a next-token "
+                        "module, which drafts on the device instead of "
+                        "the host proposer")
     p.add_argument("--draft", default="ngram",
                    choices=["ngram", "none"],
                    help="draft proposer for --speculate: 'ngram' "
@@ -598,6 +613,8 @@ def run_serve(args):
     from apex_example_tpu.models.gpt import gpt_base, gpt_tiny
     from apex_example_tpu.models.granite_hybrid import (granite_4_0_h_micro,
                                                         granite_hybrid_tiny)
+    from apex_example_tpu.models.pangu_moe import (
+        openpangu_ultra_moe_718b_cut, pangu_moe_tiny)
     from apex_example_tpu.models.xing4 import (xing4_29b_a4b_cut,
                                                xing4_tiny)
     from apex_example_tpu.parallel.mesh import (parse_serve_mesh,
@@ -632,6 +649,8 @@ def run_serve(args):
              "xing4_29b_a4b_cut": xing4_29b_a4b_cut,
              "granite_hybrid_tiny": granite_hybrid_tiny,
              "granite_4_0_h_micro": granite_4_0_h_micro,
+             "pangu_moe_tiny": pangu_moe_tiny,
+             "openpangu_ultra_moe_718b_cut": openpangu_ultra_moe_718b_cut,
              }[args.arch](tensor_parallel=tp > 1)
     max_len = args.max_len
     if max_len is None:
@@ -687,7 +706,7 @@ def run_serve(args):
     if args.tick_profile_every < 1:
         raise SystemExit(f"--tick-profile-every must be >= 1, got "
                          f"{args.tick_profile_every}")
-    if args.speculate < 0:
+    if args.speculate is not None and args.speculate < 0:
         raise SystemExit(f"--speculate must be >= 0, got "
                          f"{args.speculate}")
     if args.speculate and args.role != "both":
@@ -919,7 +938,8 @@ def run_serve(args):
                                 else None,
                                 run_id=run_id)
     proposer = None
-    if args.speculate:
+    if args.speculate and not getattr(model, "num_nextn_predict_layers", 0):
+        # (a model with a next-token module drafts for itself)
         from apex_example_tpu.spec import get_proposer
         proposer = get_proposer(args.draft, ngram=args.draft_ngram)
     parallel_state.set_mesh(mesh)

@@ -98,11 +98,14 @@ def step_traced_with():
 
     @contextlib.contextmanager
     def pinned(xla: bool):
-        engine._slot_step.cache_clear()
+        steps = (engine._slot_step, engine._draft_step)
+        for step in steps:
+            step.cache_clear()
         try:
             with _config.force_xla(xla):
                 yield
         finally:
-            engine._slot_step.cache_clear()
+            for step in steps:
+                step.cache_clear()
 
     return pinned

@@ -355,3 +355,28 @@ def test_arena_leaf_is_blocks_by_rows_by_merged_heads():
     # layout-blind accounting: K and V, float32, every layer
     assert pool.kv_bytes_per_token() == 2 * LAYERS * HIDDEN * 4
     assert pool.kv_bytes_per_token_bf16() == 2 * LAYERS * HIDDEN * 2
+
+
+def test_tpu_latent_kernel_compiles_at_128_heads(one_chip):
+    """ISSUE 36: at 16 lanes x 128 heads (models/pangu_moe.py at the
+    published widths) one grid step of the paged latent kernel holds 16.25
+    MiB, over Mosaic's default scoped-VMEM limit, and the v5e's compiler
+    refuses it; the limit the kernel asks for is worked out from the
+    shapes, and at 32 heads (models/xing4.py) it asks for none."""
+    from apex_example_tpu.ops import attention
+    assert attention._paged_vmem_limit(512, 640, 512, 512, 2) is None
+    assert attention._paged_vmem_limit(2048, 640, 512, 512, 2) \
+        == 2 * int(16.25 * 2 ** 20)
+    S, C, H, W, BS, MB = 64, 16, 128, LATENT_WIDTH, 16, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda q, arena, table, fill, n_new: attention._paged_latent_pallas(
+            q, arena, table, fill, n_new, scale=0.07, kr=512,
+            interpret=False)).lower(
+        sds((S, C, H, W), jnp.bfloat16), sds((S * MB, BS, W), jnp.bfloat16),
+        sds((S, MB), i32), sds((S,), i32), sds((S,), i32)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
