@@ -54,9 +54,12 @@ Weights and activations are ``dtype``/``param_dtype`` (bfloat16 as
 served); the state and its recurrence, softplus, ``exp``, norm statistics,
 softmax and logits are float32; convolution rows and K/V are ``dtype``.
 The model sows ``ssm_slots_advanced [mamba layers, slots]`` (1 where a
-slot's state moved this tick), ``lanes_live [1, slots]`` (``n_new``) and
-``rows_dense [1, 1]`` (the rows its token-wise products ran on: ``R``
-packed, ``SLOTS * C`` not) into the ``counters`` collection.
+slot's state moved this tick), ``ssm_state_visits [mamba layers, slots]``
+(1 where the scan's kernel fetched and wrote a slot's state, ``ops/ssd.py``;
+nothing where the XLA form ran, which reads every slot's), ``lanes_live
+[1, slots]`` (``n_new``) and ``rows_dense [1, 1]`` (the rows its token-wise
+products ran on: ``R`` packed, ``SLOTS * C`` not) into the ``counters``
+collection.
 """
 
 from __future__ import annotations
@@ -125,11 +128,13 @@ def _a_log_init(key, shape, dtype):
 
 
 class MambaMixer(nn.Module):
-    """Returns ``(y, advanced)``: ``advanced [S]`` 1 where the paged path
-    moved a slot's state, None from the plain forward.  ``h`` is ``[S, L,
-    d]``, or with ``lanes`` (a ``lane_pack.LaneMap``) its packed rows ``[R,
-    d]``: the projections, the gate and its norm run on what they are
-    given, the convolution and the scan on ``[S, L, ...]``."""
+    """Returns ``(y, advanced, visits)``: ``advanced [S]`` 1 where the
+    paged path moved a slot's state, ``visits [S]`` 1 where the scan's
+    kernel fetched and wrote it; None from the plain forward (and ``visits``
+    from the scan's XLA form).  ``h`` is ``[S, L, d]``, or with ``lanes`` (a
+    ``lane_pack.LaneMap``) its packed rows ``[R, d]``: the projections, the
+    gate and its norm run on what they are given, the convolution and the
+    scan on ``[S, L, ...]``."""
 
     hidden_size: int
     n_heads: int
@@ -195,7 +200,7 @@ class MambaMixer(nn.Module):
             xbc, rows = ssd.causal_conv(rows, xbc, conv_w, conv_b, n_new,
                                         reset)
             xbc = jax.nn.silu(xbc)
-            y, state = ssd.ssd_scan(
+            y, state, visits = ssd.ssd_scan_counted(
                 state, xbc[..., :di].reshape(S, L, H, P), dt, a_log,
                 xbc[..., di:di + N], xbc[..., di + N:], D, live,
                 chunk=self.chunk_size, reset=reset)
@@ -208,7 +213,9 @@ class MambaMixer(nn.Module):
         y = y * jax.nn.silu(z.astype(F32))
         y = rms_norm(y, norm, self.rms_norm_eps).astype(self.dtype)
         out = matmul_f32(y, w_out).astype(self.dtype)
-        return out, (n_new > 0).astype(jnp.int32) if carried else None
+        if not carried:
+            return out, None, None
+        return out, (n_new > 0).astype(jnp.int32), visits
 
 
 class GQAttention(nn.Module):
@@ -314,10 +321,10 @@ class GraniteHybridLayer(nn.Module):
         norm = lambda name, t: rms_norm(
             t, self.param(name, nn.initializers.ones, (d,), pd),
             eps).astype(dtype)
-        h, moved = norm("norm1", x), None
+        h, counts = norm("norm1", x), (None, None)
         if self.kind == "mamba":
             with device_span("ssm_mixer"):
-                y, moved = MambaMixer(
+                y, *counts = MambaMixer(
                     d, c["mamba_n_heads"], c["mamba_d_head"],
                     c["mamba_d_state"], c["mamba_d_conv"],
                     c["mamba_chunk_size"], eps, dtype, pd, c["decode"],
@@ -331,7 +338,7 @@ class GraniteHybridLayer(nn.Module):
         x = (x.astype(F32) + r * y.astype(F32)).astype(dtype)
         y = SharedMLP(d, c["intermediate_size"], dtype, pd,
                       name="mlp")(norm("norm2", x))
-        return (x.astype(F32) + r * y.astype(F32)).astype(dtype), moved
+        return (x.astype(F32) + r * y.astype(F32)).astype(dtype), counts
 
 
 class GraniteHybridForCausalLM(nn.Module):
@@ -421,18 +428,23 @@ class GraniteHybridForCausalLM(nn.Module):
         if lanes is not None:
             # a dead row holds zeros from here on (lane_pack's promise)
             x = jnp.where(lanes.row_live[:, None], x, 0)
-        moves = []
+        moves, visits = [], []
         for i, kind in enumerate(self.layer_kinds()):
-            x, moved = GraniteHybridLayer(cfg, kind, name=f"layer_{i}")(
-                x, pos, paged, lanes)
+            x, (moved, visited) = GraniteHybridLayer(
+                cfg, kind, name=f"layer_{i}")(x, pos, paged, lanes)
             if moved is not None:
                 moves.append(moved)
+            if visited is not None:
+                visits.append(visited)
         if paged is not None:
             # what the layers did this tick, read by the engine when the
             # "counters" collection is mutable, dropped otherwise
             keep = dict(reduce_fn=lambda _, new: new, init_fn=lambda: None)
             if moves:
                 self.sow("counters", "ssm_slots_advanced", jnp.stack(moves),
+                         **keep)
+            if visits:
+                self.sow("counters", "ssm_state_visits", jnp.stack(visits),
                          **keep)
             self.sow("counters", "lanes_live", paged["n_new"][None, :],
                      **keep)

@@ -183,7 +183,7 @@ def _kernel_cases():
     import jax.numpy as jnp
 
     from apex_example_tpu import ops
-    from apex_example_tpu.ops import attention, grouped_matmul
+    from apex_example_tpu.ops import attention, grouped_matmul, ssd
     from apex_example_tpu.ops.fused_optim import adagrad_update_leaf
 
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 256))
@@ -272,6 +272,27 @@ def _kernel_cases():
          (rnd((16, 3584, 1024)) / 60).astype(jnp.bfloat16),
          (rnd((16, 3584, 1024)) / 60).astype(jnp.bfloat16),
          (rnd((16, 1024, 3584)) / 32).astype(jnp.bfloat16), sizes)))
+
+    # The Mamba-2 chunk as serve.py --arch granite_4_0_h_micro reaches it
+    # (64 heads of 64, 128 state columns, 16 lanes; 8 slots): slots in
+    # prefill, decoding, part-way and dead, one at its request's start.  y
+    # of a slot with no live lane is zeros from the kernel and its resting
+    # state's read-out from XLA; nothing reads it, so it is blanked here.
+    n_new = jnp.asarray([16, 1, 0, 9, 1, 0, 1, 16], jnp.int32)
+    live = jnp.arange(16)[None, :] < n_new[:, None]
+
+    def scan(state, x, dt, a_log, B, C, D):
+        y, new = ssd.ssd_scan(state, x, dt, a_log, B, C, D, live, chunk=256,
+                              reset=jnp.arange(8) == 3)
+        return jnp.where(n_new[:, None, None, None] > 0, y, 0), new
+
+    cases.append((
+        "ssd_scan S8 L16 H64 P64 N128 f32",
+        scan,
+        (rnd((8, 64, 64, 128)), rnd((8, 16, 64, 64)),
+         jax.nn.softplus(rnd((8, 16, 64)) - 2.0),
+         jnp.log(jnp.linspace(1.0, 16.0, 64)), rnd((8, 16, 128)),
+         rnd((8, 16, 128)), rnd((64,)))))
 
     # Optimizer leaves, smallest BN vector to the embedding table.
     hp = dict(beta1=0.9, beta2=0.999, eps=1e-6, weight_decay=0.01,

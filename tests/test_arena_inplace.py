@@ -24,6 +24,7 @@ The TPU half compiles in this process (never in a child: libtpu
 belongs to one process), inside fixtures (never at import).
 """
 
+import contextlib
 import math
 import os
 import re
@@ -162,26 +163,33 @@ def test_tpu_tick_updates_the_latent_arena_in_place(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
 
 
-@pytest.fixture(scope="module", params=["kernel", "xla"])
-def latent_tick(request, one_chip):
-    """The latent tick compiled as the chip compiles it, on either form of
-    paged latent attention (ops/attention.py).  The ops' dispatch keys on
-    the live backend (the CPU here) and on the test rig's interpreter
-    switch, so the test steers both for the length of the lowering; the
-    engine's cached step is dropped on either side."""
+@contextlib.contextmanager
+def compiled_as_the_chip_does(form):
+    """The ops' dispatch keys on the live backend (the CPU here) and on the
+    test rig's interpreter switch, so a test of the chip's program steers
+    both for the length of the lowering, onto the ``"kernel"`` or the
+    ``"xla"`` form; the engine's cached step is dropped on either side."""
     from apex_example_tpu.ops import _config
     saved = _config.INTERPRET, _config.use_pallas
     _config.INTERPRET = False
     _config.use_pallas = lambda: not _config.FORCE_XLA
     engine_lib._slot_step.cache_clear()
     try:
-        with _config.force_xla(request.param == "xla"):
-            lowered, arena_bytes, _ = _lowered(False, False, one_chip,
-                                               _latent_model())
-        return request.param, lowered.compile(), arena_bytes
+        with _config.force_xla(form == "xla"):
+            yield
     finally:
         _config.INTERPRET, _config.use_pallas = saved
         engine_lib._slot_step.cache_clear()
+
+
+@pytest.fixture(scope="module", params=["kernel", "xla"])
+def latent_tick(request, one_chip):
+    """The latent tick compiled as the chip compiles it, on either form of
+    paged latent attention (ops/attention.py)."""
+    with compiled_as_the_chip_does(request.param):
+        lowered, arena_bytes, _ = _lowered(False, False, one_chip,
+                                           _latent_model())
+    return request.param, lowered.compile(), arena_bytes
 
 
 def test_tpu_tick_walks_the_latent_arena_in_a_kernel(latent_tick):
@@ -215,23 +223,14 @@ def expert_tick(request, one_chip):
     everything that is not the expert layer small.  Steered as
     ``latent_tick`` is."""
     from apex_example_tpu.models.xing4 import Xing4ForCausalLM
-    from apex_example_tpu.ops import _config
     model = Xing4ForCausalLM(
         vocab_size=512, hidden_size=EXPERT_IN, num_layers=2, first_k_dense=1,
         intermediate_size=512, moe_intermediate_size=EXPERT_WIDTH,
         n_routed_experts=EXPERTS)
-    saved = _config.INTERPRET, _config.use_pallas
-    _config.INTERPRET = False
-    _config.use_pallas = lambda: not _config.FORCE_XLA
-    engine_lib._slot_step.cache_clear()
-    try:
-        with _config.force_xla(request.param == "xla"):
-            lowered, _, _ = _lowered(False, False, one_chip, model,
-                                     slots=EXPERT_SLOTS)
-        return request.param, lowered.compile().as_text()
-    finally:
-        _config.INTERPRET, _config.use_pallas = saved
-        engine_lib._slot_step.cache_clear()
+    with compiled_as_the_chip_does(request.param):
+        lowered, _, _ = _lowered(False, False, one_chip, model,
+                                 slots=EXPERT_SLOTS)
+    return request.param, lowered.compile().as_text()
 
 
 def test_tpu_tick_multiplies_the_experts_in_a_kernel(expert_tick):
@@ -301,7 +300,17 @@ def _hybrid_lowered(sharding):
             {l.size for l in leaves})
 
 
-def test_tpu_tick_updates_both_kinds_of_cache_in_place(one_chip):
+@pytest.fixture(scope="module", params=["kernel", "xla"])
+def hybrid_tick(request, one_chip):
+    """The hybrid tick compiled for the chip with the scan's Pallas form
+    (``ops/ssd.py``'s ``ssd_chunk``, what the TPU runs) and with its XLA
+    form.  Steered as ``latent_tick`` is."""
+    with compiled_as_the_chip_does(request.param):
+        lowered, cache_bytes, sizes = _hybrid_lowered(one_chip)
+    return request.param, lowered.compile(), cache_bytes, sizes
+
+
+def test_tpu_tick_updates_both_kinds_of_cache_in_place(hybrid_tick):
     """ISSUE 34: a Mamba layer's per-slot state is read and written where
     it lies, like the arena beside it: the tick compiled for the chip
     holds no copy of a state leaf's or an arena leaf's size, nothing of
@@ -310,10 +319,12 @@ def test_tpu_tick_updates_both_kinds_of_cache_in_place(one_chip):
     convolution rows a slot are kept flat, ``[slots, 3 * 4352]``, so that
     no tile is padded, and are laid out as ``[slots, 3, 4352]`` for the
     convolution and back: a copy of that leaf's 1/300 of the state's
-    bytes, let be.)"""
-    lowered, cache_bytes, sizes = _hybrid_lowered(one_chip)
-    sizes.discard(16 * 3 * 4352)
-    compiled = lowered.compile()
+    bytes, let be.)  ISSUE 37: with the kernel the scan is one named
+    Pallas call a Mamba layer, its state operand aliased to its state
+    result, and no ``select`` passes over a whole state leaf; the XLA form
+    holds that select and no such call."""
+    form, compiled, cache_bytes, sizes = hybrid_tick
+    sizes = sizes - {16 * 3 * 4352}
     text = compiled.as_text()
     copies = [(dtype, dims) for dtype, dims
               in arena_sized_copies(text, min(sizes))
@@ -321,6 +332,16 @@ def test_tpu_tick_updates_both_kinds_of_cache_in_place(one_chip):
     assert copies == []
     assert f"[16,{BS},64,64,128]" not in text
     assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "%ssd_chunk." in line.split("=")[0]]
+    selects = re.findall(r"= f32\[16,64,64,128\]\S* select\(", text)
+    if form == "kernel":
+        assert len(calls) == 2 and not selects         # one a Mamba layer
+        assert all("output_to_operand_aliasing" in c
+                   and "f32[16,64,64,128]" in c for c in calls)
+    else:
+        assert not calls and selects
 
 
 def test_cpu_tick_aliases_both_kinds_of_cache():
