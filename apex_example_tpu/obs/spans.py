@@ -16,7 +16,11 @@ Three kinds of region exist in this stack and they need different tools:
   :class:`Phases` reads each boundary once and gives every consumer the
   same readings; each phase is also a ``jax.profiler.TraceAnnotation``,
   so that an open profiler session shows what the host was doing on the
-  same clock as the device's operations.
+  same clock as the device's operations.  ``Phases.child`` names what
+  happens inside a phase (the serve tick's hand-offs to the runtime:
+  ``engine.build``, ``engine.rng``, ``engine.put``, ``engine.fetch``,
+  tickprof.ENGINE_HANDOFFS) as an annotation alone: a child never
+  changes a boundary.
 
 Using the same names on both sides ("fwd_bwd" as a host span around a
 block that is "fwd_bwd" in the device trace) is the point: a perf PR
@@ -42,8 +46,9 @@ from apex_example_tpu.obs import trace as trace_lib
 # models/pangu_moe.py, ops/lane_pack.py,
 # the dropless layer of
 # transformer/expert_parallel.py and serve/engine._slot_step.
-# The serve tick's host phases are tickprof.ENGINE_PHASES (a jax-free
-# table).  Keep README's "Span naming" paragraph in sync.
+# The serve tick's host phases are tickprof.ENGINE_PHASES and their
+# children tickprof.ENGINE_HANDOFFS (a jax-free table each).  Keep
+# README's "Span naming" paragraph in sync.
 PHASES = (
     "data",             # host: batch synthesis / prefetcher fetch
     "step",             # host: step dispatch (+ fetch when telemetry is on)
@@ -162,6 +167,24 @@ def span(name: str, registry=None):
                 parent_id=parent.span_id if parent is not None else None)
 
 
+class _Silent:
+    """What ``Phases.child`` gives when nothing is annotated."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Silent":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **meta) -> None:
+        return None
+
+
+_SILENT = _Silent()
+
+
 class Phases:
     """The contiguous phases of one loop iteration, each boundary read
     once.
@@ -179,6 +202,11 @@ class Phases:
     events on the clock of the device's lines; with none open each is an
     inactive check (under a microsecond).  A context manager, so that an
     exception leaves no annotation open.
+
+    ``child(name)`` opens a span inside the running phase: an annotation
+    and nothing else.  A child never changes a boundary: ``names``,
+    ``at`` and ``ms`` do not know it, so the records fed from them read
+    the same with children as without.
     """
 
     __slots__ = ("names", "at", "_root", "_open")
@@ -204,6 +232,15 @@ class Phases:
         self.names.append(name)
         self.at.append(now)
         return now
+
+    def child(self, name: str, **meta):
+        """A span inside the running phase, carrying ``meta``: a context
+        manager whose ``set_metadata`` takes what is known only at its
+        end.  Close it before the next ``enter``.  Without ``annotate``
+        it is nothing at all."""
+        if self._root is None:
+            return _SILENT
+        return jax.profiler.TraceAnnotation(name, **meta)
 
     def set_meta(self, **meta) -> None:
         """Further metadata for the iteration's annotation."""

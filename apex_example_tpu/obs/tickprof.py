@@ -108,6 +108,20 @@ ENGINE_PHASES = {
     "engine.harvest": "harvest",          # per-slot loop, finishes
     "engine.gauges": "telemetry",         # histograms, gauges, SLO fold
 }
+# Child spans inside those phases, one level down: every hand-off between
+# the tick's host thread and the runtime, and the host work before the
+# first.  The value is the phase a child lies in, whole.  A child is an
+# annotation only: it moves no boundary and feeds no record.  The
+# hand-offs a tick are ENGINE_HANDOFF_SPANS counted as they are made
+# (``engine.tick``'s ``handoffs=``): ``engine.enqueue`` is one whole.
+ENGINE_HANDOFFS = {
+    "engine.build": "engine.marshal",  # per-slot numpy arrays: host work
+    "engine.rng": "engine.marshal",    # the key split: two small programs
+    "engine.put": "engine.marshal",    # one host-to-device put (arg=, bytes=)
+    "engine.fetch": "engine.sync",     # one device-to-host fetch (out=, bytes=)
+}
+ENGINE_HANDOFF_SPANS = ("engine.rng", "engine.put", "engine.enqueue",
+                        "engine.fetch")
 TRAIN_PHASES = ("data_wait", "dispatch", "device", "checkpoint",
                 "telemetry")
 

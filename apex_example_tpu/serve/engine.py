@@ -535,6 +535,11 @@ class ServeEngine:
             if getattr(model, "packed_lanes", False) else None
         self.prefill_chunks_deferred = 0
         self.prefill_ticks_deferring = 0
+        # Hand-offs between the tick's host thread and the runtime (the
+        # key split, each put, the step's call, each fetch), summed over
+        # the ticks that ran a step.  (Not the KV hand-offs between a
+        # prefill and a decode worker: those are handoffs_in/_out.)
+        self.runtime_handoffs = 0
         self.tokens_drafted = 0
         self.tokens_accepted = 0
         self.tokens_sampled = 0
@@ -830,81 +835,104 @@ class ServeEngine:
         t_admit_end = ph.enter("engine.marshal")
         tracer = self._tracer
         self._spool_ms = 0.0
-        # Chunk width: block_size for interleaved/prefill engines, ONE
-        # for a decode-role engine — its slots only ever feed a single
-        # token per tick (handoffs arrive pre-filled), so its compiled
-        # step drops the prefill lanes and each decode tick pays
-        # 1/block_size of the interleaved program's token FLOPs: the
-        # decode-tick stall the disaggregation removes.
-        S, C = pool.num_slots, self.chunk
-        tok = np.zeros((S, C), np.int32)
-        fill = np.zeros((S,), np.int32)
-        n_new = np.zeros((S,), np.int32)
-        cow_src = np.full((S,), -1, np.int32)
-        cow_dst = np.full((S,), -1, np.int32)
-        temps = np.zeros((S,), np.float32)
-        ks = np.zeros((S,), np.int32)
-        drafts: Dict[int, List[int]] = {}
-        # self-drafting: per slot, how many of its last lanes are drafts,
-        # and the prompt token after a chunk that ends inside its prompt
-        # (-1: the module reads the token sampled this tick)
-        aux = np.tile(np.int32([0, -1]), (S, 1)) if self.self_draft else None
-        # The token budget of chunked prefill, for a model whose rows are
-        # packed: a chunk of more than one lane is granted whole or not
-        # at all, oldest admission first; a slot granted nothing has
-        # n_new = 0 this tick, stages no write and keeps state and rows
-        # bit for bit.  Decoding slots (and a prompt's last single
-        # token) keep their one lane: the rows always hold those.
-        deferred = frozenset()
-        if self._chunk_budget is not None:
-            slots = pool.slots
-            asking = sorted(
-                (i for i in live
-                 if min(C, slots[i].n_prompt - slots[i].cursor) > 1),
-                key=lambda i: (slots[i].admitted_step, slots[i].t_admitted))
-            deferred = frozenset(asking[self._chunk_budget:])
-            self.prefill_chunks_deferred += len(deferred)
-            self.prefill_ticks_deferring += bool(deferred)
-        for i in live:
-            slot = pool.slots[i]
-            fill[i] = slot.cursor
-            if i in deferred:
-                continue
-            # Chunked prefill: up to one block of prompt tokens per
-            # tick; decode feeds the single previously-sampled token.
-            n = min(C, slot.n_prompt - slot.cursor) if slot.prefilling \
-                else 1
-            if self.speculate and not slot.prefilling \
-                    and slot.request.temperature == 0:
-                # Speculative decode lanes: the last sampled token plus
-                # up to K host-drafted candidates, verified in the same
-                # dispatch.  Sampled-temperature slots keep the plain
-                # single-lane path — speculation is greedy-only.
-                draft = self._draft_for(slot)
-                drafts[i] = draft
-                n = 1 + len(draft)
-                tok[i, :n] = [slot.tokens[slot.cursor]] + draft
-            else:
-                tok[i, :n] = slot.tokens[slot.cursor:slot.cursor + n]
-                if aux is not None and slot.cursor + n < slot.n_prompt:
-                    aux[i, 1] = slot.tokens[slot.cursor + n]
-            n_new[i] = n
-            if aux is not None:
-                aux[i, 0] = len(drafts.get(i, ()))
-            # Map/COW the blocks this slot writes this tick (draws from
-            # the budget reserved at admission, so it cannot OOM).
-            cow_src[i], cow_dst[i] = pool.stage_writes(i, n)
-            temps[i] = slot.request.temperature
-            ks[i] = slot.request.top_k
-        self.rng, key = jax.random.split(self.rng)
-        args = (self.params, pool.cache, jnp.asarray(tok),
-                jnp.asarray(pool.table), jnp.asarray(fill),
-                jnp.asarray(n_new), jnp.asarray(cow_src),
-                jnp.asarray(cow_dst), key, jnp.asarray(temps),
-                jnp.asarray(ks))
+        with ph.child("engine.build") as build:
+            # Chunk width: block_size for interleaved/prefill engines, ONE
+            # for a decode-role engine — its slots only ever feed a single
+            # token per tick (handoffs arrive pre-filled), so its compiled
+            # step drops the prefill lanes and each decode tick pays
+            # 1/block_size of the interleaved program's token FLOPs: the
+            # decode-tick stall the disaggregation removes.
+            S, C = pool.num_slots, self.chunk
+            tok = np.zeros((S, C), np.int32)
+            fill = np.zeros((S,), np.int32)
+            n_new = np.zeros((S,), np.int32)
+            cow_src = np.full((S,), -1, np.int32)
+            cow_dst = np.full((S,), -1, np.int32)
+            temps = np.zeros((S,), np.float32)
+            ks = np.zeros((S,), np.int32)
+            drafts: Dict[int, List[int]] = {}
+            # self-drafting: per slot, how many of its last lanes are drafts,
+            # and the prompt token after a chunk that ends inside its prompt
+            # (-1: the module reads the token sampled this tick)
+            aux = np.tile(np.int32([0, -1]), (S, 1)) if self.self_draft \
+                else None
+            # The token budget of chunked prefill, for a model whose rows are
+            # packed: a chunk of more than one lane is granted whole or not
+            # at all, oldest admission first; a slot granted nothing has
+            # n_new = 0 this tick, stages no write and keeps state and rows
+            # bit for bit.  Decoding slots (and a prompt's last single
+            # token) keep their one lane: the rows always hold those.
+            deferred = frozenset()
+            if self._chunk_budget is not None:
+                slots = pool.slots
+                asking = sorted(
+                    (i for i in live
+                     if min(C, slots[i].n_prompt - slots[i].cursor) > 1),
+                    key=lambda i: (slots[i].admitted_step,
+                                   slots[i].t_admitted))
+                deferred = frozenset(asking[self._chunk_budget:])
+                self.prefill_chunks_deferred += len(deferred)
+                self.prefill_ticks_deferring += bool(deferred)
+            for i in live:
+                slot = pool.slots[i]
+                fill[i] = slot.cursor
+                if i in deferred:
+                    continue
+                # Chunked prefill: up to one block of prompt tokens per
+                # tick; decode feeds the single previously-sampled token.
+                n = min(C, slot.n_prompt - slot.cursor) if slot.prefilling \
+                    else 1
+                if self.speculate and not slot.prefilling \
+                        and slot.request.temperature == 0:
+                    # Speculative decode lanes: the last sampled token plus
+                    # up to K host-drafted candidates, verified in the same
+                    # dispatch.  Sampled-temperature slots keep the plain
+                    # single-lane path — speculation is greedy-only.
+                    draft = self._draft_for(slot)
+                    drafts[i] = draft
+                    n = 1 + len(draft)
+                    tok[i, :n] = [slot.tokens[slot.cursor]] + draft
+                else:
+                    tok[i, :n] = slot.tokens[slot.cursor:slot.cursor + n]
+                    if aux is not None and slot.cursor + n < slot.n_prompt:
+                        aux[i, 1] = slot.tokens[slot.cursor + n]
+                n_new[i] = n
+                if aux is not None:
+                    aux[i, 0] = len(drafts.get(i, ()))
+                # Map/COW the blocks this slot writes this tick (draws from
+                # the budget reserved at admission, so it cannot OOM).
+                cow_src[i], cow_dst[i] = pool.stage_writes(i, n)
+                temps[i] = slot.request.temperature
+                ks[i] = slot.request.top_k
+            build.set_metadata(lanes=int(n_new.sum()))
+        # Every hand-off to the runtime from here to the tokens' return is
+        # a child span (tickprof.ENGINE_HANDOFFS) and is counted as it is
+        # made: the key split, each put, the step's call, each fetch.
+        with ph.child("engine.rng"):
+            self.rng, key = jax.random.split(self.rng)
+        handoffs = 1
+
+        def put(arg: str, value: np.ndarray) -> jax.Array:
+            nonlocal handoffs
+            handoffs += 1
+            with ph.child("engine.put", arg=arg, bytes=value.nbytes):
+                return jnp.asarray(value)
+
+        def fetch(out: str, value: jax.Array) -> np.ndarray:
+            nonlocal handoffs
+            handoffs += 1
+            with ph.child("engine.fetch", out=out, bytes=value.nbytes):
+                return np.asarray(value)
+
+        args = (self.params, pool.cache, put("tok", tok),
+                put("table", pool.table), put("fill", fill),
+                put("n_new", n_new), put("cow_src", cow_src),
+                put("cow_dst", cow_dst), key, put("temps", temps),
+                put("ks", ks))
         if aux is not None:
-            args += (jnp.asarray(aux),)
+            args += (put("aux", aux),)
         ph.enter("engine.enqueue")
+        handoffs += 1
         if self.mesh is not None:
             # Pallas custom calls are opaque to the SPMD partitioner;
             # pin the XLA reference ops for the sharded trace exactly
@@ -925,19 +953,22 @@ class ServeEngine:
         if self.self_draft:
             # [n1, n2, the next draft] a slot in the one fetch of tokens
             pool.cache, picked, finite, *counted = outs
-            picked = np.asarray(picked)
+            picked = fetch("picked", picked)   # the scheduler's host sync
             lane_greedy, next_draft, nxt = (picked[:, :2], picked[:, 2],
                                             picked[:, 0])
         elif self.speculate:
             pool.cache, nxt, finite, lane_greedy, lane_finite = outs
-            lane_greedy = np.asarray(lane_greedy)
-            lane_finite = np.asarray(lane_finite)
+            lane_greedy = fetch("lane_greedy", lane_greedy)
+            lane_finite = fetch("lane_finite", lane_finite)
+            nxt = fetch("nxt", nxt)
         else:
             pool.cache, nxt, finite, *counted = outs
-        nxt = np.asarray(nxt)          # the scheduler's host sync
-        finite = np.asarray(finite)
+            nxt = fetch("nxt", nxt)            # the scheduler's host sync
+        finite = fetch("finite", finite)
         if self.self_draft:
             lane_finite = np.repeat(finite[:, None], 2, axis=1)
+        ph.set_meta(handoffs=handoffs)
+        self.runtime_handoffs += handoffs
         now = t_dispatch_end = ph.enter("engine.harvest")
 
         fault = self.fault
@@ -1892,6 +1923,8 @@ class ServeEngine:
             rec["prefill_chunks_deferred"] = self.prefill_chunks_deferred
             rec["prefill_ticks_deferring"] = self.prefill_ticks_deferring
         if self.compute_steps:
+            rec["runtime_handoffs_per_tick"] = round(
+                self.runtime_handoffs / self.compute_steps, 3)
             rec["occupancy"] = round(
                 self._occupancy_sum / (self.compute_steps
                                        * pool.num_slots), 3)

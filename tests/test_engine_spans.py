@@ -9,6 +9,11 @@ programs.
   spin writes nothing,
 - tracing is a pure observer: tokens with a session open equal tokens
   without, and the jitted step compiles once either way,
+- one level down (ISSUE 38): every hand-off between the tick's host thread
+  and the runtime is a child span, whole inside the phase
+  ``ENGINE_HANDOFFS`` names for it, overlapping no other; their number a
+  tick is the tick's ``handoffs=`` and the engine's own count; a child
+  moves no boundary,
 - the Tracer's X events and the TickProfiler's fold come from the same
   boundary readings (they agree to the float, and the streams validate),
 - ``obs.spans.Phases`` telescopes, closes once, and closes on an exception,
@@ -32,8 +37,10 @@ from apex_example_tpu.models.gpt import gpt_tiny
 from apex_example_tpu.obs import schema as obs_schema
 from apex_example_tpu.obs import trace as trace_lib
 from apex_example_tpu.obs.spans import PHASES, Phases
-from apex_example_tpu.obs.tickprof import (ENGINE_PHASES, ENGINE_TICK,
-                                           SERVE_PHASES, TickProfiler)
+from apex_example_tpu.obs.tickprof import (ENGINE_HANDOFF_SPANS,
+                                           ENGINE_HANDOFFS, ENGINE_PHASES,
+                                           ENGINE_TICK, SERVE_PHASES,
+                                           TickProfiler)
 from apex_example_tpu.serve import ServeEngine, synthetic_requests
 from apex_example_tpu.serve.engine import _slot_step
 
@@ -109,7 +116,7 @@ def test_every_tick_that_ran_a_step_has_the_six_phases(traced):
     ticks = [ev for ev in events if ev[0] == ENGINE_TICK]
     ran = []
     for _, start, end, stats in ticks:
-        inside = [ev for ev in events if ev[0] != ENGINE_TICK
+        inside = [ev for ev in events if ev[0] in ENGINE_PHASES
                   and start <= ev[1] and ev[2] <= end]
         if [ev[0] for ev in inside] == NAMES[:1]:
             # it had a request to look at and turned it away or could
@@ -134,8 +141,83 @@ def test_every_tick_that_ran_a_step_has_the_six_phases(traced):
     assert len(ran) == eng.compute_steps > 0
     assert ran == sorted(set(ran)) and ran[-1] < traced["spun"]
     # every phase event belongs to some tick
-    assert sum(ev[0] != ENGINE_TICK for ev in events) \
+    assert sum(ev[0] in ENGINE_PHASES for ev in events) \
         == 6 * len(ran) + (len(ticks) - len(ran))
+
+
+def _by_tick(events):
+    """(tick, its phases by name, its children) of every traced tick."""
+    out = []
+    for tick in (ev for ev in events if ev[0] == ENGINE_TICK):
+        inside = [ev for ev in events
+                  if tick[1] <= ev[1] and ev[2] <= tick[2]]
+        out.append((tick,
+                    {ev[0]: ev for ev in inside if ev[0] in ENGINE_PHASES},
+                    [ev for ev in inside if ev[0] in ENGINE_HANDOFFS]))
+    return out
+
+
+@pytest.mark.parametrize("case", ["inside_their_phase", "disjoint",
+                                  "counted", "none_without_a_step",
+                                  "no_session_no_difference"])
+def test_the_hand_offs_are_children_of_the_phases(traced, case):
+    eng, events = traced["eng"], traced["events"]
+    ticks = _by_tick(events)
+    ran = [t for t in ticks if len(t[1]) == 6]
+    assert len(ran) == eng.compute_steps > 0
+    if case == "inside_their_phase":
+        # every child event of the trace is some tick's, and lies whole
+        # inside the phase the table names for it
+        assert sum(len(kids) for _, _, kids in ticks) \
+            == sum(ev[0] in ENGINE_HANDOFFS for ev in events)
+        for _, phases, kids in ran:
+            assert {k[0] for k in kids} == set(ENGINE_HANDOFFS)
+            for name, start, end, _ in kids:
+                _, lo, hi, _ = phases[ENGINE_HANDOFFS[name]]
+                assert lo <= start <= end <= hi, name
+    elif case == "disjoint":
+        for _, _, kids in ran:
+            for a, b in zip(kids, kids[1:]):
+                assert a[2] <= b[1], (a[0], b[0])
+            # the host's own work first, then the split, then the puts
+            assert [k[0] for k in kids][:3] == [
+                "engine.build", "engine.rng", "engine.put"]
+            assert kids[0][3]["lanes"] >= 1
+    elif case == "counted":
+        # the events, the tick's own word and the engine's count agree:
+        # 1 split + 8 puts + the step's call + 2 fetches on this engine
+        total = 0
+        for tick, phases, kids in ran:
+            made = [k for k in kids if k[0] in ENGINE_HANDOFF_SPANS] \
+                + [phases["engine.enqueue"]]
+            assert len(made) == tick[3]["handoffs"] == 12
+            total += len(made)
+            assert [k[3]["arg"] for k in kids if k[0] == "engine.put"] \
+                == ["tok", "table", "fill", "n_new", "cow_src", "cow_dst",
+                    "temps", "ks"]
+            assert [k[3]["out"] for k in kids if k[0] == "engine.fetch"] \
+                == ["nxt", "finite"]
+            assert all(k[3]["bytes"] > 0 for k in kids
+                       if k[0] in ("engine.put", "engine.fetch"))
+        assert total == eng.runtime_handoffs
+        assert eng.summary_record()["runtime_handoffs_per_tick"] == 12
+    elif case == "none_without_a_step":
+        idle = [t for t in ticks if len(t[1]) != 6]
+        for tick, phases, kids in idle:
+            assert list(phases) == NAMES[:1] and kids == []
+            assert "handoffs" not in tick[3]
+    else:
+        # the count is made with a session or without one
+        assert traced["plain"].runtime_handoffs == eng.runtime_handoffs
+        assert traced["plain"].compute_steps == eng.compute_steps
+        assert _tokens(eng) == _tokens(traced["plain"])
+        assert eng._step_fn._cache_size() == traced["compiled"]
+
+
+def test_speculation_fetches_its_two_lane_arrays_as_well(model_and_params):
+    eng = _engine(model_and_params, speculate=2)
+    eng.run(max_steps=500)
+    assert eng.runtime_handoffs == 14 * eng.compute_steps > 0
 
 
 def test_an_idle_spin_writes_nothing(traced):
@@ -219,6 +301,8 @@ def test_phases_telescope_and_close_once():
         ph.set_meta(live=2)
         now = ph.enter("c")
         end = ph.close()
+        with ph.child("kid", n=1) as kid:   # an annotation, no boundary
+            kid.set_metadata(m=2)
         assert ph.close() == end            # idempotent
     assert ph.names == ["a", "b", "c"] and len(ph.at) == 4
     assert ph.at[2] == now and ph.at[-1] == end
@@ -228,6 +312,9 @@ def test_phases_telescope_and_close_once():
     silent = Phases("t", "a", annotate=False)
     silent.enter("b")
     silent.set_meta(live=1)                 # nothing to carry it: ignored
+    with silent.child("kid", n=1) as kid:   # nothing at all
+        kid.set_metadata(m=2)
+    assert silent.names == ["a", "b"] and len(silent.at) == 2
     assert silent.close() >= silent.at[0]
 
 
@@ -262,13 +349,27 @@ def test_one_table_of_names():
     assert list(reader.ENGINE_PHASES) == NAMES
     assert reader.ENGINE_TICK == ENGINE_TICK
     assert set(reader.SCOPES) < set(PHASES)
+    assert set(ENGINE_HANDOFFS.values()) <= set(NAMES)
+    assert not set(ENGINE_HANDOFFS) & set(NAMES)
+    # what is counted: the children but the host's own work, and the call
+    assert set(ENGINE_HANDOFF_SPANS) \
+        == set(ENGINE_HANDOFFS) - {"engine.build"} | {"engine.enqueue"}
+    spec = importlib.util.spec_from_file_location(
+        "handoff_trace", os.path.join(REPO, "benchmarks",
+                                      "handoff_trace.py"))
+    handoffs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(handoffs)
+    assert handoffs.CHILDREN == ENGINE_HANDOFFS
+    assert handoffs.HANDOFFS == ENGINE_HANDOFF_SPANS
     readme = open(os.path.join(REPO, "README.md")).read()
-    for name in NAMES + [ENGINE_TICK] + list(reader.SCOPES):
+    for name in NAMES + [ENGINE_TICK] + list(ENGINE_HANDOFFS) \
+            + list(reader.SCOPES):
         assert f"`{name}`" in readme, name
+    # every engine.* name the engine emits is in one of the two tables
     engine_src = open(os.path.join(
         REPO, "apex_example_tpu", "serve", "engine.py")).read()
     assert set(re.findall(r'"(engine\.\w+)"', engine_src)) \
-        == set(NAMES)
+        == set(NAMES) | set(ENGINE_HANDOFFS)
 
 
 def _op_names(compiled):

@@ -267,6 +267,10 @@ def test_served_tokens_are_the_same_with_drafting_on_and_off(
     assert summary["output_tokens"] \
         == summary["tokens_accepted"] + summary["tokens_sampled"]
     assert "speculate_k" not in off.summary_record()
+    # hand-offs to the runtime a tick: the split, 8 puts and `aux`, the
+    # step's call, 2 fetches (the picked tokens in one); 12 without `aux`
+    assert summary["runtime_handoffs_per_tick"] == 13
+    assert off.runtime_handoffs == 12 * off.compute_steps
     # the counters, in every tick's tree, 0 included
     log = [jax.tree_util.tree_map(np.asarray, t) for _, t in on.counter_log]
     assert sum(int(t["drafts_verified"].sum()) for t in log) \
