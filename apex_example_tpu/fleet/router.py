@@ -775,10 +775,22 @@ class FleetRouter:
                 # already released at rescue/drain time must not eat an
                 # unrelated request's slot (review finding, ISSUE 12).
                 if uid in self._done:
-                    self._duplicates += 1
-                    if self._stale.get(uid) == src:
+                    booked = self._stale.get(uid) == src
+                    meta = self._replicas.get(src) if booked else None
+                    if booked and status == "handoff":
+                        # The decode worker's terminal overtook the
+                        # prefill replica's report of the handoff that
+                        # fed it (two outboxes polled in turn; a short
+                        # request on a fast tick): the handoff happened
+                        # and its booking is still live — count it as
+                        # one, not as a duplicate.
+                        self._handoffs += 1
+                        if meta is not None:
+                            meta.bump("handoff")
+                    else:
+                        self._duplicates += 1
+                    if booked:
                         del self._stale[uid]
-                        meta = self._replicas.get(src)
                         if meta is not None:
                             meta.inflight = max(meta.inflight - 1, 0)
                 return

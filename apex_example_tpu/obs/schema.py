@@ -821,8 +821,8 @@ OPTIONAL: Dict[str, Dict[str, Any]] = {
         # waiting — every other stream is byte-identical.
         "prefill_chunks_deferred": int,  # chunks left waiting a tick
         "prefill_ticks_deferring": int,  # ticks that left any waiting
-        # hand-offs between the tick's host thread and the runtime (key
-        # split, puts, the step's call, fetches), mean a tick that ran
+        # hand-offs between the tick's host thread and the runtime (the
+        # key, the put, the step's call, fetches), mean a tick that ran
         "runtime_handoffs_per_tick": _NUM,
     },
     "preemption": {

@@ -19,8 +19,9 @@ Three kinds of region exist in this stack and they need different tools:
   same clock as the device's operations.  ``Phases.child`` names what
   happens inside a phase (the serve tick's hand-offs to the runtime:
   ``engine.build``, ``engine.rng``, ``engine.put``, ``engine.fetch``,
-  tickprof.ENGINE_HANDOFFS) as an annotation alone: a child never
-  changes a boundary.
+  tickprof.ENGINE_HANDOFFS; and the next step's key, split while the chip
+  runs this one's program, tickprof.ENGINE_KEY_AHEAD) as an annotation
+  alone: a child never changes a boundary.
 
 Using the same names on both sides ("fwd_bwd" as a host span around a
 block that is "fwd_bwd" in the device trace) is the point: a perf PR
@@ -46,9 +47,11 @@ from apex_example_tpu.obs import trace as trace_lib
 # models/pangu_moe.py, ops/lane_pack.py,
 # the dropless layer of
 # transformer/expert_parallel.py and serve/engine._slot_step.
-# The serve tick's host phases are tickprof.ENGINE_PHASES and their
-# children tickprof.ENGINE_HANDOFFS (a jax-free table each).  Keep
-# README's "Span naming" paragraph in sync.
+# The serve tick's host phases are tickprof.ENGINE_PHASES, their
+# children tickprof.ENGINE_HANDOFFS (a jax-free table each: the key, the
+# tick's one put, each read of a result) and tickprof.ENGINE_KEY_AHEAD
+# (the next step's key, split under engine.sync while the chip is busy).
+# Keep README's "Span naming" paragraph in sync.
 PHASES = (
     "data",             # host: batch synthesis / prefetcher fetch
     "step",             # host: step dispatch (+ fetch when telemetry is on)

@@ -102,7 +102,7 @@ SERVE_PHASES = ("admit", "dispatch_enqueue", "device_wait", "harvest",
 ENGINE_TICK = "engine.tick"
 ENGINE_PHASES = {
     "engine.admit": "admit",              # mature/expire/shed/evict/admit
-    "engine.marshal": "dispatch_enqueue",  # per-slot arrays, rng, puts
+    "engine.marshal": "dispatch_enqueue",  # the packed array, key, one put
     "engine.enqueue": "dispatch_enqueue",  # the compiled call's return
     "engine.sync": "device_wait",         # device run + device-to-host
     "engine.harvest": "harvest",          # per-slot loop, finishes
@@ -115,13 +115,23 @@ ENGINE_PHASES = {
 # hand-offs a tick are ENGINE_HANDOFF_SPANS counted as they are made
 # (``engine.tick``'s ``handoffs=``): ``engine.enqueue`` is one whole.
 ENGINE_HANDOFFS = {
-    "engine.build": "engine.marshal",  # per-slot numpy arrays: host work
-    "engine.rng": "engine.marshal",    # the key split: two small programs
-    "engine.put": "engine.marshal",    # one host-to-device put (arg=, bytes=)
-    "engine.fetch": "engine.sync",     # one device-to-host fetch (out=, bytes=)
+    "engine.build": "engine.marshal",  # the tick's one packed array: host work
+    "engine.rng": "engine.marshal",    # the step's key: taken as prepared
+    #                                    (ENGINE_KEY_AHEAD), split here only
+    #                                    where nothing is (the first step)
+    "engine.put": "engine.marshal",    # THE host-to-device put (arg="packed",
+    #                                    bytes=): one a tick
+    "engine.fetch": "engine.sync",     # one device-to-host read (out=, bytes=);
+    #                                    all requested at once, the first waits
 }
 ENGINE_HANDOFF_SPANS = ("engine.rng", "engine.put", "engine.enqueue",
                         "engine.fetch")
+# Inside engine.sync, before the first engine.fetch: the split of the key
+# the NEXT step will use, made while the chip runs this tick's program.
+# In neither table above: nothing waits for it, so it is no hand-off of
+# the count, and the chip is busy under it, so no reader of idle time has
+# anything to lay to it.
+ENGINE_KEY_AHEAD = "engine.key_ahead"
 TRAIN_PHASES = ("data_wait", "dispatch", "device", "checkpoint",
                 "telemetry")
 

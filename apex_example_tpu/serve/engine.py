@@ -74,6 +74,7 @@ models/gpt.sample_tokens), so greedy and sampled requests batch together.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import functools
 import time
 import traceback
@@ -82,6 +83,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from apex_example_tpu.models.gpt import sample_tokens
 from apex_example_tpu.obs import costmodel as costmodel_lib
@@ -89,7 +91,8 @@ from apex_example_tpu.obs import trace as trace_lib
 from apex_example_tpu.obs.metrics import Histogram, nearest_rank
 from apex_example_tpu.obs.slo import SloTracker
 from apex_example_tpu.obs.spans import Phases, device_span
-from apex_example_tpu.obs.tickprof import ENGINE_PHASES, ENGINE_TICK
+from apex_example_tpu.obs.tickprof import (ENGINE_KEY_AHEAD, ENGINE_PHASES,
+                                           ENGINE_TICK)
 from apex_example_tpu.ops import lane_pack
 from apex_example_tpu.resilience.faults import FaultInjected
 from apex_example_tpu.serve.queue import (STATUSES, Completion, Request,
@@ -113,11 +116,78 @@ def _pct_dict(vals_ms: List[float]) -> Dict[str, float]:
             "max": round(s[-1], 3) if s else 0.0}
 
 
+@dataclasses.dataclass(frozen=True)
+class TickArgs:
+    """The column layout of the ONE int32 array a tick hands its step
+    program, a row a slot: ``[SLOTS, C + T + 6 (+ 2)]`` for ``chunk`` C
+    lanes and a block table of ``blocks`` T columns —
+
+      ``tok`` [S, C] | ``block_table`` [S, T] | ``fill`` | ``n_new`` |
+      ``cow_src`` | ``cow_dst`` | ``top_k`` | ``temperature`` (float32,
+      carried as its bit pattern) | ``aux`` [S, 2] (``self_draft`` only:
+      :func:`draft_tick`'s draft count and next prompt token)
+
+    A hand-off to the runtime costs the host 0.3–0.5 ms whatever it
+    carries (PERF.md §5), so the tick makes one.  :meth:`fields` is the
+    layout's one definition: on the host's numpy array it gives writable
+    views (what ``ServeEngine._tick`` fills), on the traced array inside
+    the program the same names as slices (what the step computes from),
+    so packer and unpacker cannot drift apart.  Frozen and hashable: a key
+    of the step builders' ``lru_cache`` beside the module clone."""
+
+    chunk: int
+    blocks: int
+    self_draft: bool = False
+
+    SCALARS = ("fill", "n_new", "cow_src", "cow_dst", "top_k",
+               "temperature")
+
+    @property
+    def width(self) -> int:
+        return self.chunk + self.blocks + len(self.SCALARS) \
+            + 2 * self.self_draft
+
+    def fields(self, packed) -> Dict[str, Any]:
+        """``packed`` [SLOTS, width] int32 by field name: views of a numpy
+        array, slices of a jax one.  The temperature is reinterpreted, not
+        converted, on either side: bit for bit what the request said."""
+        C, T = self.chunk, self.blocks
+        out = {"tok": packed[:, :C], "block_table": packed[:, C:C + T]}
+        for j, name in enumerate(self.SCALARS, C + T):
+            out[name] = packed[:, j]
+        bits = out["temperature"]
+        out["temperature"] = bits.view(np.float32) \
+            if isinstance(packed, np.ndarray) \
+            else lax.bitcast_convert_type(bits, jnp.float32)
+        if self.self_draft:
+            out["aux"] = packed[:, self.width - 2:]
+        return out
+
+    def blank(self, num_slots: int):
+        """``(packed, fields)`` of a tick nobody has filled yet: zeros, no
+        COW pair (-1, -1), no draft lane and no next prompt token (0, -1)."""
+        packed = np.zeros((num_slots, self.width), np.int32)
+        f = self.fields(packed)
+        f["cow_src"][:] = f["cow_dst"][:] = -1
+        if self.self_draft:
+            f["aux"][:] = (0, -1)
+        return packed, f
+
+
+# what of a tick's fields the models' paged forward reads
+_PAGED = ("block_table", "fill", "n_new", "cow_src", "cow_dst")
+
+
 @functools.lru_cache(maxsize=8)
-def _slot_step(dec, dequant_weights: bool = False, lanes: bool = False):
+def _slot_step(dec, args: TickArgs, dequant_weights: bool = False,
+               lanes: bool = False):
     """One compiled decode step for a PAGED slot-decode model clone
-    (cached on the frozen module config, block geometry included, with
-    params as an argument: models/gpt._decode_loop's contract).  ``tok``
+    (cached on the frozen module config, block geometry included, and on
+    the layout of its arguments, with params as an argument:
+    models/gpt._decode_loop's contract).  ``step(params, cache, packed,
+    rng)``: everything a tick says about its slots arrives as the one
+    int32 array ``packed`` (:class:`TickArgs`, one host-to-device put a
+    tick), taken apart as the program's first traced operations.  ``tok``
     is [SLOTS, C]: a prefill chunk for slots inside their prompt, one
     token (lane 0) for decoding slots; ``n_new`` says how many lanes are
     real per slot, and sampling reads the logits AFTER each slot's last
@@ -161,17 +231,16 @@ def _slot_step(dec, dequant_weights: bool = False, lanes: bool = False):
     program; re-running a variant reuses its compile."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def step(params, cache, tok, block_table, fill, n_new, cow_src,
-             cow_dst, rng, temperature, top_k):
+    def step(params, cache, packed, rng):
         if dequant_weights:
             from apex_example_tpu.quant import weights as _qw
             with device_span("dequant_weights"):
                 params = _qw.dequantize_tree(params)
-        paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
-                 "cow_src": cow_src, "cow_dst": cow_dst}
+        a = args.fields(packed)
+        tok, n_new = a["tok"], a["n_new"]
         logits, mut = dec.apply(
             {"params": params, "cache": cache}, tok, train=False,
-            paged=paged,
+            paged={k: a[k] for k in _PAGED},
             mutable=["cache"] if lanes else ["cache", "counters"])
         with device_span("sample"):
             if logits.shape[1] == tok.shape[1]:
@@ -181,7 +250,7 @@ def _slot_step(dec, dequant_weights: bool = False, lanes: bool = False):
             else:
                 # the model ran its head on each slot's sampled lane only
                 last = logits[:, 0]
-            nxt = sample_tokens(rng, last, temperature, top_k)
+            nxt = sample_tokens(rng, last, a["temperature"], a["top_k"])
             finite = jnp.all(jnp.isfinite(last), axis=-1)
             out = (mut["cache"], nxt, finite)
             if lanes:
@@ -196,12 +265,13 @@ def _slot_step(dec, dequant_weights: bool = False, lanes: bool = False):
     return step
 
 
-def draft_tick(dec, params, cache, tok, block_table, fill, n_new, cow_src,
-               cow_dst, rng, temperature, top_k, aux):
+def draft_tick(dec, args: TickArgs, params, cache, packed, rng):
     """One serve tick of a model that drafts for itself with its own
     next-token module (``num_nextn_predict_layers``; models/pangu_moe.py):
     verify this tick's draft, deliver one token or two, and make the next
-    draft.  Traced inside :func:`_draft_step`'s one program.
+    draft.  Traced inside :func:`_draft_step`'s one program; ``packed`` is
+    the tick's one argument array, taken apart as :func:`_slot_step` does
+    (``args.fields``, with ``aux`` [SLOTS, 2] as its last two columns).
 
     A greedy decoding slot feeds ``[t_p, d]`` (``aux[:, 0]``, ``n_draft``,
     says how many of a slot's last lanes are drafts: 1 or 0).  The model's
@@ -225,15 +295,16 @@ def draft_tick(dec, params, cache, tok, block_table, fill, n_new, cow_src,
     model's (the module's rows after the layers') and ``drafts_verified``
     / ``drafts_accepted`` ``[1, 1]``, every tick; the two logits are for
     whoever compares them with a reference (the tests)."""
-    n_draft, next_tok = aux[:, 0], aux[:, 1]
-    paged = {"block_table": block_table, "fill": fill, "n_new": n_new,
-             "cow_src": cow_src, "cow_dst": cow_dst, "n_draft": n_draft}
+    a = args.fields(packed)
+    tok, n_new = a["tok"], a["n_new"]
+    n_draft, next_tok = a["aux"][:, 0], a["aux"][:, 1]
+    paged = dict({k: a[k] for k in _PAGED}, n_draft=n_draft)
     (logits, hidden), mut = dec.apply(
         {"params": params, "cache": cache}, tok, train=False, paged=paged,
         mutable=["cache", "counters"])
     C = tok.shape[1]
     with device_span("sample"):
-        n1 = sample_tokens(rng, logits[:, 0], temperature, top_k)
+        n1 = sample_tokens(rng, logits[:, 0], a["temperature"], a["top_k"])
         finite = jnp.all(jnp.isfinite(logits), axis=(1, 2))
     with device_span("draft_verify"):
         lane = jnp.arange(C)[None, :]
@@ -268,19 +339,21 @@ def draft_tick(dec, params, cache, tok, block_table, fill, n_new, cow_src,
 
 
 @functools.lru_cache(maxsize=8)
-def _draft_step(dec, dequant_weights: bool = False):
+def _draft_step(dec, args: TickArgs, dequant_weights: bool = False):
     """:func:`_slot_step` for a self-drafting model: :func:`draft_tick` as
-    ONE compiled program, the cache donated.  Two fetches a tick, as the
-    plain step: the tokens (n1, n2 and the next draft of every slot in one
-    array) and the logits-finite mask; the counters stay on the device."""
+    ONE compiled program ``step(params, cache, packed, rng)``, the cache
+    donated.  The tick reads two of its outputs, as the plain step's, both
+    requested from the device at once: the tokens (n1, n2 and the next
+    draft of every slot in one array) and the logits-finite mask; the
+    counters stay on the device."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def step(params, cache, *rest):
+    def step(params, cache, packed, rng):
         if dequant_weights:
             from apex_example_tpu.quant import weights as _qw
             with device_span("dequant_weights"):
                 params = _qw.dequantize_tree(params)
-        return draft_tick(dec, params, cache, *rest)[:4]
+        return draft_tick(dec, args, params, cache, packed, rng)[:4]
 
     return step
 
@@ -536,9 +609,9 @@ class ServeEngine:
         self.prefill_chunks_deferred = 0
         self.prefill_ticks_deferring = 0
         # Hand-offs between the tick's host thread and the runtime (the
-        # key split, each put, the step's call, each fetch), summed over
-        # the ticks that ran a step.  (Not the KV hand-offs between a
-        # prefill and a decode worker: those are handoffs_in/_out.)
+        # key, the one put, the step's call, each fetch), summed over the
+        # ticks that ran a step.  (Not the KV hand-offs between a prefill
+        # and a decode worker: those are handoffs_in/_out.)
         self.runtime_handoffs = 0
         self.tokens_drafted = 0
         self.tokens_accepted = 0
@@ -593,6 +666,13 @@ class ServeEngine:
         self.params = params
         self.queue = queue if queue is not None else RequestQueue()
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
+        # The split the NEXT step will use, made while the chip runs this
+        # tick's program: (the carried key it was split from, the key to
+        # carry on, the step's key).  Held aside and committed on use, so
+        # ``self.rng`` is at all times the state after exactly
+        # ``compute_steps`` splits; a pair made from another key than the
+        # one carried now (someone set ``rng``) is not used.
+        self._key_ahead = None
         self.sink = sink
         self.run_id = run_id
         self.fault = fault
@@ -610,13 +690,17 @@ class ServeEngine:
         # prefill role instruments under its own name: its program is
         # [SLOTS, block_size]-wide while the decode role's is
         # [SLOTS, 1]-wide — one program per role, each compiling once.
+        self.tick_args = TickArgs(self.chunk, self.pool.max_blocks,
+                                  self.self_draft)
         self._step_fn = costmodel_lib.instrument(
             "serve_spec_step" if self.speculate
             else "serve_prefill_step" if role == "prefill"
             else "serve_decode_step",
-            _draft_step(self.pool.dec, weight_quant != "none")
+            _draft_step(self.pool.dec, self.tick_args,
+                        weight_quant != "none")
             if self.self_draft else
-            _slot_step(self.pool.dec, dequant_weights=weight_quant != "none",
+            _slot_step(self.pool.dec, self.tick_args,
+                       dequant_weights=weight_quant != "none",
                        lanes=bool(self.speculate)))
         self._t0 = time.perf_counter()
         self._tokens_out = 0
@@ -842,20 +926,18 @@ class ServeEngine:
             # step drops the prefill lanes and each decode tick pays
             # 1/block_size of the interleaved program's token FLOPs: the
             # decode-tick stall the disaggregation removes.
-            S, C = pool.num_slots, self.chunk
-            tok = np.zeros((S, C), np.int32)
-            fill = np.zeros((S,), np.int32)
-            n_new = np.zeros((S,), np.int32)
-            cow_src = np.full((S,), -1, np.int32)
-            cow_dst = np.full((S,), -1, np.int32)
-            temps = np.zeros((S,), np.float32)
-            ks = np.zeros((S,), np.int32)
+            C = self.chunk
+            # Every array below is a view of ``packed``, the tick's one
+            # argument (TickArgs): a row a slot, filled in place.
+            packed, f = self.tick_args.blank(pool.num_slots)
+            tok, fill, n_new = f["tok"], f["fill"], f["n_new"]
+            cow_src, cow_dst = f["cow_src"], f["cow_dst"]
+            temps, ks = f["temperature"], f["top_k"]
             drafts: Dict[int, List[int]] = {}
             # self-drafting: per slot, how many of its last lanes are drafts,
             # and the prompt token after a chunk that ends inside its prompt
             # (-1: the module reads the token sampled this tick)
-            aux = np.tile(np.int32([0, -1]), (S, 1)) if self.self_draft \
-                else None
+            aux = f.get("aux")
             # The token budget of chunked prefill, for a model whose rows are
             # packed: a chunk of more than one lane is granted whole or not
             # at all, oldest admission first; a slot granted nothing has
@@ -904,35 +986,23 @@ class ServeEngine:
                 cow_src[i], cow_dst[i] = pool.stage_writes(i, n)
                 temps[i] = slot.request.temperature
                 ks[i] = slot.request.top_k
+            # the table last: stage_writes mapped this tick's blocks
+            f["block_table"][:] = pool.table
             build.set_metadata(lanes=int(n_new.sum()))
-        # Every hand-off to the runtime from here to the tokens' return is
-        # a child span (tickprof.ENGINE_HANDOFFS) and is counted as it is
-        # made: the key split, each put, the step's call, each fetch.
+        # Every hand-off to the runtime the chip waits for, from here to
+        # the tokens' return, is a child span (tickprof.ENGINE_HANDOFFS)
+        # and is counted as it is made: the key, the one put, the step's
+        # call, each fetch.  The key was split while the chip ran the last
+        # step's program (below); the first step splits on the spot.
         with ph.child("engine.rng"):
-            self.rng, key = jax.random.split(self.rng)
-        handoffs = 1
-
-        def put(arg: str, value: np.ndarray) -> jax.Array:
-            nonlocal handoffs
-            handoffs += 1
-            with ph.child("engine.put", arg=arg, bytes=value.nbytes):
-                return jnp.asarray(value)
-
-        def fetch(out: str, value: jax.Array) -> np.ndarray:
-            nonlocal handoffs
-            handoffs += 1
-            with ph.child("engine.fetch", out=out, bytes=value.nbytes):
-                return np.asarray(value)
-
-        args = (self.params, pool.cache, put("tok", tok),
-                put("table", pool.table), put("fill", fill),
-                put("n_new", n_new), put("cow_src", cow_src),
-                put("cow_dst", cow_dst), key, put("temps", temps),
-                put("ks", ks))
-        if aux is not None:
-            args += (put("aux", aux),)
+            ahead, self._key_ahead = self._key_ahead, None
+            if ahead is None or ahead[0] is not self.rng:
+                ahead = self._split_key()
+            _, self.rng, key = ahead
+        with ph.child("engine.put", arg="packed", bytes=packed.nbytes):
+            packed_dev = jnp.asarray(packed)
         ph.enter("engine.enqueue")
-        handoffs += 1
+        args = (self.params, pool.cache, packed_dev, key)
         if self.mesh is not None:
             # Pallas custom calls are opaque to the SPMD partitioner;
             # pin the XLA reference ops for the sharded trace exactly
@@ -948,25 +1018,45 @@ class ServeEngine:
         # run and the device-to-host copy.  (On CPU jax dispatch is
         # synchronous, so the device time hides in engine.enqueue.)
         ph.enter("engine.sync")
-        lane_greedy = lane_finite = next_draft = None
+        next_draft = None
         counted = []
+        # what the tick reads of the step's outputs, in the order read
         if self.self_draft:
-            # [n1, n2, the next draft] a slot in the one fetch of tokens
+            # [n1, n2, the next draft] a slot in the one array of tokens
             pool.cache, picked, finite, *counted = outs
-            picked = fetch("picked", picked)   # the scheduler's host sync
-            lane_greedy, next_draft, nxt = (picked[:, :2], picked[:, 2],
-                                            picked[:, 0])
+            reads = {"picked": picked, "finite": finite}
         elif self.speculate:
-            pool.cache, nxt, finite, lane_greedy, lane_finite = outs
-            lane_greedy = fetch("lane_greedy", lane_greedy)
-            lane_finite = fetch("lane_finite", lane_finite)
-            nxt = fetch("nxt", nxt)
+            pool.cache, nxt, finite, greedy, lanes_ok = outs
+            reads = {"lane_greedy": greedy, "lane_finite": lanes_ok,
+                     "nxt": nxt, "finite": finite}
         else:
             pool.cache, nxt, finite, *counted = outs
-            nxt = fetch("nxt", nxt)            # the scheduler's host sync
-        finite = fetch("finite", finite)
+            reads = {"nxt": nxt, "finite": finite}
+        # Every device-to-host copy is requested now, so that they arrive
+        # together when the program ends and only the first read waits.
+        for value in reads.values():
+            value.copy_to_host_async()
+        # The next step's key, while the chip runs this one's program: the
+        # same split of the same carried key, so the chain of keys is the
+        # one a split a step gives.  Nothing waits for it and the chip is
+        # busy: no hand-off of the count, a span of its own name.
+        with ph.child(ENGINE_KEY_AHEAD):
+            self._key_ahead = self._split_key()
+        got = {}
+        for out, value in reads.items():      # the scheduler's host sync
+            with ph.child("engine.fetch", out=out, bytes=value.nbytes):
+                got[out] = np.asarray(value)
+        handoffs = 3 + len(got)     # the key, the put, the call, the reads
+        finite = got["finite"]
         if self.self_draft:
+            picked = got["picked"]
+            lane_greedy, next_draft, nxt = (picked[:, :2], picked[:, 2],
+                                            picked[:, 0])
             lane_finite = np.repeat(finite[:, None], 2, axis=1)
+        else:
+            nxt = got["nxt"]
+            lane_greedy = got.get("lane_greedy")
+            lane_finite = got.get("lane_finite")
         ph.set_meta(handoffs=handoffs)
         self.runtime_handoffs += handoffs
         now = t_dispatch_end = ph.enter("engine.harvest")
@@ -1147,6 +1237,12 @@ class ServeEngine:
             # the training loops: forensics hold the last good tick).
             fault.maybe_fire(tick1)
         return True
+
+    def _split_key(self):
+        """One split of the carried key, with the key it was made from:
+        ``(self.rng, the key to carry on, the step's key)``.  The caller
+        commits it (``_tick``'s ``engine.rng``) or holds it aside."""
+        return (self.rng, *jax.random.split(self.rng))
 
     # ------------------------------------------------------ speculation
 

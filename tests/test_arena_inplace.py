@@ -98,13 +98,10 @@ def _lowered(speculative: bool, kv_quant: bool, sharding, model=None,
         return jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), t)
 
     lanes = max(BS, SPEC_K + 1) if speculative else BS
-    i32 = jnp.int32
+    layout = engine_lib.TickArgs(lanes, MAX_LEN // BS)
     args = (tree(shapes["params"]), tree(shapes["cache"]),
-            sds((slots, lanes), i32), sds((slots, MAX_LEN // BS), i32),
-            sds((slots,), i32), sds((slots,), i32), sds((slots,), i32),
-            sds((slots,), i32), sds((2,), jnp.uint32),
-            sds((slots,), jnp.float32), sds((slots,), i32))
-    step = engine_lib._slot_step(dec, lanes=speculative)
+            sds((slots, layout.width), jnp.int32), sds((2,), jnp.uint32))
+    step = engine_lib._slot_step(dec, layout, lanes=speculative)
     leaves = jax.tree_util.tree_leaves(shapes["cache"])
     arena_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
     arena_elems = max(l.size for l in leaves)
@@ -286,16 +283,13 @@ def _hybrid_lowered(sharding):
     def tree(t):
         return jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), t)
 
-    i32 = jnp.int32
+    layout = engine_lib.TickArgs(BS, MAX_LEN // BS)
     args = (tree(shapes["params"]), tree(shapes["cache"]),
-            sds((slots, BS), i32), sds((slots, MAX_LEN // BS), i32),
-            sds((slots,), i32), sds((slots,), i32), sds((slots,), i32),
-            sds((slots,), i32), sds((2,), jnp.uint32),
-            sds((slots,), jnp.float32), sds((slots,), i32))
+            sds((slots, layout.width), jnp.int32), sds((2,), jnp.uint32))
     leaves = jax.tree_util.tree_leaves(shapes["cache"])
     assert {l.shape for l in leaves} == {
         (slots, 3 * 4352), (slots, 64, 64, 128), (NB, BS, 512)}
-    return (engine_lib._slot_step(dec).lower(*args),
+    return (engine_lib._slot_step(dec, layout).lower(*args),
             sum(l.size * l.dtype.itemsize for l in leaves),
             {l.size for l in leaves})
 

@@ -12,7 +12,8 @@ programs.
 - one level down (ISSUE 38): every hand-off between the tick's host thread
   and the runtime is a child span, whole inside the phase
   ``ENGINE_HANDOFFS`` names for it, overlapping no other; their number a
-  tick is the tick's ``handoffs=`` and the engine's own count; a child
+  tick is the tick's ``handoffs=`` and the engine's own count (ISSUE 39:
+  5, the key, the one packed put, the call and two fetches); a child
   moves no boundary,
 - the Tracer's X events and the TickProfiler's fold come from the same
   boundary readings (they agree to the float, and the streams validate),
@@ -38,7 +39,8 @@ from apex_example_tpu.obs import schema as obs_schema
 from apex_example_tpu.obs import trace as trace_lib
 from apex_example_tpu.obs.spans import PHASES, Phases
 from apex_example_tpu.obs.tickprof import (ENGINE_HANDOFF_SPANS,
-                                           ENGINE_HANDOFFS, ENGINE_PHASES,
+                                           ENGINE_HANDOFFS,
+                                           ENGINE_KEY_AHEAD, ENGINE_PHASES,
                                            ENGINE_TICK, SERVE_PHASES,
                                            TickProfiler)
 from apex_example_tpu.serve import ServeEngine, synthetic_requests
@@ -179,28 +181,30 @@ def test_the_hand_offs_are_children_of_the_phases(traced, case):
         for _, _, kids in ran:
             for a, b in zip(kids, kids[1:]):
                 assert a[2] <= b[1], (a[0], b[0])
-            # the host's own work first, then the split, then the puts
+            # the host's own work first, then the key, then the one put
             assert [k[0] for k in kids][:3] == [
                 "engine.build", "engine.rng", "engine.put"]
+            assert [k[0] for k in kids][3:] == ["engine.fetch"] * 2
             assert kids[0][3]["lanes"] >= 1
     elif case == "counted":
         # the events, the tick's own word and the engine's count agree:
-        # 1 split + 8 puts + the step's call + 2 fetches on this engine
+        # the key + the one put + the step's call + 2 fetches on this engine
         total = 0
         for tick, phases, kids in ran:
             made = [k for k in kids if k[0] in ENGINE_HANDOFF_SPANS] \
                 + [phases["engine.enqueue"]]
-            assert len(made) == tick[3]["handoffs"] == 12
+            assert len(made) == tick[3]["handoffs"] == 5
             total += len(made)
             assert [k[3]["arg"] for k in kids if k[0] == "engine.put"] \
-                == ["tok", "table", "fill", "n_new", "cow_src", "cow_dst",
-                    "temps", "ks"]
+                == ["packed"]
             assert [k[3]["out"] for k in kids if k[0] == "engine.fetch"] \
                 == ["nxt", "finite"]
+            assert [k[3]["bytes"] for k in kids if k[0] == "engine.put"] \
+                == [4 * SLOTS * eng.tick_args.width]
             assert all(k[3]["bytes"] > 0 for k in kids
-                       if k[0] in ("engine.put", "engine.fetch"))
+                       if k[0] == "engine.fetch")
         assert total == eng.runtime_handoffs
-        assert eng.summary_record()["runtime_handoffs_per_tick"] == 12
+        assert eng.summary_record()["runtime_handoffs_per_tick"] == 5
     elif case == "none_without_a_step":
         idle = [t for t in ticks if len(t[1]) != 6]
         for tick, phases, kids in idle:
@@ -217,7 +221,7 @@ def test_the_hand_offs_are_children_of_the_phases(traced, case):
 def test_speculation_fetches_its_two_lane_arrays_as_well(model_and_params):
     eng = _engine(model_and_params, speculate=2)
     eng.run(max_steps=500)
-    assert eng.runtime_handoffs == 14 * eng.compute_steps > 0
+    assert eng.runtime_handoffs == 7 * eng.compute_steps > 0
 
 
 def test_an_idle_spin_writes_nothing(traced):
@@ -365,11 +369,17 @@ def test_one_table_of_names():
     for name in NAMES + [ENGINE_TICK] + list(ENGINE_HANDOFFS) \
             + list(reader.SCOPES):
         assert f"`{name}`" in readme, name
-    # every engine.* name the engine emits is in one of the two tables
+    # every engine.* name the engine emits is in one of the two tables;
+    # the span of the key split ahead is in neither (nothing waits for it,
+    # the chip is busy under it) and is named once, beside them
+    assert ENGINE_KEY_AHEAD not in set(NAMES) | set(ENGINE_HANDOFFS) \
+        | set(ENGINE_HANDOFF_SPANS)
+    assert f"`{ENGINE_KEY_AHEAD}`" in readme
     engine_src = open(os.path.join(
         REPO, "apex_example_tpu", "serve", "engine.py")).read()
     assert set(re.findall(r'"(engine\.\w+)"', engine_src)) \
         == set(NAMES) | set(ENGINE_HANDOFFS)
+    assert "ph.child(ENGINE_KEY_AHEAD)" in engine_src
 
 
 def _op_names(compiled):
@@ -380,12 +390,10 @@ def test_the_serving_program_carries_its_scopes(model_and_params):
     model, params = model_and_params
     eng = ServeEngine(model, params, num_slots=SLOTS, max_len=MAX_LEN,
                       rng=jax.random.PRNGKey(0))
-    pool, S, C = eng.pool, SLOTS, eng.chunk
-    z = lambda *shape: jnp.zeros(shape, jnp.int32)
-    compiled = _slot_step(pool.dec).lower(
-        params, pool.cache, z(S, C), jnp.asarray(pool.table), z(S), z(S),
-        z(S) - 1, z(S) - 1, jax.random.PRNGKey(0),
-        jnp.zeros((S,), jnp.float32), z(S)).compile()
+    pool = eng.pool
+    compiled = _slot_step(pool.dec, eng.tick_args).lower(
+        params, pool.cache, jnp.asarray(eng.tick_args.blank(SLOTS)[0]),
+        jax.random.PRNGKey(0)).compile()
     names = _op_names(compiled)
     for scope in ("kv_cow", "kv_write", "kv_gather", "paged_attention"):
         assert any(f"/attention/{scope}/" in n for n in names), scope

@@ -827,6 +827,44 @@ def test_router_spool_stale_sweep_reroutes_through_prefill():
     assert summary["handoffs"] == 1 and summary["in_spool"] == 0
 
 
+@pytest.mark.parametrize("order", ["handoff_first", "terminal_first"])
+def test_router_counts_a_handoff_its_terminal_overtook(order):
+    """Two outboxes, polled in turn: a decode worker that finishes a short
+    request within the poll interval reports ``ok`` before the prefill
+    replica's ``handoff`` for the same uid has been read.  The handoff
+    happened either way: the count is 1 in both orders, the prefill
+    replica's booking is released once, and only a REPLAYED handoff line
+    is a duplicate."""
+    pre = FakeReplica("p0")
+    pre.role = "prefill"
+    dec = FakeReplica("d0")
+    dec.role = "decode"
+    router = FleetRouter([pre, dec], log=None)
+    router.submit(_spec("u1"))
+    if order == "handoff_first":
+        pre.report("u1", "handoff")
+        router.poll()
+        dec.report("u1", "ok", tokens=[1])
+        router.poll()
+    else:
+        dec.report("u1", "ok", tokens=[1])
+        router.poll()
+        assert router.done()
+        pre.report("u1", "handoff")
+        router.poll()
+    summary = router.summary_record()
+    assert summary["completed"] == 1 and summary["lost"] == 0
+    assert summary["handoffs"] == 1 and summary["in_spool"] == 0
+    assert summary["duplicates"] == 0
+    assert summary["per_replica"]["p0"].get("handoff") == 1
+    assert router._replicas["p0"].inflight == 0
+    pre.report("u1", "handoff")             # a replayed outbox line
+    router.poll()
+    summary = router.summary_record()
+    assert summary["handoffs"] == 1 and summary["duplicates"] == 1
+    assert router._replicas["p0"].inflight == 0
+
+
 def test_thread_replica_rejects_inert_handoff_drills(model_and_params):
     """A drill the transport/drive loop can never express must be a
     construction error, not a silently-clean chaos run."""

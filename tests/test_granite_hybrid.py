@@ -87,17 +87,19 @@ def _record_logits(eng, between=None):
     dec = eng.pool.dec
 
     @jax.jit
-    def step(params, cache, tok, table, fill, n_new, cow_src, cow_dst):
+    def step(params, cache, packed):
+        said = eng.tick_args.fields(packed)
         logits, mut = dec.apply(
-            {"params": params, "cache": cache}, tok, train=False,
-            paged={"block_table": table, "fill": fill, "n_new": n_new,
-                   "cow_src": cow_src, "cow_dst": cow_dst},
+            {"params": params, "cache": cache}, said["tok"], train=False,
+            paged={k: said[k] for k in ("block_table", "fill", "n_new",
+                                        "cow_src", "cow_dst")},
             mutable=["cache", "counters"])
         return mut["cache"], logits[:, 0], mut["counters"]
 
     def recording(*a):
-        cache, last, counters = step(*a[:8])
-        fill, n_new = np.asarray(a[4]), np.asarray(a[5])
+        cache, last, counters = step(*a[:3])
+        said = eng.tick_args.fields(np.asarray(a[2]))
+        fill, n_new = said["fill"], said["n_new"]
         for i, slot in enumerate(eng.pool.slots):
             if slot is not None and n_new[i]:
                 seen.setdefault(slot.request.uid, {})[
@@ -527,9 +529,10 @@ def budgeted(model, params):
                         and s.n_prompt - s.cursor > 1),
                        key=lambda i: slots[i].t_admitted)
         out = step(*a)
+        said = eng.tick_args.fields(np.asarray(a[2]))
         ticks.append(dict(
-            tok=np.asarray(a[2]), table=np.asarray(a[3]).copy(),
-            fill=np.asarray(a[4]), n_new=np.asarray(a[5]), asking=order,
+            tok=said["tok"], table=said["block_table"],
+            fill=said["fill"], n_new=said["n_new"], asking=order,
             uid={i: s.request.uid for i, s in enumerate(slots)
                  if s is not None},
             before=before, after=jax.tree_util.tree_map(np.asarray, out[0])))

@@ -478,22 +478,25 @@ def test_cow_then_write_in_one_tick_leaves_the_source_block_bit_identical(
     place, the write must land in the copy and nowhere else: ``src``
     stays bit-identical, ``dst`` is ``src`` except the written row,
     every other block keeps its bytes."""
-    from apex_example_tpu.serve.engine import _slot_step
+    from apex_example_tpu.serve.engine import TickArgs, _slot_step
     model, params = model_and_params
     pool = BlockPool(model, num_slots=SLOTS, max_len=MAX_LEN,
                      block_size=BS, kv_quant=kv_quant)
-    step = _slot_step(pool.dec)
+    layout = TickArgs(BS, pool.max_blocks)
+    step = _slot_step(pool.dec, layout)
     src, dst, row = 3, 5, 4
     rng = jax.random.PRNGKey(0)
-    z = lambda *s: jnp.zeros(s, jnp.int32)
-    none = jnp.full((SLOTS,), -1, jnp.int32)
+    none = np.full((SLOTS,), -1, np.int32)
     table = np.zeros((SLOTS, pool.max_blocks), np.int32)
 
     def tick(tok, table, fill, n_new, cow_src, cow_dst):
-        pool.cache, nxt, finite = step(
-            params, pool.cache, jnp.asarray(tok), jnp.asarray(table),
-            jnp.asarray(fill), jnp.asarray(n_new), cow_src, cow_dst, rng,
-            jnp.zeros((SLOTS,), jnp.float32), z(SLOTS))
+        packed, f = layout.blank(SLOTS)
+        for name, value in dict(tok=tok, block_table=table, fill=fill,
+                                n_new=n_new, cow_src=cow_src,
+                                cow_dst=cow_dst).items():
+            f[name][...] = value
+        pool.cache, nxt, finite = step(params, pool.cache,
+                                       jnp.asarray(packed), rng)
         assert bool(np.asarray(finite)[0])
         return {k: np.asarray(v) for k, v in _arena_leaves(pool).items()}
 
@@ -510,7 +513,7 @@ def test_cow_then_write_in_one_tick_leaves_the_source_block_bit_identical(
     tok[1, 0] = 99
     table[1, 0] = dst
     after = tick(tok, table, [0, row, 0, 0], [0, 1, 0, 0],
-                 none.at[1].set(src), none.at[1].set(dst))
+                 [-1, src, -1, -1], [-1, dst, -1, -1])
     assert sorted(after) == sorted(before)
     for name, a in after.items():
         b = before[name]

@@ -101,11 +101,14 @@ def _record_logits(eng):
     head's logits row at every verify lane, ``drafted[uid][position]`` the
     module's at the lane the next draft was read from."""
     seen, drafted = {}, {}
-    tick = jax.jit(lambda *a: engine_lib.draft_tick(eng.pool.dec, *a))
+    tick = jax.jit(lambda *a: engine_lib.draft_tick(
+        eng.pool.dec, eng.tick_args, *a))
 
     def recording(*a):
         cache, picked, finite, counters, logits, draft_logits = tick(*a)
-        fill, n_new, aux = (np.asarray(a[i]) for i in (4, 5, 11))
+        said = eng.tick_args.fields(np.asarray(a[2]))
+        fill, n_new, aux, tok = (said[k] for k in ("fill", "n_new", "aux",
+                                                   "tok"))
         picked_h = np.asarray(picked)
         for i, slot in enumerate(eng.pool.slots):
             if slot is None or not n_new[i]:
@@ -113,7 +116,7 @@ def _record_logits(eng):
             at = int(fill[i] + n_new[i] - 1 - aux[i, 0])
             rows = seen.setdefault(slot.request.uid, {})
             rows[at] = np.asarray(logits[i, 0])
-            took = bool(aux[i, 0]) and picked_h[i, 0] == int(a[2][i, 1])
+            took = bool(aux[i, 0]) and picked_h[i, 0] == int(tok[i, 1])
             if took:
                 rows[at + 1] = np.asarray(logits[i, 1])
             drafted.setdefault(slot.request.uid, {})[at + took] = \
@@ -267,10 +270,12 @@ def test_served_tokens_are_the_same_with_drafting_on_and_off(
     assert summary["output_tokens"] \
         == summary["tokens_accepted"] + summary["tokens_sampled"]
     assert "speculate_k" not in off.summary_record()
-    # hand-offs to the runtime a tick: the split, 8 puts and `aux`, the
-    # step's call, 2 fetches (the picked tokens in one); 12 without `aux`
-    assert summary["runtime_handoffs_per_tick"] == 13
-    assert off.runtime_handoffs == 12 * off.compute_steps
+    # hand-offs to the runtime a tick: the key, the one packed put (`aux`
+    # its last two columns), the step's call, 2 fetches (the picked tokens
+    # in one); the same 5 with drafting off, two columns narrower
+    assert summary["runtime_handoffs_per_tick"] == 5
+    assert off.runtime_handoffs == 5 * off.compute_steps
+    assert on.tick_args.width == off.tick_args.width + 2
     # the counters, in every tick's tree, 0 included
     log = [jax.tree_util.tree_map(np.asarray, t) for _, t in on.counter_log]
     assert sum(int(t["drafts_verified"].sum()) for t in log) \
@@ -606,14 +611,15 @@ def _other(name):
 
 
 # sha256 of the tick's lowered text (``jit(...).lower(...).as_text()``, 4
-# slots x 64, blocks of 8, under the tests' interpreter) as the commit before
-# PR 36 lowered it.  A PR that changes one of these models' tick on purpose
-# replaces its line; this PR, which only adds a model and an engine path
-# beside them, must not.
-TICK_BEFORE_PR36 = {
-    "gpt1": "9b495b9d4488a04f49efd24bc936b17246e8677c17fa1a1b0387473e8593fe9f",
-    "xing4": "16e03838199ef635238e57f888d3a3f4fbacc38e8dadb0aca60b717051629dcc",
-    "granite": "c74837b92bfc964ffc6011807459641924e2dc863cf670e4ffc349c86cd1124d",
+# slots x 64, blocks of 8, under the tests' interpreter).  A PR that changes
+# one of these models' tick on purpose replaces its line: PR 39 did, for all
+# three (the step takes one packed array and a key, ``engine.TickArgs``, where
+# it took nine arrays; what it computes from them is what PR 36 pinned).  A
+# PR that only adds a model or an engine path beside them must not.
+TICK_SINCE_PR39 = {
+    "gpt1": "3d3e68c6aefad0b5cf85da83646e6365d42ed4f589d5cbf493e9fd48110a0566",
+    "xing4": "d26034df746a79fc3359ee9beda6887b49a3f4c3282c6faa33712ad8e5b76b5f",
+    "granite": "14236cb44a18a4d62899c5ad0400c5741454dafcbe21663f47f62e78b98d5736",
 }
 
 
@@ -627,13 +633,12 @@ def test_the_other_models_engines_and_tick_programs_are_untouched(name):
     eng = _engine(model, params)
     assert not eng.self_draft and eng.speculate == 0 and eng.proposer is None
     assert not eng.pool.rows_read_next_token and eng.pool.spec_slack == 0
-    step = engine_lib._slot_step(eng.pool.dec)
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    assert eng.tick_args == engine_lib.TickArgs(BS, MAX_LEN // BS)
+    step = engine_lib._slot_step(eng.pool.dec, eng.tick_args)
     text = step.lower(
-        params, eng.pool.cache, i32(SLOTS, BS), i32(SLOTS, MAX_LEN // BS),
-        i32(SLOTS), i32(SLOTS), i32(SLOTS), i32(SLOTS),
-        jax.random.PRNGKey(0), jnp.zeros((SLOTS,), jnp.float32),
-        i32(SLOTS)).as_text()
+        params, eng.pool.cache,
+        jnp.zeros((SLOTS, eng.tick_args.width), jnp.int32),
+        jax.random.PRNGKey(0)).as_text()
     assert "draft_verify" not in text and "mtp" not in text
     assert hashlib.sha256(text.encode()).hexdigest() \
-        == TICK_BEFORE_PR36[name]
+        == TICK_SINCE_PR39[name]

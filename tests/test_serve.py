@@ -1124,11 +1124,11 @@ def test_a_model_without_packed_lanes_keeps_its_marshal_and_its_program(
         eng.step()
         dec = eng.pool.dec
         S, C = SLOTS, eng.chunk
-        z = lambda *s: jnp.zeros(s, jnp.int32)
-        text = str(jax.make_jaxpr(_slot_step(dec).__wrapped__)(
-            params, eng.pool.cache, z(S, C), jnp.asarray(eng.pool.table),
-            z(S), z(S), z(S) - 1, z(S) - 1, jax.random.PRNGKey(0),
-            jnp.zeros((S,), jnp.float32), z(S)))
+        text = str(jax.make_jaxpr(
+            _slot_step(dec, eng.tick_args).__wrapped__)(
+                params, eng.pool.cache,
+                jnp.asarray(eng.tick_args.blank(S)[0]),
+                jax.random.PRNGKey(0)))
         assert "lane_pack" not in text
         assert f"i32[{S},{C}]" in text          # tok, lane for lane
         return
@@ -1146,9 +1146,10 @@ def test_a_model_without_packed_lanes_keeps_its_marshal_and_its_program(
             n = min(C, s.n_prompt - s.cursor) if s.prefilling else 1
             want_n[i], want_fill[i] = n, s.cursor
             want_tok[i, :n] = s.tokens[s.cursor:s.cursor + n]
-        np.testing.assert_array_equal(np.asarray(a[5]), want_n)
-        np.testing.assert_array_equal(np.asarray(a[4]), want_fill)
-        np.testing.assert_array_equal(np.asarray(a[2]), want_tok)
+        got = eng.tick_args.fields(np.asarray(a[2]))
+        np.testing.assert_array_equal(got["n_new"], want_n)
+        np.testing.assert_array_equal(got["fill"], want_fill)
+        np.testing.assert_array_equal(got["tok"], want_tok)
         seen.append(int((want_n > 1).sum()))
         return step(*a)
     eng._step_fn = keeping
