@@ -44,7 +44,8 @@ from apex_example_tpu.obs import trace as trace_lib
 # engine.make_train_step, the loss functions of workloads.py, the models'
 # heads, ops/paged_cache.py (kv_cow, kv_write, kv_gather), the paged branch
 # of models/bert.py, models/xing4.py, models/granite_hybrid.py,
-# models/pangu_moe.py, ops/lane_pack.py,
+# models/pangu_moe.py, models/trinity.py, ops/lane_pack.py,
+# ops/attention.py (paged_gqa_attention),
 # the dropless layer of
 # transformer/expert_parallel.py and serve/engine._slot_step.
 # The serve tick's host phases are tickprof.ENGINE_PHASES, their
@@ -78,6 +79,8 @@ PHASES = (
     "ssm_scan",         # device: inside it, conv + chunked recurrence + state
     "shared_mlp",       # device: the dense SwiGLU MLP of every hybrid layer
     "gqa_attention",    # device: grouped-query attention over the paged K/V
+    "paged_gqa_attention",  # device: ops/attention.py, the paged GQA kernel
+                        # (or its XLA form): scores, mask, softmax, sum
     "lane_pack",        # device: ops/lane_pack.py, packed rows <-> [slots, lanes]
     "sandwich_norm",    # device: models/pangu_moe.py, the four norms a layer
     "mtp",              # device: its next-token module whole (layer + head)

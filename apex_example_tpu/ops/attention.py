@@ -815,3 +815,368 @@ def paged_latent_attention(qf, arena, block_table, fill, n_new, *, scale, kr):
                                         scale, kr, _cfg.INTERPRET)
     return paged_latent_attention_reference(qf, arena, block_table, fill,
                                             n_new, scale, kr)
+
+
+# --------------------------------------------------------------------------
+# Paged grouped-query attention (the serve tick of models/trinity.py): K and
+# V in two [NB, BS, Hk * hd] arenas, full or window, the window's through a
+# ring.  ``paged_gqa_attention`` is the op; the XLA form below it is the CPU
+# fallback, FORCE_XLA's path and the kernel tests' golden.
+# --------------------------------------------------------------------------
+
+def _gqa_rows(q, kv_heads: int):
+    """``[S, C, Hq, hd]`` queries as ``[S, Hk, C * g, hd]``: a key/value
+    head's ``g`` query heads of every lane, lane-major (row // g is the
+    lane), so that a slot's live rows come first."""
+    S, C, Hq, hd = q.shape
+    g = Hq // kv_heads
+    return q.reshape(S, C, kv_heads, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        S, kv_heads, C * g, hd)
+
+
+def _gqa_lanes(o, lanes: int):
+    """:func:`_gqa_rows` undone: ``[S, Hk, C * g, hd]`` as ``[S, C, Hq,
+    hd]``."""
+    S, Hk, rows, hd = o.shape
+    g = rows // lanes
+    return o.reshape(S, Hk, lanes, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        S, lanes, Hk * g, hd)
+
+
+def paged_gqa_attention_reference(q, k_arena, v_arena, table, fill, n_new,
+                                  scale, window=None, ring=None):
+    """The XLA form: every slot's whole row of the table gathered into
+    ``[S, L, Hk, hd]`` views, scores against all ``L`` rows, masked by each
+    lane's own position (and window), softmaxed and multiplied back.  A
+    ring's column ``c`` is taken for the newest logical block ``j <=`` the
+    slot's last with ``j mod ring == c``: an older one the window hides, a
+    column not yet written reads as before position 0 and is masked.  Same
+    contract as :func:`paged_gqa_attention`; ``walked`` is ``L`` for every
+    slot."""
+    S, C, Hq, hd = q.shape
+    BS, Hk = k_arena.shape[1], k_arena.shape[2] // hd
+    keys, vals = paged_cache.gather((k_arena, v_arena), table, heads=Hk)
+    with jax.named_scope("paged_gqa_attention"):
+        L = keys.shape[1]
+        lane = jnp.arange(C)[None, :]
+        pos = fill[:, None] + lane                               # [S, C]
+        col, at = jnp.arange(L)[None, :] // BS, jnp.arange(L)[None, :] % BS
+        if ring:
+            last = (jnp.maximum(fill + n_new, 1)[:, None] - 1) // BS
+            col = last - (last - col) % table.shape[1]
+        kpos = col * BS + at                                     # [S, L]
+        seen = (kpos[:, None, :] <= pos[:, :, None]) & (kpos[:, None, :] >= 0)
+        if window is not None:
+            seen &= kpos[:, None, :] > pos[:, :, None] - window
+        qg = q.reshape(S, C, Hk, Hq // Hk, hd)
+        scores = jnp.einsum("sckgd,slkd->skgcl", qg, keys,
+                            preferred_element_type=jnp.float32) * scale
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None, None], scores, -1e30), -1)
+        o = jnp.einsum("skgcl,slkd->sckgd", probs.astype(q.dtype), vals,
+                       preferred_element_type=jnp.float32)
+        o = jnp.where((lane < n_new[:, None])[..., None, None, None], o, 0.0)
+        return (o.reshape(S, C, Hq, hd).astype(q.dtype),
+                jnp.full((S,), L, jnp.int32))
+
+
+def _paged_gqa_kernel(table_ref, fill_ref, n_new_ref, q_ref, k_ref, v_ref,
+                      o_ref, walked_ref, kbuf, vbuf, sem, first_buf, acc, m,
+                      l, *, scale, group, hd, bs, pages, columns, window,
+                      ring, row_tile, small):
+    """One grid step = one slot, all its key/value heads.  A head's ``rows =
+    C * group`` query rows lie lane-major, so the live ones come first and
+    row tiles past ``n_new * group`` are never computed.  The walk goes over
+    the slot's logical blocks from the first any live lane may see (block 0,
+    or in a window layer the block of ``fill - window + 1``) to the last it
+    wrote, ``pages`` a compute tile: each page one DMA of K and one of V
+    from where they lie in their arenas (through the ring's column ``block
+    mod ring`` where the table is one), the next tile in flight while this
+    one is scored, online softmax state in float32 scratch a head."""
+    s, slots = pl.program_id(0), pl.num_programs(0)
+    heads, rows = q_ref.shape[1], q_ref.shape[2]
+    nb = k_ref.shape[0]
+    tile = pages * bs
+
+    def span(slot):
+        """A slot's first logical block, its blocks walked and the compute
+        tiles they make (none for a slot that feeds nothing)."""
+        fill, n_new = fill_ref[slot], n_new_ref[slot]
+        first = 0 if window is None \
+            else jnp.maximum(fill - window + 1, 0) // bs
+        blocks = jnp.where(
+            n_new > 0,
+            jnp.minimum((fill + n_new + bs - 1) // bs - first, columns), 0)
+        return first, blocks, (blocks + pages - 1) // pages
+
+    fill, n_new = fill_ref[s], n_new_ref[s]
+    total = fill + n_new
+    live_rows = n_new * group
+    first_block, n_blocks, n_tiles = span(s)
+    walked_ref[s] = n_blocks * bs
+
+    def start_pages(slot, t, buf):
+        """Start both DMAs of every live page of ``slot``'s tile ``t``."""
+        first, blocks, _ = span(slot)
+        at = t * pages
+
+        def one(p, carry):
+            block = first + at + p
+            column = lax.rem(block, columns) if ring else block
+            page = jnp.clip(table_ref[slot * columns + column], 0, nb - 1)
+            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            pltpu.make_async_copy(k_ref.at[page], kbuf.at[buf, dst],
+                                  sem.at[buf, 0]).start()
+            pltpu.make_async_copy(v_ref.at[page], vbuf.at[buf, dst],
+                                  sem.at[buf, 1]).start()
+            return carry
+
+        lax.fori_loop(0, jnp.clip(blocks - at, 0, pages), one, 0)
+
+    def wait_pages(t, buf):
+        """Wait for this slot's tile ``t`` to have landed in buffer
+        ``buf``.  A wait needs the semaphore and the bytes, not the page:
+        a full tile is one wait an arena over the whole buffer, a slot's
+        last, partial tile one a page."""
+        n = jnp.clip(n_blocks - t * pages, 0, pages)
+
+        def landed(ref, sem_ref):
+            pltpu.make_async_copy(ref, ref, sem_ref).wait()
+
+        @pl.when(n == pages)
+        def _():
+            landed(kbuf.at[buf], sem.at[buf, 0])
+            landed(vbuf.at[buf], sem.at[buf, 1])
+
+        @pl.when(n < pages)
+        def _():
+            def one(p, carry):
+                dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+                landed(kbuf.at[buf, dst], sem.at[buf, 0])
+                landed(vbuf.at[buf, dst], sem.at[buf, 1])
+                return carry
+            lax.fori_loop(0, n, one, 0)
+
+    def row_tiles(lo, hi, body):
+        def one(i, carry):
+            body(pl.ds(pl.multiple_of(i * row_tile, row_tile), row_tile),
+                 i * row_tile)
+            return carry
+        lax.fori_loop(lo, hi, one, 0)
+
+    live_tiles = (live_rows + row_tile - 1) // row_tile
+
+    def init(rs, r):
+        for h in range(heads):
+            m[h, rs] = jnp.full((row_tile, 1), _MASK, jnp.float32)
+            l[h, rs] = jnp.zeros((row_tile, 1), jnp.float32)
+            acc[h, rs] = jnp.zeros((row_tile, hd), jnp.float32)
+
+    row_tiles(0, live_tiles, init)
+
+    # tile t of this slot lies in buffer (first + t) % 2.  The slot before,
+    # if it walked anything, left ``first`` behind and our tile 0 in flight
+    before, after = jnp.maximum(s - 1, 0), jnp.minimum(s + 1, slots - 1)
+    in_flight = jnp.logical_and(s > 0, span(before)[2] > 0)
+    hand_on = jnp.logical_and(s + 1 < slots, span(after)[2] > 0)
+    first = jnp.where(s > 0, first_buf[0], 0)
+
+    @pl.when(jnp.logical_and(n_tiles > 0, jnp.logical_not(in_flight)))
+    def _():
+        start_pages(s, 0, first)
+
+    def walk(t, carry):
+        buf = lax.rem(first + t, 2)
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            start_pages(s, t + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(t + 1 == n_tiles, hand_on))
+        def _():
+            start_pages(after, 0, 1 - buf)
+
+        wait_pages(t, buf)
+        t0 = (first_block + t * pages) * bs      # the tile's first position
+
+        @pl.when(t0 + tile > total)
+        def _():
+            # the slot's last tile: rows past its last token (the tail of a
+            # page, pages not fetched) hold whatever was there; as values
+            # they would meet a zero probability, and 0 * NaN is NaN (as
+            # keys their scores are replaced by the mask below)
+            at = lax.broadcasted_iota(jnp.int32, vbuf.shape[1:], 0)
+            vbuf[buf] = jnp.where(at < total - t0, vbuf[buf], 0)
+
+        def score(rs, r):
+            # the heads' first products, then their softmax steps, then
+            # their second products: independent products side by side keep
+            # the MXUs fed (0.77 -> 0.60 ms a full-layer call on the v5e)
+            ks = [kbuf[buf, :, pl.ds(h * hd, hd)] for h in range(heads)]
+            scs = [_dot_f32(q_ref[0, h, rs, :], ks[h], trans_b=True) * scale
+                   for h in range(heads)]
+            row = r + lax.broadcasted_iota(jnp.int32, scs[0].shape, 0)
+            col = t0 + lax.broadcasted_iota(jnp.int32, scs[0].shape, 1)
+            seen = (col - fill) * group <= row
+            if window is not None:
+                seen = jnp.logical_and(
+                    seen, (col - fill + window) * group > row)
+            ps, alphas = [], []
+            for h in range(heads):
+                sc = jnp.where(seen, scs[h], _MASK)
+                m_new = jnp.maximum(m[h, rs], jnp.max(sc, -1, keepdims=True))
+                alpha = jnp.exp(m[h, rs] - m_new)
+                p = jnp.exp(sc - m_new)
+                l[h, rs] = l[h, rs] * alpha + jnp.sum(p, -1, keepdims=True)
+                m[h, rs] = m_new
+                ps.append(p.astype(ks[h].dtype))
+                alphas.append(alpha)
+            for h in range(heads):
+                acc[h, rs] = acc[h, rs] * alphas[h] + _dot_f32(
+                    ps[h], vbuf[buf, :, pl.ds(h * hd, hd)])
+
+        # a decoding slot's ``group`` live rows a head in one small tile
+        # (a product's cost on the MXU is its key tile's load plus the rows
+        # streamed past it), a prefilling slot's in row tiles
+        if small is None:
+            row_tiles(0, live_tiles, score)
+        else:
+            @pl.when(live_rows <= small)
+            def _():
+                score(pl.ds(0, small), 0)
+
+            @pl.when(live_rows > small)
+            def _():
+                row_tiles(0, live_tiles, score)
+        return carry
+
+    lax.fori_loop(0, n_tiles, walk, 0)
+    first_buf[0] = lax.rem(first + n_tiles, 2)
+
+    def write(rs, r):
+        row = r + lax.broadcasted_iota(jnp.int32, (row_tile, hd), 0)
+        for h in range(heads):
+            o_ref[0, h, rs, :] = jnp.where(
+                row < live_rows, acc[h, rs] / l[h, rs], 0.0).astype(
+                    o_ref.dtype)
+
+    def blank(rs, r):
+        for h in range(heads):
+            o_ref[0, h, rs, :] = jnp.zeros((row_tile, hd), o_ref.dtype)
+
+    row_tiles(0, live_tiles, write)
+    row_tiles(live_tiles, rows // row_tile, blank)
+
+
+# The paged GQA kernel's tiles: cache positions a compute tile (pages of
+# both arenas, 16 KB each at 16 x 512 bfloat16) and query rows a row tile (a
+# prefilling slot's; a decoding slot's ``group`` live rows a head go through
+# one sublane tile).  On the v5e at 32 over 4 heads of 128, 16 lanes, pages
+# of 16, under a load like the serving cell's (48 of 64 slots live, 108k
+# positions walked in a full layer's call, 71k in a window layer's), full /
+# window, ms a call (PERF.md section 6, PR 40): 512 positions a tile, 32
+# rows, head by head, a wait a page 0.94 / 0.73; 1,024 positions 0.76 /
+# 0.61 (256: 1.40 / 0.97; 2,048 no better); the heads' products side by
+# side 0.60 / 0.50; one wait an arena for a full tile and 64 rows 0.55 /
+# 0.47; a decoding slot's rows in one tile of 16 and a prefilling slot's
+# in one of 128 0.52 / 0.48.  The pages' DMAs alone take 0.43 / 0.43 (514
+# GB/s: 16 KB a descriptor), the products alone 0.46-0.56.
+_GQA_TILE = 1024
+_GQA_ROW_TILE = 128
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "window", "ring", "interpret", "pages", "row_tile"))
+def _paged_gqa_pallas(q, k_arena, v_arena, table, fill, n_new, scale, window,
+                      ring, interpret, pages=None, row_tile=None):
+    _bind_pallas()
+    S, C, Hq, hd = q.shape
+    NB, BS, W = k_arena.shape
+    Hk = W // hd
+    columns = table.shape[1]
+    rows = C * (Hq // Hk)
+    if pages is None:
+        pages = max(1, min(columns, _GQA_TILE // BS))
+    if row_tile is None:
+        row_tile = _GQA_ROW_TILE if rows % _GQA_ROW_TILE == 0 else rows
+    # the least rows a product takes: one sublane tile of the queries' dtype
+    small = 8 * 4 // q.dtype.itemsize
+    small = small if small < row_tile and row_tile % small == 0 else None
+    i32 = lambda x: x.astype(jnp.int32)
+    block = lambda: pl.BlockSpec((1, Hk, rows, hd), lambda s, *_: (s, 0, 0, 0))
+    o, walked = pl.pallas_call(
+        functools.partial(_paged_gqa_kernel, scale=scale, group=Hq // Hk,
+                          hd=hd, bs=BS, pages=pages, columns=columns,
+                          window=window, ring=ring is not None,
+                          row_tile=row_tile, small=small),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[block(), pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[block(), pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.VMEM((2, pages * BS, W), k_arena.dtype),
+                            pltpu.VMEM((2, pages * BS, W), v_arena.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((Hk, rows, hd), jnp.float32),
+                            pltpu.VMEM((Hk, rows, 1), jnp.float32),
+                            pltpu.VMEM((Hk, rows, 1), jnp.float32)]),
+        out_shape=[sds((S, Hk, rows, hd), q.dtype, q, k_arena),
+                   sds((S,), jnp.int32, q, k_arena)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_gqa_attention",
+        interpret=interpret,
+    )(i32(table).reshape(-1), i32(fill), i32(n_new), _gqa_rows(q, Hk),
+      k_arena, v_arena)
+    return _gqa_lanes(o, C), walked
+
+
+def _paged_gqa_ok(q, k_arena) -> bool:
+    if not _cfg.use_pallas():
+        return False
+    if _cfg.INTERPRET:
+        return True
+    # Mosaic: a head a whole 128-lane tile, whole sublane tiles a page
+    sublanes = 8 * 4 // k_arena.dtype.itemsize
+    _, C, Hq, hd = q.shape
+    rows = C * Hq // (k_arena.shape[2] // hd)
+    return (hd % 128 == 0 and k_arena.shape[1] % sublanes == 0
+            and rows % sublanes == 0)
+
+
+def paged_gqa_attention(q, k_arena, v_arena, table, fill, n_new, *, scale,
+                        window=None, ring=None):
+    """Grouped-query attention of one serve tick against paged K and V.
+
+    q: (S, C, Hq, hd) queries (normed and rotated by the caller);
+    k_arena, v_arena: (NB, BS, Hk * hd), a row a cached token, heads the
+    outer factor of the merged dimension; query head ``i`` reads key/value
+    head ``i // (Hq // Hk)``.  table: (S, columns) block ids: a slot's row
+    of the block table, or with ``ring`` (the ring's blocks a slot, ``==
+    columns``) of a window leaf's ring table, where logical block ``j``
+    stands in column ``j mod ring``; entries that hold nothing may be
+    anything.  fill, n_new: (S,) tokens cached before this tick and lanes
+    that are real (this tick's rows are written already).  Lane ``j`` of
+    slot ``s``, at position ``p = fill[s] + j``, attends the positions ``p'
+    <= p`` and, with ``window``, ``p' > p - window``.  Returns ``(o,
+    walked)``: o (S, C, Hq, hd) in q's dtype, zeros for lanes ``>= n_new``,
+    and walked (S,) int32, the cache positions read for each slot.
+
+    Scores, softmax and accumulation are float32; probabilities are cast to
+    the arena's dtype for the second product.  On TPU (and under the
+    interpreter) a Pallas kernel walks, for each slot, the blocks from the
+    first any live lane may see to the last it wrote, where they lie, and
+    nothing for ``n_new == 0``; elsewhere the XLA form gathers and scores
+    every row of the table."""
+    if ring and ring != table.shape[1]:
+        raise ValueError(f"a ring of {ring} blocks wants a table {ring} "
+                         f"columns wide, got {table.shape[1]}")
+    if _paged_gqa_ok(q, k_arena):
+        with jax.named_scope("paged_gqa_attention"):
+            return _paged_gqa_pallas(q, k_arena, v_arena, table, fill,
+                                     n_new, scale, window, ring,
+                                     _cfg.INTERPRET)
+    return paged_gqa_attention_reference(q, k_arena, v_arena, table, fill,
+                                         n_new, scale, window, ring)

@@ -35,9 +35,28 @@ block leaf's (``num_slots == num_blocks``).  A cache tree that holds one
 cannot share prefixes: the state at a prefix boundary is held nowhere
 (serve/slots.BlockPool reads :func:`slot_leaves` for that).
 
+**The third kind: window leaves.**  A layer that attends a sliding window
+of ``W`` positions needs a slot's last ``W`` tokens and never an older one,
+so its payload leaves live in a second, smaller arena of their own:
+``[num_slots * ring_blocks, block_size, W']`` with ``ring_blocks =
+ceil((W + chunk) / block_size) + 1`` (:func:`ring_blocks`: the window, the
+tick's write span and one block to turn over), addressed through a second
+per-slot table ``[num_slots, ring_blocks]`` in which logical block ``j``
+stands in column ``j mod ring_blocks``.  The host hands a block back while
+the request still runs, once the slot's fill has passed it by a window
+(serve/slots.BlockPool).  Declared where it is created
+(:func:`window_variable`; the key carries the window), as a per-slot leaf
+is: its shape may coincide with a block leaf's.  It is written through the
+ring (:func:`write_rows` with ``ring=True``), read where it lies by the
+paged kernel (ops/attention.paged_gqa_attention) and never gathered whole;
+it has no copy-on-write (nothing of it is shared) and no scale table.
+
+The kinds, then: PAYLOAD and SCALE (block leaves, by shape), PER_SLOT and
+WINDOW (declared).
+
 **The pool's side**: :func:`block_leaves`, :func:`slot_leaves`,
-:func:`shard`, :func:`extract`, :func:`insert`.  That discovery of block
-leaves goes by shape is private to this module.
+:func:`window_leaves`, :func:`shard`, :func:`extract`, :func:`insert`.
+That discovery of block leaves goes by shape is private to this module.
 """
 
 from __future__ import annotations
@@ -49,9 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-PAYLOAD, SCALE, PER_SLOT = "payload", "scale", "per_slot"
+PAYLOAD, SCALE, PER_SLOT, WINDOW = "payload", "scale", "per_slot", "window"
 # a per-slot leaf's key in its module's ``cache`` dict: the declaration
 _SLOT_KEY = "slot:"
+# a window leaf's: ``window:<W>:<name>``
+_WINDOW_KEY = "window:"
 
 
 def lane_tiles(width: int) -> int:
@@ -72,11 +93,39 @@ def variable(module, name: str, num_blocks: int, block_size: int, dtype,
             "slot_decode is block-paged: clone the model with "
             "kv_num_blocks/kv_block_size >= 1 "
             f"(got {num_blocks}/{block_size})")
-    if name.startswith(_SLOT_KEY):
-        raise ValueError(f"{name!r}: the {_SLOT_KEY!r} keys are the per-slot "
-                         "leaves' (slot_variable)")
+    if name.startswith((_SLOT_KEY, _WINDOW_KEY)):
+        raise ValueError(f"{name!r}: the {_SLOT_KEY!r} and {_WINDOW_KEY!r} "
+                         "keys are the per-slot and the window leaves' "
+                         "(slot_variable, window_variable)")
     shape = (num_blocks, block_size) + (() if width is None else (width,))
     return module.variable("cache", name, jnp.zeros, shape, dtype)
+
+
+def ring_blocks(window: int, block_size: int) -> int:
+    """Blocks a slot's ring holds for a window of ``window`` positions and
+    a tick that writes up to ``block_size`` (the engine's prefill chunk):
+    what a lane may see and the tick writes, in whole blocks, and one more
+    to turn over."""
+    return -(-window // block_size) + 2
+
+
+def window_variable(module, name: str, num_slots: int, window: int,
+                    block_size: int, dtype, width: int):
+    """``module``'s window payload leaf ``name``: zeroed ``[num_slots *
+    ring_blocks, block_size, width]``, declared by the key it is stored
+    under, which carries ``window``."""
+    if window < 1 or block_size < 1:
+        raise ValueError(f"a window leaf needs window and block_size >= 1 "
+                         f"(got {window}/{block_size})")
+    shape = (num_slots * ring_blocks(window, block_size), block_size, width)
+    return module.variable("cache", f"{_WINDOW_KEY}{window}:{name}",
+                           jnp.zeros, shape, dtype)
+
+
+def has_window_variable(module, name: str, window: int) -> bool:
+    """Does ``module`` hold the window leaf already (a tick), or is this
+    the init trace that allocates it?"""
+    return module.has_variable("cache", f"{_WINDOW_KEY}{window}:{name}")
 
 
 def slot_variable(module, name: str, num_slots: int, shape: Tuple[int, ...],
@@ -118,16 +167,19 @@ def cow(leaves, cow_src, cow_dst, constrain: Optional[Callable] = None):
         return jax.tree_util.tree_map(copied, leaves)
 
 
-def write_rows(table, pos, n_new, num_blocks: int, block_size: int):
+def write_rows(table, pos, n_new, num_blocks: int, block_size: int,
+               ring: bool = False):
     """Flat arena rows ``[S * C]`` of this tick's tokens: lane ``j`` of
     slot ``s``, at logical position ``pos[s, j]``, is row ``table[s, pos //
     block_size] * block_size + pos % block_size``.  Lanes past ``n_new[s]``
     get row ``num_blocks * block_size`` and drop; the host maps only
-    exclusively owned blocks over a write span."""
+    exclusively owned blocks over a write span.  With ``ring`` the table
+    is a window leaf's: logical block ``j`` stands in column ``j mod
+    table.shape[1]`` (``num_blocks`` then the window arena's)."""
     with jax.named_scope("kv_write"):
-        blk = jnp.take_along_axis(
-            table, jnp.clip(pos // block_size, 0, table.shape[1] - 1),
-            axis=1)
+        col = (pos // block_size) % table.shape[1] if ring \
+            else jnp.clip(pos // block_size, 0, table.shape[1] - 1)
+        blk = jnp.take_along_axis(table, col, axis=1)
         flat = blk * block_size + pos % block_size
         valid = jnp.arange(pos.shape[1])[None, :] < n_new[:, None]
         return jnp.where(valid, flat, num_blocks * block_size).reshape(-1)
@@ -171,17 +223,24 @@ def _path_str(path) -> str:
                     for p in path)
 
 
-def _declared_per_slot(path) -> bool:
+def _declared(path, prefix: str) -> bool:
     return bool(path) and str(getattr(path[-1], "key", "")).startswith(
-        _SLOT_KEY)
+        prefix)
+
+
+def _declared_per_slot(path) -> bool:
+    return _declared(path, _SLOT_KEY)
 
 
 def _kind(path, leaf, num_blocks: int, block_size: int) -> Optional[str]:
-    """A per-slot leaf by its declaration (the key ``slot_variable`` stored
-    it under); a block leaf by shape alone: the first two dimensions are
-    the geometry's, three dimensions a payload, two a scale table."""
+    """A per-slot or a window leaf by its declaration (the key
+    ``slot_variable`` or ``window_variable`` stored it under); a block leaf
+    by shape alone: the first two dimensions are the geometry's, three
+    dimensions a payload, two a scale table."""
     if _declared_per_slot(path):
         return PER_SLOT
+    if _declared(path, _WINDOW_KEY):
+        return WINDOW
     if leaf.shape[:2] != (num_blocks, block_size):
         return None
     return {3: PAYLOAD, 2: SCALE}.get(leaf.ndim)
@@ -207,6 +266,17 @@ def slot_leaves(cache) -> List[Tuple[str, object]]:
     return [(_path_str(path), leaf) for path, leaf
             in jax.tree_util.tree_flatten_with_path(cache)[0]
             if _declared_per_slot(path)]
+
+
+def window_leaves(cache) -> List[Tuple[str, object, int]]:
+    """``(path, leaf, window)`` of every window leaf of a cache tree (none
+    for a model whose layers all attend their whole context)."""
+    out = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        if _declared(path, _WINDOW_KEY):
+            window = int(str(path[-1].key).split(":")[1])
+            out.append((_path_str(path), leaf, window))
+    return out
 
 
 def _sharding(mesh, kind: Optional[str]):

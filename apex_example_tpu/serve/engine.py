@@ -119,13 +119,16 @@ def _pct_dict(vals_ms: List[float]) -> Dict[str, float]:
 @dataclasses.dataclass(frozen=True)
 class TickArgs:
     """The column layout of the ONE int32 array a tick hands its step
-    program, a row a slot: ``[SLOTS, C + T + 6 (+ 2)]`` for ``chunk`` C
-    lanes and a block table of ``blocks`` T columns —
+    program, a row a slot: ``[SLOTS, C + T + R + 6 (+ 2)]`` for ``chunk``
+    C lanes, a block table of ``blocks`` T columns and a window leaves'
+    ring table of ``ring`` R (0 for a model without window leaves, whose
+    layout is what it was) —
 
-      ``tok`` [S, C] | ``block_table`` [S, T] | ``fill`` | ``n_new`` |
-      ``cow_src`` | ``cow_dst`` | ``top_k`` | ``temperature`` (float32,
-      carried as its bit pattern) | ``aux`` [S, 2] (``self_draft`` only:
-      :func:`draft_tick`'s draft count and next prompt token)
+      ``tok`` [S, C] | ``block_table`` [S, T] | ``ring_table`` [S, R] |
+      ``fill`` | ``n_new`` | ``cow_src`` | ``cow_dst`` | ``top_k`` |
+      ``temperature`` (float32, carried as its bit pattern) | ``aux``
+      [S, 2] (``self_draft`` only: :func:`draft_tick`'s draft count and
+      next prompt token)
 
     A hand-off to the runtime costs the host 0.3–0.5 ms whatever it
     carries (PERF.md §5), so the tick makes one.  :meth:`fields` is the
@@ -138,22 +141,25 @@ class TickArgs:
     chunk: int
     blocks: int
     self_draft: bool = False
+    ring: int = 0
 
     SCALARS = ("fill", "n_new", "cow_src", "cow_dst", "top_k",
                "temperature")
 
     @property
     def width(self) -> int:
-        return self.chunk + self.blocks + len(self.SCALARS) \
+        return self.chunk + self.blocks + self.ring + len(self.SCALARS) \
             + 2 * self.self_draft
 
     def fields(self, packed) -> Dict[str, Any]:
         """``packed`` [SLOTS, width] int32 by field name: views of a numpy
         array, slices of a jax one.  The temperature is reinterpreted, not
         converted, on either side: bit for bit what the request said."""
-        C, T = self.chunk, self.blocks
+        C, T, R = self.chunk, self.blocks, self.ring
         out = {"tok": packed[:, :C], "block_table": packed[:, C:C + T]}
-        for j, name in enumerate(self.SCALARS, C + T):
+        if R:
+            out["ring_table"] = packed[:, C + T:C + T + R]
+        for j, name in enumerate(self.SCALARS, C + T + R):
             out[name] = packed[:, j]
         bits = out["temperature"]
         out["temperature"] = bits.view(np.float32) \
@@ -174,8 +180,13 @@ class TickArgs:
         return packed, f
 
 
-# what of a tick's fields the models' paged forward reads
-_PAGED = ("block_table", "fill", "n_new", "cow_src", "cow_dst")
+# what of a tick's fields the models' paged forward reads (``ring_table``
+# where the layout has one)
+_PAGED = ("block_table", "ring_table", "fill", "n_new", "cow_src", "cow_dst")
+
+
+def _paged(a: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: a[k] for k in _PAGED if k in a}
 
 
 @functools.lru_cache(maxsize=8)
@@ -240,7 +251,7 @@ def _slot_step(dec, args: TickArgs, dequant_weights: bool = False,
         tok, n_new = a["tok"], a["n_new"]
         logits, mut = dec.apply(
             {"params": params, "cache": cache}, tok, train=False,
-            paged={k: a[k] for k in _PAGED},
+            paged=_paged(a),
             mutable=["cache"] if lanes else ["cache", "counters"])
         with device_span("sample"):
             if logits.shape[1] == tok.shape[1]:
@@ -298,7 +309,7 @@ def draft_tick(dec, args: TickArgs, params, cache, packed, rng):
     a = args.fields(packed)
     tok, n_new = a["tok"], a["n_new"]
     n_draft, next_tok = a["aux"][:, 0], a["aux"][:, 1]
-    paged = dict({k: a[k] for k in _PAGED}, n_draft=n_draft)
+    paged = dict(_paged(a), n_draft=n_draft)
     (logits, hidden), mut = dec.apply(
         {"params": params, "cache": cache}, tok, train=False, paged=paged,
         mutable=["cache", "counters"])
@@ -559,6 +570,11 @@ class ServeEngine:
                               num_blocks=num_blocks, kv_quant=kv_quant,
                               spec_slack=speculate,
                               rows_read_next_token=self.self_draft)
+        if self.pool.window is not None and role != "both":
+            raise ValueError(
+                f"role {role!r}: this model has window leaves, and a ring "
+                "is not handed from a prefill to a decode worker (ROADMAP "
+                "M3: hand-off of a ring between roles)")
         if speculate and not self.self_draft \
                 and not getattr(model, "all_lane_logits", True):
             raise ValueError(
@@ -608,6 +624,7 @@ class ServeEngine:
             if getattr(model, "packed_lanes", False) else None
         self.prefill_chunks_deferred = 0
         self.prefill_ticks_deferring = 0
+        self._window_released_seen = 0
         # Hand-offs between the tick's host thread and the runtime (the
         # key, the one put, the step's call, each fetch), summed over the
         # ticks that ran a step.  (Not the KV hand-offs between a prefill
@@ -691,7 +708,7 @@ class ServeEngine:
         # [SLOTS, block_size]-wide while the decode role's is
         # [SLOTS, 1]-wide — one program per role, each compiling once.
         self.tick_args = TickArgs(self.chunk, self.pool.max_blocks,
-                                  self.self_draft)
+                                  self.self_draft, self.pool.ring_blocks)
         self._step_fn = costmodel_lib.instrument(
             "serve_spec_step" if self.speculate
             else "serve_prefill_step" if role == "prefill"
@@ -986,8 +1003,10 @@ class ServeEngine:
                 cow_src[i], cow_dst[i] = pool.stage_writes(i, n)
                 temps[i] = slot.request.temperature
                 ks[i] = slot.request.top_k
-            # the table last: stage_writes mapped this tick's blocks
+            # the tables last: stage_writes mapped this tick's blocks
             f["block_table"][:] = pool.table
+            if "ring_table" in f:
+                f["ring_table"][:] = pool.ring_table
             build.set_metadata(lanes=int(n_new.sum()))
         # Every hand-off to the runtime the chip waits for, from here to
         # the tokens' return, is a child span (tickprof.ENGINE_HANDOFFS)
@@ -1176,13 +1195,26 @@ class ServeEngine:
         live_slots = len(self.pool.live)
         kv_live = self.pool.kv_bytes_live()
         blocks_live = self.pool.blocks_live()
-        per_block = self.pool.block_size * self.pool.kv_bytes_per_token()
         self._occ_hist.observe(live_slots)
         self._kv_hist.observe(kv_live)
         self._blk_hist.observe(blocks_live)
-        self._committed_hist.observe(
-            self.pool.blocks_committed() * per_block)
+        self._committed_hist.observe(self.pool.kv_bytes_committed())
         if counted:
+            if self.pool.window is not None:
+                # what the window arena holds of each slot beside what the
+                # full arena does, and the blocks handed back this tick
+                # (every tick, 0 included, like the model's own)
+                released = self.pool.window_blocks_released
+                row = lambda v: np.asarray(v, np.int32).reshape(1, -1)
+                counted[0] = dict(
+                    counted[0],
+                    window_blocks_released=row(
+                        released - self._window_released_seen),
+                    window_tokens_held=row(self.pool.window_tokens_held()),
+                    full_tokens_held=row(
+                        [0 if s is None else s.cursor
+                         for s in self.pool.slots]))
+                self._window_released_seen = released
             if self._chunk_budget is not None:
                 # beside what the model counted, on the host already
                 # (every tick, like the model's own: a reader takes a

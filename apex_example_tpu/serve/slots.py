@@ -39,6 +39,23 @@ Shared prefixes always stop one token short of the full prompt: the
 first generated token is sampled from the logits AFTER the last prompt
 token, and sharing that position's KV would skip the forward pass that
 produces those logits.
+
+**Two lifetimes in one pool.**  A model whose cache tree declares *window
+leaves* (ops/paged_cache.py: layers that attend a sliding window of ``W``
+positions) gets a second arena, a second :class:`BlockAllocator` and a
+second table beside the first: ``num_slots * ring_blocks`` blocks,
+``ring_table [SLOTS, ring_blocks]`` with logical block ``j`` in column ``j
+mod ring_blocks``.  A request then holds its whole sequence in the full
+arena and its last ``W`` tokens (and the tick's span) in the window arena:
+``stage_writes`` allocates in both, ``commit_writes`` hands back every
+window block the slot's fill has passed by a window — while the request
+still runs — and ``evict`` frees both.  Admission reserves ``min(blocks,
+ring_blocks)`` there, so a running slot can never want a window block and
+find none.  ``num_blocks``, ``blocks_needed``, ``fits`` and the prefix index
+stay the full arena's; a pool with window leaves shares no prefix (the
+window blocks a match would need may be handed back already), hands no
+request to another pool and takes no low-bit cache or draft lanes: each is
+refused with its reason, here or in serve/engine.py.
 """
 
 from __future__ import annotations
@@ -264,6 +281,9 @@ class Slot:
     token after the newest (fed beside it next tick), ``drafts`` every
     draft verified so far as ``(output index it claimed, token,
     accepted)`` (serve/engine.py).
+
+    ``ring_lo``/``ring_hi``/``ring_reserved``: what the slot holds of the
+    window arena, where the model has window leaves.
     """
 
     request: Request
@@ -279,6 +299,11 @@ class Slot:
     block_keys: List[Optional[Tuple]] = field(default_factory=list)
     draft: Optional[int] = None
     drafts: List[Tuple[int, int, bool]] = field(default_factory=list)
+    # window leaves: the logical blocks [ring_lo, ring_hi) stand in the
+    # window arena; ring_reserved more may be drawn at once
+    ring_lo: int = 0
+    ring_hi: int = 0
+    ring_reserved: int = 0
 
     @property
     def n_prompt(self) -> int:
@@ -290,7 +315,8 @@ class Slot:
 
 
 class BlockPool:
-    """``num_slots`` request slots over one block-paged KV arena.
+    """``num_slots`` request slots over one block-paged KV arena (and,
+    for a model with window leaves, the window arena beside it).
 
     ``model`` is the plain (training) module; the pool derives the
     paged slot-decode clone and allocates the per-layer arenas via an
@@ -382,6 +408,44 @@ class BlockPool:
                 "speculate: this model keeps a recurrent state per slot, "
                 "and a state advanced over rejected draft lanes cannot be "
                 "rolled back without a snapshot (ROADMAP M4)")
+        # The third kind: window leaves, in an arena, an allocator and a
+        # table of their own (module docstring).  ``window`` None and a
+        # table zero wide for every model without them.
+        windows = paged_cache.window_leaves(shapes)
+        self.window: Optional[int] = None
+        self.ring_blocks = 0
+        self.ring_alloc: Optional[BlockAllocator] = None
+        win_bytes = sum(leaf.size * leaf.dtype.itemsize
+                        for _, leaf, _ in windows)
+        if windows:
+            widths = sorted({w for _, _, w in windows})
+            if len(widths) != 1:
+                raise ValueError("window leaves of more than one window "
+                                 f"({widths}): one ring table serves one")
+            self.window = widths[0]
+            self.ring_blocks = paged_cache.ring_blocks(self.window,
+                                                       block_size)
+            ring_total = num_slots * self.ring_blocks
+            for path, leaf, _ in windows:
+                if leaf.shape[:2] != (ring_total, block_size):
+                    raise ValueError(
+                        f"window leaf {path!r} {tuple(leaf.shape)}: wanted "
+                        f"[{ring_total}, {block_size}, ..] ({num_slots} "
+                        f"slots x {self.ring_blocks} ring blocks)")
+            if self.spec_slack:
+                raise ValueError(
+                    "speculate: rejected draft lanes would have turned the "
+                    "ring over blocks the accepted prefix still needs; "
+                    "drafting over a ring is not built (ROADMAP M3)")
+            self.ring_alloc = BlockAllocator(ring_total, block_size)
+            self._kv_reserved += win_bytes
+        self._win_per_token = win_bytes // (
+            num_slots * self.ring_blocks * block_size) if windows else 0
+        self._full_per_token = (self._kv_reserved - win_bytes) \
+            // (num_blocks * block_size)
+        self.ring_table = np.zeros((num_slots, self.ring_blocks), np.int32)
+        self._ring_reserved_total = 0
+        self.window_blocks_released = 0
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.table = np.zeros((num_slots, self.max_blocks), np.int32)
         self.slots: List[Optional[Slot]] = [None] * num_slots
@@ -460,13 +524,23 @@ class BlockPool:
             return False
         shared, bids, _ = self._match_prefix(request.prompt)
         need = self.blocks_needed(request, shared)
+        if self.ring_alloc is not None and self.ring_alloc.available() \
+                - self._ring_reserved_total < self._ring_needed(request):
+            return False
         return self.alloc.available(tuple(bids)) \
             - self._reserved_total >= need
 
+    def _ring_needed(self, request: Request) -> int:
+        """Window blocks a request can hold at once: its sequence's, or a
+        ring's if that is less."""
+        return min(self.blocks_needed(request), self.ring_blocks)
+
     def _match_prefix(self, prompt) -> Tuple[int, List[int], List[Tuple]]:
         """``alloc.match_prefix``; no match at all for a cache tree with
-        per-slot state, one token less where rows read the next token."""
-        if self.per_slot_state:
+        per-slot state or window leaves (the window blocks a match needs
+        may be handed back: ROADMAP M3, prefix sharing per leaf kind), one
+        token less where rows read the next token."""
+        if self.per_slot_state or self.window is not None:
             return 0, [], []
         shared, bids, keys = self.alloc.match_prefix(prompt)
         if self.rows_read_next_token and shared:
@@ -505,6 +579,10 @@ class BlockPool:
                                n_mapped=len(bids), reserved=need,
                                block_keys=list(keys))
         self._reserved_total += need
+        if self.ring_alloc is not None:
+            self.ring_table[idx, :] = 0
+            self.slots[idx].ring_reserved = self._ring_needed(request)
+            self._ring_reserved_total += self.slots[idx].ring_reserved
         self._shared_tokens += shared
         self._prompt_tokens += n_prompt
         return idx
@@ -520,6 +598,12 @@ class BlockPool:
             self.alloc.unref(int(self.table[idx, b]))
         self._reserved_total -= slot.reserved
         self.table[idx, :] = 0
+        if self.ring_alloc is not None:
+            for b in range(slot.ring_lo, slot.ring_hi):
+                self.ring_alloc.unref(
+                    int(self.ring_table[idx, b % self.ring_blocks]))
+            self._ring_reserved_total -= slot.ring_reserved
+            self.ring_table[idx, :] = 0
         self.slots[idx] = None
         self._free.append(idx)
 
@@ -542,11 +626,20 @@ class BlockPool:
         slot = self.slots[idx]
         if slot is None:
             raise RuntimeError(f"slot {idx} is free — nothing to hand off")
+        self._refuse_ring_handoff()
         n = slot.n_mapped if n_blocks is None else n_blocks
         payload = paged_cache.extract(self.cache, self.table[idx, :n],
                                       self.num_blocks, self.block_size,
                                       slot=idx)
         return slot.cursor, n, payload
+
+    def _refuse_ring_handoff(self) -> None:
+        if self.window is not None:
+            raise ValueError(
+                "this pool has window leaves: a payload of blocks carries "
+                "no ring (which columns stand for which positions), so a "
+                "request is not handed to or taken from another pool "
+                "(ROADMAP M3: hand-off of a ring between roles)")
 
     def blocks_needed_prefilled(self, request: Request) -> int:
         """Worst-case blocks a handed-off request needs on the RECEIVING
@@ -578,6 +671,7 @@ class BlockPool:
         if not self._free:
             raise RuntimeError("no free slot (handoff admission must "
                                "check can_admit_prefilled first)")
+        self._refuse_ring_handoff()
         BS = self.block_size
         n_pay = math.ceil(fill / BS)
         total = self.blocks_needed_prefilled(request)
@@ -645,6 +739,21 @@ class BlockPool:
                 self.table[idx, b] = self._alloc_for(slot)
                 slot.block_keys.append(None)
                 slot.n_mapped += 1
+        if self.ring_alloc is not None:
+            # the same span in the window arena, through the ring: logical
+            # block b in column b mod ring_blocks (never shared: no COW)
+            R = self.ring_blocks
+            for b in range(max(slot.ring_hi, start // BS),
+                           (end - 1) // BS + 1):
+                if slot.ring_reserved < 1 or b - slot.ring_lo >= R:
+                    raise RuntimeError(
+                        f"{slot.request.uid}: the ring is full at block "
+                        f"{b} (holds {slot.ring_lo}..{slot.ring_hi}) — "
+                        "ring_blocks accounting bug")
+                self.ring_table[idx, b % R] = self.ring_alloc.alloc()
+                slot.ring_reserved -= 1
+                self._ring_reserved_total -= 1
+                slot.ring_hi = b + 1
         return cow
 
     def commit_writes(self, idx: int, n_new: int) -> None:
@@ -653,9 +762,23 @@ class BlockPool:
         immutable; its chain key hashes the whole token prefix)."""
         slot = self.slots[idx]
         slot.cursor += n_new
-        if self.per_slot_state:
-            return                 # nothing is shared, so nothing is indexed
         BS = self.block_size
+        if self.ring_alloc is not None:
+            # Hand back every window block the fill has passed by a window:
+            # the next lane, at position ``cursor``, sees positions >
+            # cursor - window, so a block whose last position lies at or
+            # before that is never read again.
+            R = self.ring_blocks
+            while slot.ring_lo < slot.ring_hi and \
+                    (slot.ring_lo + 1) * BS - 1 <= slot.cursor - self.window:
+                self.ring_alloc.unref(
+                    int(self.ring_table[idx, slot.ring_lo % R]))
+                slot.ring_lo += 1
+                slot.ring_reserved += 1
+                self._ring_reserved_total += 1
+                self.window_blocks_released += 1
+        if self.per_slot_state or self.window is not None:
+            return                 # nothing is shared, so nothing is indexed
         for b in range(slot.n_mapped):
             if slot.block_keys[b] is None and (b + 1) * BS <= slot.cursor:
                 parent = slot.block_keys[b - 1] if b else None
@@ -668,7 +791,8 @@ class BlockPool:
     def kv_bytes_reserved(self) -> int:
         """HBM bytes the arenas pin for the engine's lifetime: every
         page leaf is a full [num_blocks, block_size, W] allocation
-        (scale tables counted with them).  The default
+        (scale tables counted with them), every window leaf a
+        [num_slots * ring_blocks, block_size, W] one.  The default
         ``num_blocks`` makes this equal to the dense layout's
         reservation — the paged win shows up in the per-tick committed/
         live gauges, not here."""
@@ -676,11 +800,11 @@ class BlockPool:
 
     def kv_bytes_per_token(self) -> int:
         """Bytes one cached token occupies across every layer's arena
-        leaves (``kv_bytes_reserved / (num_blocks * block_size)``) —
+        leaves, each arena's bytes over its own room in tokens (the full
+        arena's ``num_blocks * block_size``; the window arena's) —
         dtype-accurate: int8 payload plus the bf16 block scales under
         kv_quant, the full-precision payload otherwise."""
-        return self.kv_bytes_reserved() \
-            // (self.num_blocks * self.block_size)
+        return self._full_per_token + self._win_per_token
 
     def kv_bytes_per_token_bf16(self) -> int:
         """What one cached token WOULD cost in a bf16 dense-payload
@@ -698,10 +822,21 @@ class BlockPool:
         """Bytes of KV the live slots logically hold (per-slot fill
         level times the per-token cost; a shared block's tokens count
         once per sharer — this is the demand gauge, ``blocks_in_use``
-        the physical one)."""
+        the physical one).  A window leaf's tokens count while their
+        slot lives, handed back or not: ``window_tokens_held`` says what
+        still stands in the window arena."""
         per_token = self.kv_bytes_per_token()
         return sum(s.cursor for s in self.slots if s is not None) \
             * per_token
+
+    def window_tokens_held(self) -> List[int]:
+        """Per slot, the tokens whose rows stand in the window arena (a
+        free slot's 0): the fill less what has been handed back.  The
+        slot's fill itself where nothing was, and for a pool without
+        window leaves."""
+        BS = self.block_size
+        return [0 if s is None else s.cursor - s.ring_lo * BS
+                for s in self.slots]
 
     def state_bytes_reserved(self) -> int:
         """HBM bytes the per-slot leaves pin (a recurrent layer's state and
@@ -715,13 +850,26 @@ class BlockPool:
         return self._state_per_slot * (self.num_slots - len(self._free))
 
     def blocks_live(self) -> int:
-        """Arena blocks physically held by live slots right now."""
+        """Blocks of the full arena physically held by live slots right
+        now."""
         return self.alloc.blocks_in_use
 
+    def window_blocks_live(self) -> int:
+        """Blocks of the window arena held right now (0 without one)."""
+        return self.ring_alloc.blocks_in_use if self.ring_alloc else 0
+
     def blocks_committed(self) -> int:
-        """Blocks admission has committed: physically held plus
-        reserved-but-unallocated worst-case budget."""
+        """Blocks of the full arena admission has committed: physically
+        held plus reserved-but-unallocated worst-case budget."""
         return self.alloc.blocks_in_use + self._reserved_total
+
+    def kv_bytes_committed(self) -> int:
+        """``blocks_committed`` in bytes, the window arena's held and
+        reserved blocks with it."""
+        BS = self.block_size
+        return self.blocks_committed() * BS * self._full_per_token \
+            + (self.window_blocks_live() + self._ring_reserved_total) \
+            * BS * self._win_per_token
 
     def prefix_hit_rate(self) -> float:
         """Shared prompt tokens / total prompt tokens over every

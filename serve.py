@@ -143,7 +143,8 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["gpt_tiny", "gpt_base", "xing4_tiny",
                             "xing4_29b_a4b_cut", "granite_hybrid_tiny",
                             "granite_4_0_h_micro", "pangu_moe_tiny",
-                            "openpangu_ultra_moe_718b_cut"],
+                            "openpangu_ultra_moe_718b_cut", "trinity_tiny",
+                            "trinity_mini_cut"],
                    help="gpt_*: the post-LN decoder (float32). xing4_*: "
                         "latent attention, dropless experts, hyper-"
                         "connected residual (models/xing4.py): the tiny "
@@ -171,7 +172,17 @@ def build_parser() -> argparse.ArgumentParser:
                         "and the module); served drafting one token a "
                         "tick with no flag (--speculate 0 turns it off, "
                         "--speculate 2, --kv-quant and a model-sharded "
-                        "--mesh are refused)")
+                        "--mesh are refused). trinity_*: window and full "
+                        "attention layers mixed (gated, QK-normed GQA) "
+                        "with all 128 sigmoid-routed experts "
+                        "(models/trinity.py): the tiny preset in float32 "
+                        "(window 8), Trinity-Mini's published widths cut "
+                        "to 5 layers in bfloat16; the window layers' K/V "
+                        "live in a second, ring-sized arena whose blocks "
+                        "are handed back while a request runs; no prefix "
+                        "is shared, and --kv-quant, --speculate, --role "
+                        "prefill|decode and a model-sharded --mesh are "
+                        "refused")
     p.add_argument("--checkpoint-dir", default=None,
                    help="CheckpointManager directory to restore params "
                         "from (omit = random init, smoke mode)")
@@ -615,6 +626,8 @@ def run_serve(args):
                                                         granite_hybrid_tiny)
     from apex_example_tpu.models.pangu_moe import (
         openpangu_ultra_moe_718b_cut, pangu_moe_tiny)
+    from apex_example_tpu.models.trinity import (trinity_mini_cut,
+                                                 trinity_tiny)
     from apex_example_tpu.models.xing4 import (xing4_29b_a4b_cut,
                                                xing4_tiny)
     from apex_example_tpu.parallel.mesh import (parse_serve_mesh,
@@ -651,6 +664,8 @@ def run_serve(args):
              "granite_4_0_h_micro": granite_4_0_h_micro,
              "pangu_moe_tiny": pangu_moe_tiny,
              "openpangu_ultra_moe_718b_cut": openpangu_ultra_moe_718b_cut,
+             "trinity_tiny": trinity_tiny,
+             "trinity_mini_cut": trinity_mini_cut,
              }[args.arch](tensor_parallel=tp > 1)
     max_len = args.max_len
     if max_len is None:

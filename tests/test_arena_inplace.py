@@ -395,3 +395,76 @@ def test_tpu_latent_kernel_compiles_at_128_heads(one_chip):
         sds((S, C, H, W), jnp.bfloat16), sds((S * MB, BS, W), jnp.bfloat16),
         sds((S, MB), i32), sds((S,), i32), sds((S,), i32)).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+WINDOW_SLOTS, WINDOW_MAX_LEN, WINDOW = 16, 4096, 2048
+
+
+@pytest.fixture(scope="module", params=["kernel", "xla"])
+def window_tick(request, one_chip):
+    """The tick of models/trinity.py lowered from shapes: one window layer
+    and one full layer at the published head shape (32 query heads over 4
+    key/value heads of 128, window 2048, blocks of 16: a ring of 130 blocks
+    a slot beside 256 a slot in the full arena), everything else small.
+    Steered as ``latent_tick`` is."""
+    from apex_example_tpu.models.trinity import (FULL, WINDOW as W,
+                                                 TrinityForCausalLM)
+    from apex_example_tpu.ops import paged_cache
+    slots, nb = WINDOW_SLOTS, WINDOW_SLOTS * WINDOW_MAX_LEN // BS
+    dec = TrinityForCausalLM(
+        vocab_size=512, hidden_size=512, num_layers=2, num_dense_layers=1,
+        intermediate_size=512, moe_intermediate_size=256, num_experts=8,
+        layer_types=(W, FULL)).clone(
+            decode=True, slot_decode=True, fused_attention=False,
+            kv_num_blocks=nb, kv_block_size=BS)
+    shapes = jax.eval_shape(dec.init, jax.random.PRNGKey(0),
+                            jnp.zeros((slots, WINDOW_MAX_LEN), jnp.int32))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def tree(t):
+        return jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype), t)
+
+    ring = paged_cache.ring_blocks(WINDOW, BS)
+    layout = engine_lib.TickArgs(BS, WINDOW_MAX_LEN // BS, False, ring)
+    args = (tree(shapes["params"]), tree(shapes["cache"]),
+            sds((slots, layout.width), jnp.int32), sds((2,), jnp.uint32))
+    leaves = jax.tree_util.tree_leaves(shapes["cache"])
+    assert sorted({l.shape for l in leaves}) == [
+        (slots * ring, BS, 512), (nb, BS, 512)] and ring == 130
+    with compiled_as_the_chip_does(request.param):
+        lowered = engine_lib._slot_step(dec, layout).lower(*args)
+    return (request.param, lowered.compile(),
+            sum(l.size * l.dtype.itemsize for l in leaves),
+            {l.size for l in leaves})
+
+
+def test_tpu_tick_walks_both_arenas_in_a_kernel_and_in_place(window_tick):
+    """ISSUE 40: at the published head shape the tick compiles for the
+    chip with the paged GQA kernel once a layer (a table of 256 columns
+    and a ring of 130 in scalar memory), holds no gathered ``[S, L, 4,
+    128]`` view and no ``[S, 4, 8, C, L]`` score tensor of either table,
+    no copy of either arena's size, and aliases every cache byte; the XLA
+    form of the same op holds the views and no such call."""
+    form, compiled, cache_bytes, sizes = window_tick
+    text = compiled.as_text()
+    copies = [(dtype, dims) for dtype, dims
+              in arena_sized_copies(text, min(sizes))
+              if math.prod(int(d) for d in dims.split(",")) in sizes]
+    # (the XLA form's gathered views are as large as the arenas here, at
+    # dense capacity, and are relaid for the scores: attention's work)
+    assert copies == [] or form == "xla"
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "paged_gqa_attention" in line]
+    views = [f"bf16[{WINDOW_SLOTS},{L},4,128]"
+             for L in (WINDOW_MAX_LEN, 130 * BS)]
+    scores = [f"f32[{WINDOW_SLOTS},4,8,{BS},{L}]"
+              for L in (WINDOW_MAX_LEN, 130 * BS)]
+    if form == "kernel":
+        assert len(calls) == 2                       # one a layer
+        assert not any(t in text for t in views + scores)
+    else:
+        assert not calls and all(t in text for t in scores)
