@@ -129,6 +129,14 @@ class BertSelfAttention(nn.Module):
     # (serve/slots.py allocates; there is no device-side index state).
     # One compiled step advances every live slot by up to kv_block_size
     # tokens (chunked prefill) or one (decode); the geometry is static.
+    # Which form of attention reads the arenas (PR 41): float arenas held
+    # whole on one chip go through ops.attention.paged_gqa_attention — on
+    # the TPU, and under the tests' interpreter, a Pallas kernel that
+    # walks each slot's live blocks where they lie (two heads of 64 a
+    # lane tile); on a plain CPU drive and under FORCE_XLA the op's XLA
+    # form, which gathers the whole table row.  int8 arenas (kv_quant)
+    # and arenas sharded over 'model' (tensor_parallel) keep the
+    # gathered view and this module's own masked softmax.
     slot_decode: bool = False
     kv_num_blocks: int = 0
     kv_block_size: int = 0
@@ -251,11 +259,27 @@ class BertSelfAttention(nn.Module):
                         (cks.value, cvs.value), flat, (k_sc, v_sc))
                 ck.value, cv.value = paged_cache.write(
                     (ck.value, cv.value), flat, (k, v), arena)
-                # 3. Each slot's logical view ([S, max_blocks*BS, H, D])
-                # and attention under the per-slot causal live mask: query
+                # 3. Attention under the per-slot causal live mask: query
                 # j (position fill+j) sees keys at positions <= fill+j;
                 # stale rows sit beyond it, and the host discards dead
-                # slots' lanes.
+                # slots' lanes.  A float arena held whole on one chip is
+                # read where it lies: ops.attention.paged_gqa_attention
+                # (a Pallas kernel over each slot's live blocks on the
+                # TPU, its XLA gather form on the CPU; softmax in fp32).
+                # What that op cannot take keeps the gathered form below:
+                # int8 rows with a scale table, heads sharded over 'model'
+                # (a pallas_call is opaque to the partitioner), a half-
+                # softmax contract.
+                if not (self.kv_quant or self.tensor_parallel
+                        or self.softmax_dtype != jnp.float32):
+                    from apex_example_tpu.ops.attention import (
+                        paged_gqa_attention)
+                    with device_span("paged_attention"):
+                        ctx, _ = paged_gqa_attention(
+                            q, ck.value, cv.value, table, paged["fill"],
+                            paged["n_new"], scale=1.0 / float(hd) ** 0.5)
+                        return dense_out(ctx.reshape(*x.shape[:-1], d))
+                # each slot's logical view ([S, max_blocks*BS, H, D])
                 keys, vals = paged_cache.gather((ck.value, cv.value), table,
                                                 heads=h)
                 if self.kv_quant:

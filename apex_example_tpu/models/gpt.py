@@ -73,6 +73,10 @@ class GPTForCausalLM(nn.Module):
     # per step (chunked prefill) or one (decode); the geometry is
     # static, so one compiled step serves every slot mix.  This is the
     # substrate the continuous-batching engine (serve/) schedules on.
+    # Float arenas on one chip are read by
+    # ops.attention.paged_gqa_attention (a Pallas kernel over the live
+    # blocks on the TPU, its XLA gather form on the CPU); int8 arenas and
+    # tensor-parallel ones keep the gathered view (models/bert.py).
     slot_decode: bool = False
     kv_num_blocks: int = 0
     kv_block_size: int = 0

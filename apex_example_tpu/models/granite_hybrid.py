@@ -17,6 +17,12 @@ S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t`` in its chunked form
 (``ops/ssd.py``); ``RMSNorm_w(y silu(z))`` over all channels; ``W_out``.
 *Attention*: GQA (query head ``i`` reads K/V head ``i // group``), no bias,
 no rotary or learned position, scores scaled by ``attention_multiplier``.
+On the paged path the scores, mask, softmax and weighted sum are
+``ops.attention.paged_gqa_attention`` (PR 41): on the TPU, and under the
+tests' interpreter, a Pallas kernel that walks each slot's live blocks where
+they lie in the arenas (two heads of 64 a lane tile); on a plain CPU drive
+and under ``FORCE_XLA`` the op's XLA form, which gathers every row of the
+table.  The plain forward keeps its einsums.
 
 **Two kinds of cache** in the paged slot-decode path (``decode=True,
 slot_decode=True``; the contract ``serve/slots.BlockPool`` and
@@ -56,10 +62,12 @@ softmax and logits are float32; convolution rows and K/V are ``dtype``.
 The model sows ``ssm_slots_advanced [mamba layers, slots]`` (1 where a
 slot's state moved this tick), ``ssm_state_visits [mamba layers, slots]``
 (1 where the scan's kernel fetched and wrote a slot's state, ``ops/ssd.py``;
-nothing where the XLA form ran, which reads every slot's), ``lanes_live
-[1, slots]`` (``n_new``) and ``rows_dense [1, 1]`` (the rows its token-wise
-products ran on: ``R`` packed, ``SLOTS * C`` not) into the ``counters``
-collection.
+nothing where the XLA form ran, which reads every slot's),
+``attn_positions_walked [attention layers, slots]`` (the cache positions
+attention read for each slot: the kernel's live blocks times the block
+size, the whole table row from the XLA form), ``lanes_live [1, slots]``
+(``n_new``) and ``rows_dense [1, 1]`` (the rows its token-wise products ran
+on: ``R`` packed, ``SLOTS * C`` not) into the ``counters`` collection.
 """
 
 from __future__ import annotations
@@ -73,6 +81,7 @@ import jax.numpy as jnp
 
 from apex_example_tpu.obs.spans import device_span
 from apex_example_tpu.ops import lane_pack, paged_cache, ssd
+from apex_example_tpu.ops.attention import paged_gqa_attention
 
 F32 = jnp.float32
 
@@ -219,6 +228,10 @@ class MambaMixer(nn.Module):
 
 
 class GQAttention(nn.Module):
+    """Returns ``(y, walked)``: ``walked [S]`` the cache positions the
+    paged form read for each slot (``ops.attention.paged_gqa_attention``),
+    None from the plain forward."""
+
     hidden_size: int
     num_heads: int
     num_kv_heads: int
@@ -247,8 +260,8 @@ class GQAttention(nn.Module):
         q, k, v = mm(h, wq), mm(h, wk), mm(h, wv)          # [.., Hk * hd]
         if lanes is not None:
             q = lanes.unpack(q)
-        q = q.reshape(S, L, Hk, g, hd)
-        keys = vals = None
+        q = q.reshape(S, L, Hq, hd)
+        o = walked = None
         if self.decode:
             NB, BS = self.kv_num_blocks, self.kv_block_size
             ready = self.has_variable("cache", "cached_key")
@@ -269,24 +282,30 @@ class GQAttention(nn.Module):
                     flat = lanes.pack(flat.reshape(S, L), fill=NB * BS)
                 ck.value, cv.value = paged_cache.write(
                     (ck.value, cv.value), flat, (k, v))
-                # each slot's logical view [S, max_blocks * BS, Hk, hd];
-                # rows past a slot's fill are stale and masked below
-                keys, vals = paged_cache.gather((ck.value, cv.value), table,
-                                                heads=Hk)
-                kpos = jnp.arange(keys.shape[1])[None, :]
-        if keys is None:
-            keys, vals = (t.reshape(S, L, Hk, hd) for t in (k, v))
-            kpos = pos
-        with device_span("gqa_attention"):
-            scores = einsum_f32("sqkgd,slkd->skgql", q, keys) * self.scale
-            seen = kpos[:, None, None, None, :] <= pos[:, None, None, :, None]
-            probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
-            o = einsum_f32("skgql,slkd->sqkgd", probs.astype(self.dtype),
-                           vals).astype(self.dtype).reshape(S, L, Hq * hd)
+                # scores, mask, softmax and weighted sum over the slot's
+                # blocks where they lie: one op that names its own scope
+                # (ops/attention.py)
+                o, walked = paged_gqa_attention(
+                    q, ck.value, cv.value, table, paged["fill"],
+                    paged["n_new"], scale=self.scale)
+            # init trace on the [B, max_len] dummy: the leaves are
+            # allocated above; fall through so that params initialize
+        if o is None:
+            with device_span("gqa_attention"):
+                keys, vals = (t.reshape(S, L, Hk, hd) for t in (k, v))
+                scores = einsum_f32("sqkgd,slkd->skgql",
+                                    q.reshape(S, L, Hk, g, hd),
+                                    keys) * self.scale
+                seen = pos[:, None, None, None, :] \
+                    <= pos[:, None, None, :, None]
+                probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
+                o = einsum_f32("skgql,slkd->sqkgd", probs.astype(self.dtype),
+                               vals).astype(self.dtype)
+        o = o.reshape(S, L, Hq * hd)
         if lanes is not None:
             o = lanes.pack(o)
         with device_span("gqa_attention"):
-            return mm(o, wo)
+            return mm(o, wo), walked
 
 
 class SharedMLP(nn.Module):
@@ -307,7 +326,9 @@ class SharedMLP(nn.Module):
 
 
 class GraniteHybridLayer(nn.Module):
-    """One layer; ``cfg`` is the model's own field values."""
+    """One layer; ``cfg`` is the model's own field values.  Returns ``(x,
+    (advanced, visits, walked))``: the first two a Mamba layer's, the
+    third an attention layer's on the paged path, None otherwise."""
 
     cfg: Tuple[Tuple[str, object], ...]
     kind: str
@@ -321,7 +342,7 @@ class GraniteHybridLayer(nn.Module):
         norm = lambda name, t: rms_norm(
             t, self.param(name, nn.initializers.ones, (d,), pd),
             eps).astype(dtype)
-        h, counts = norm("norm1", x), (None, None)
+        h, counts, walked = norm("norm1", x), (None, None), None
         if self.kind == "mamba":
             with device_span("ssm_mixer"):
                 y, *counts = MambaMixer(
@@ -330,7 +351,7 @@ class GraniteHybridLayer(nn.Module):
                     c["mamba_chunk_size"], eps, dtype, pd, c["decode"],
                     name="mixer")(h, paged, lanes)
         else:
-            y = GQAttention(
+            y, walked = GQAttention(
                 d, c["num_heads"], c["num_kv_heads"], c["head_dim"],
                 c["attention_multiplier"], dtype, pd, c["decode"],
                 c["kv_num_blocks"], c["kv_block_size"],
@@ -338,7 +359,8 @@ class GraniteHybridLayer(nn.Module):
         x = (x.astype(F32) + r * y.astype(F32)).astype(dtype)
         y = SharedMLP(d, c["intermediate_size"], dtype, pd,
                       name="mlp")(norm("norm2", x))
-        return (x.astype(F32) + r * y.astype(F32)).astype(dtype), counts
+        return (x.astype(F32) + r * y.astype(F32)).astype(dtype), \
+            (*counts, walked)
 
 
 class GraniteHybridForCausalLM(nn.Module):
@@ -428,24 +450,21 @@ class GraniteHybridForCausalLM(nn.Module):
         if lanes is not None:
             # a dead row holds zeros from here on (lane_pack's promise)
             x = jnp.where(lanes.row_live[:, None], x, 0)
-        moves, visits = [], []
+        counted = ([], [], [])                # moved, visited, walked
         for i, kind in enumerate(self.layer_kinds()):
-            x, (moved, visited) = GraniteHybridLayer(
+            x, counts = GraniteHybridLayer(
                 cfg, kind, name=f"layer_{i}")(x, pos, paged, lanes)
-            if moved is not None:
-                moves.append(moved)
-            if visited is not None:
-                visits.append(visited)
+            for rows, row in zip(counted, counts):
+                if row is not None:
+                    rows.append(row)
         if paged is not None:
             # what the layers did this tick, read by the engine when the
             # "counters" collection is mutable, dropped otherwise
             keep = dict(reduce_fn=lambda _, new: new, init_fn=lambda: None)
-            if moves:
-                self.sow("counters", "ssm_slots_advanced", jnp.stack(moves),
-                         **keep)
-            if visits:
-                self.sow("counters", "ssm_state_visits", jnp.stack(visits),
-                         **keep)
+            for name, rows in zip(("ssm_slots_advanced", "ssm_state_visits",
+                                   "attn_positions_walked"), counted):
+                if rows:
+                    self.sow("counters", name, jnp.stack(rows), **keep)
             self.sow("counters", "lanes_live", paged["n_new"][None, :],
                      **keep)
             self.sow("counters", "rows_dense",

@@ -818,9 +818,10 @@ def paged_latent_attention(qf, arena, block_table, fill, n_new, *, scale, kr):
 
 
 # --------------------------------------------------------------------------
-# Paged grouped-query attention (the serve tick of models/trinity.py): K and
-# V in two [NB, BS, Hk * hd] arenas, full or window, the window's through a
-# ring.  ``paged_gqa_attention`` is the op; the XLA form below it is the CPU
+# Paged per-head attention (the serve tick of models/trinity.py, of
+# models/granite_hybrid.py and of models/bert.py's float arenas): K and V in
+# two [NB, BS, Hk * hd] arenas, full or window, the window's through a ring.
+# ``paged_gqa_attention`` is the op; the XLA form below it is the CPU
 # fallback, FORCE_XLA's path and the kernel tests' golden.
 # --------------------------------------------------------------------------
 
@@ -841,6 +842,27 @@ def _gqa_lanes(o, lanes: int):
     g = rows // lanes
     return o.reshape(S, Hk, lanes, g, hd).transpose(0, 2, 1, 3, 4).reshape(
         S, lanes, Hk * g, hd)
+
+
+def _heads_a_tile(hd: int, kv_heads: int) -> int:
+    """Key/value heads the kernel takes as one: a head of 64 is half a
+    128-lane tile of the arena's merged dimension, so two that lie side by
+    side are taken as one of 128 (:func:`_paired_rows`)."""
+    return 2 if hd == 64 and kv_heads % 2 == 0 else 1
+
+
+def _paired_rows(q, kv_heads: int):
+    """Queries for key/value heads taken two a lane tile: ``[S, C, Hq,
+    hd]`` as ``[S, C, Hq, 2 * hd]``, each row in its own head's half of the
+    pair's lanes and zeros in the other, so that its scores against the
+    pair's keys are its own head's exactly (``0 * k`` in the other half).
+    Also ``upper [Hq]``: whose half is the second.  A pair's ``2 g`` query
+    heads stay adjacent, so :func:`_gqa_rows` over ``Hk / 2`` heads orders
+    the rows lane-major as before (row // (2 g) is the lane)."""
+    Hq, hd = q.shape[2:]
+    upper = (jnp.arange(Hq) // (Hq // kv_heads)) % 2 == 1
+    own = upper[:, None] == (jnp.arange(2 * hd) >= hd)[None, :]
+    return jnp.where(own, jnp.concatenate([q, q], -1), 0), upper
 
 
 def paged_gqa_attention_reference(q, k_arena, v_arena, table, fill, n_new,
@@ -1093,6 +1115,12 @@ def _paged_gqa_pallas(q, k_arena, v_arena, table, fill, n_new, scale, window,
     S, C, Hq, hd = q.shape
     NB, BS, W = k_arena.shape
     Hk = W // hd
+    paired = _heads_a_tile(hd, Hk) == 2
+    if paired:
+        # the second product fills both halves of a pair's lanes; each row
+        # keeps its own below
+        q, upper = _paired_rows(q, Hk)
+        Hk, hd = Hk // 2, 2 * hd
     columns = table.shape[1]
     rows = C * (Hq // Hk)
     if pages is None:
@@ -1130,7 +1158,10 @@ def _paged_gqa_pallas(q, k_arena, v_arena, table, fill, n_new, scale, window,
         interpret=interpret,
     )(i32(table).reshape(-1), i32(fill), i32(n_new), _gqa_rows(q, Hk),
       k_arena, v_arena)
-    return _gqa_lanes(o, C), walked
+    o = _gqa_lanes(o, C)
+    if paired:
+        o = jnp.where(upper[:, None], o[..., hd // 2:], o[..., :hd // 2])
+    return o, walked
 
 
 def _paged_gqa_ok(q, k_arena) -> bool:
@@ -1138,11 +1169,14 @@ def _paged_gqa_ok(q, k_arena) -> bool:
         return False
     if _cfg.INTERPRET:
         return True
-    # Mosaic: a head a whole 128-lane tile, whole sublane tiles a page
+    # Mosaic: a head, or a pair of heads, a whole 128-lane tile; whole
+    # sublane tiles a page
     sublanes = 8 * 4 // k_arena.dtype.itemsize
     _, C, Hq, hd = q.shape
-    rows = C * Hq // (k_arena.shape[2] // hd)
-    return (hd % 128 == 0 and k_arena.shape[1] % sublanes == 0
+    Hk = k_arena.shape[2] // hd
+    heads = _heads_a_tile(hd, Hk)
+    rows = C * Hq // (Hk // heads)
+    return ((heads * hd) % 128 == 0 and k_arena.shape[1] % sublanes == 0
             and rows % sublanes == 0)
 
 
@@ -1165,11 +1199,16 @@ def paged_gqa_attention(q, k_arena, v_arena, table, fill, n_new, *, scale,
     and walked (S,) int32, the cache positions read for each slot.
 
     Scores, softmax and accumulation are float32; probabilities are cast to
-    the arena's dtype for the second product.  On TPU (and under the
-    interpreter) a Pallas kernel walks, for each slot, the blocks from the
-    first any live lane may see to the last it wrote, where they lie, and
-    nothing for ``n_new == 0``; elsewhere the XLA form gathers and scores
-    every row of the table."""
+    the arena's dtype for the second product (bfloat16 arenas stay
+    bfloat16, float32 arenas float32).  On TPU (and under the interpreter)
+    a Pallas kernel walks, for each slot, the blocks from the first any
+    live lane may see to the last it wrote, where they lie, and nothing for
+    ``n_new == 0``; elsewhere the XLA form gathers and scores every row of
+    the table.  Mosaic wants a head, or a pair of heads, a whole 128-lane
+    tile of the arena: ``hd % 128 == 0`` (models/trinity.py), or ``hd ==
+    64`` with an even ``Hk``, two adjacent heads taken as one of 128 by the
+    launcher (models/granite_hybrid.py, models/bert.py's float arenas);
+    one kernel body either way."""
     if ring and ring != table.shape[1]:
         raise ValueError(f"a ring of {ring} blocks wants a table {ring} "
                          f"columns wide, got {table.shape[1]}")

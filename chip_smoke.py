@@ -250,6 +250,32 @@ def _kernel_cases():
             qf, arena, table, fill, n_new, scale=0.1, kr=512)[0],
         (qf, rnd((NB, BS, 640), jnp.bfloat16), table, fill, n_new)))
 
+    # Paged per-head attention at 64-wide heads, two a lane tile, at the
+    # two older decoders' served shapes: serve.py --arch granite_4_0_h_micro
+    # (64 slots, 32 query heads over 8 x 64 bfloat16, 64 columns of 16) and
+    # --arch gpt_base (12 x 64 float32, 32 columns); slots in prefill, in
+    # decode, part-way and dead at seeded fills, their blocks in shuffled
+    # places, -1 behind them.  The float32 case is judged by the bfloat16
+    # bound: the XLA form's products are one bfloat16 pass on the TPU.
+    for tag, Hq, Hk, dt, cols in (("granite4h", 32, 8, jnp.bfloat16, 64),
+                                  ("gpt1", 12, 12, jnp.float32, 32)):
+        lanes = jnp.tile(n_new, 8)
+        fills = jax.random.randint(next(keys), (64,), 0, (cols - 1) * BS)
+        held = jnp.where(lanes > 0, -(-(fills + lanes) // BS), 0)
+        col = jnp.arange(cols)[None, :]
+        places = jax.random.permutation(next(keys), 64 * cols).reshape(
+            64, cols)
+        cases.append((
+            f"paged_gqa_attention S64 C16 H{Hq}/{Hk} D64 "
+            f"{jnp.dtype(dt).name} ({tag}), {cols} blocks of 16",
+            lambda q, k, v, table, fill, n_new: attention.paged_gqa_attention(
+                q, k, v, table, fill, n_new, scale=0.125)[0].astype(
+                    jnp.bfloat16),
+            (rnd((64, 16, Hq, 64), dt), rnd((64 * cols, BS, Hk * 64), dt),
+             rnd((64 * cols, BS, Hk * 64), dt),
+             jnp.where(col < held[:, None], places, -1).astype(jnp.int32),
+             fills.astype(jnp.int32), lanes)))
+
     # The dropless experts' grouped products at the served widths (3584 x
     # 1024, bf16; 16 of the 64 experts): empty groups, groups across a row
     # tile of 128, rows past the last group.  Those rows hold anything in

@@ -133,15 +133,58 @@ def test_copy_counter_sees_the_copies_it_is_for():
         ("f32", "96,16,12,64"), ("bf16", "1536,12,64")]
 
 
-@pytest.mark.parametrize("speculative", [False, True],
-                         ids=["plain", "speculative"])
-def test_tpu_tick_has_no_arena_sized_copy_and_aliases_the_arena(
-        one_chip, speculative):
-    lowered, arena_bytes, arena_elems = _lowered(speculative, False,
-                                                 one_chip)
+@pytest.fixture(scope="module", params=["kernel", "xla"])
+def gpt_tick(request, one_chip):
+    """The GPT tick at GPT-1's published attention widths (12 heads of 64,
+    float32 arenas ``[NB, 16, 768]``) compiled as the chip compiles it, on
+    either form of ``ops.attention.paged_gqa_attention``."""
+    with compiled_as_the_chip_does(request.param):
+        lowered, arena_bytes, arena_elems = _lowered(False, False, one_chip)
+    return request.param, lowered.compile(), arena_bytes, arena_elems
+
+
+def _per_head_calls(text):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "paged_gqa_attention" in line]
+
+
+def test_tpu_tick_has_no_arena_sized_copy_and_aliases_the_arena(gpt_tick):
+    _, compiled, arena_bytes, arena_elems = gpt_tick
+    assert arena_sized_copies(compiled.as_text(), arena_elems) == []
+    assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
+
+
+def test_tpu_speculative_tick_has_no_arena_sized_copy_either(one_chip):
+    with compiled_as_the_chip_does("kernel"):
+        lowered, arena_bytes, arena_elems = _lowered(True, False, one_chip)
     compiled = lowered.compile()
     assert arena_sized_copies(compiled.as_text(), arena_elems) == []
     assert compiled.memory_analysis().alias_size_in_bytes == arena_bytes
+    assert len(_per_head_calls(compiled.as_text())) == LAYERS
+
+
+def test_tpu_tick_walks_gpt1s_float32_arenas_in_a_kernel(gpt_tick):
+    """ISSUE 41: at 12 heads of 64 over float32 arenas the tick compiles
+    for the chip with the paged GQA kernel once a layer, two heads a lane
+    tile (six pairs of 128 lanes, query rows ``16 lanes x 2``), and holds
+    no gathered ``[S, L, 768]`` view of either dtype, no ``[S, L, 12, 64]``
+    relayout of one and no ``[S, 12, C, L]`` scores; the XLA form of the
+    same op holds them and no such call."""
+    form, compiled, _, _ = gpt_tick
+    text = compiled.as_text()
+    calls = _per_head_calls(text)
+    views = [f"{dt}[{SLOTS},{MAX_LEN},{tail}]" for dt in ("f32", "bf16")
+             for tail in (HIDDEN, f"{HEADS},64")]
+    scores = f"f32[{SLOTS},{HEADS},1,{BS},{MAX_LEN}]"
+    if form == "kernel":
+        assert len(calls) == LAYERS
+        assert all(f"f32[{SLOTS},6,{2 * BS},128]" in c
+                   and f"f32[{NB},{BS},{HIDDEN}]" in c for c in calls)
+        assert not any(t in text for t in views + [scores])
+    else:
+        assert not calls and scores in text \
+            and any(t in text for t in views)
 
 
 def test_tpu_tick_updates_the_latent_arena_in_place(one_chip):
@@ -336,6 +379,28 @@ def test_tpu_tick_updates_both_kinds_of_cache_in_place(hybrid_tick):
                    and "f32[16,64,64,128]" in c for c in calls)
     else:
         assert not calls and selects
+
+
+def test_tpu_tick_walks_the_hybrids_arenas_in_a_kernel(hybrid_tick):
+    """ISSUE 41: at `granite4h`'s published attention widths (32 query
+    heads over 8 key/value heads of 64, bfloat16) the GQA layer is the
+    paged kernel, two heads a lane tile (four pairs of 128 lanes, query
+    rows ``16 lanes x 8``: `trinity_mini`'s operand shape), with no
+    gathered ``[S, L, 8, 64]`` view and no ``[S, 8, 4, C, L]`` scores in
+    the program; the XLA form of the same op holds the scores and no such
+    call."""
+    form, compiled, _, _ = hybrid_tick
+    text = compiled.as_text()
+    calls = _per_head_calls(text)
+    views = [f"bf16[16,{MAX_LEN},{tail}]" for tail in ("512", "8,64")]
+    scores = f"f32[16,8,4,{BS},{MAX_LEN}]"
+    if form == "kernel":
+        assert len(calls) == 1                       # the one GQA layer
+        assert f"bf16[16,4,{8 * BS},128]" in calls[0] \
+            and f"bf16[{NB},{BS},512]" in calls[0]
+        assert not any(t in text for t in views + [scores])
+    else:
+        assert not calls and scores in text
 
 
 def test_cpu_tick_aliases_both_kinds_of_cache():

@@ -387,19 +387,41 @@ def _op_names(compiled):
 
 
 def test_the_serving_program_carries_its_scopes(model_and_params):
+    """Since ISSUE 41 the float arena is walked by
+    ``ops.attention.paged_gqa_attention`` under ``paged_attention`` (the
+    interpreter's kernel here): no ``kv_gather``; the op's XLA form, which
+    ``FORCE_XLA`` and a plain CPU drive take, gathers under that name."""
+    from apex_example_tpu.ops import _config as ops_config
+    from apex_example_tpu.serve import engine as engine_lib
     model, params = model_and_params
     eng = ServeEngine(model, params, num_slots=SLOTS, max_len=MAX_LEN,
                       rng=jax.random.PRNGKey(0))
     pool = eng.pool
-    compiled = _slot_step(pool.dec, eng.tick_args).lower(
-        params, pool.cache, jnp.asarray(eng.tick_args.blank(SLOTS)[0]),
-        jax.random.PRNGKey(0)).compile()
-    names = _op_names(compiled)
-    for scope in ("kv_cow", "kv_write", "kv_gather", "paged_attention"):
-        assert any(f"/attention/{scope}/" in n for n in names), scope
-    assert any("/sample/" in n for n in names)
+
+    def names():
+        engine_lib._slot_step.cache_clear()     # the form is read at trace
+        return _op_names(_slot_step(pool.dec, eng.tick_args).lower(
+            params, pool.cache, jnp.asarray(eng.tick_args.blank(SLOTS)[0]),
+            jax.random.PRNGKey(0)).compile())
+
+    def scopes(found):
+        return {scope for scope in ("kv_cow", "kv_write", "kv_gather",
+                                    "paged_attention")
+                if any("/attention/" in n and f"/{scope}/" in n
+                       for n in found)}
+
+    kernel = names()
+    assert scopes(kernel) == {"kv_cow", "kv_write", "paged_attention"}
+    assert any("/paged_attention/paged_gqa_attention/" in n for n in kernel)
+    assert any("/sample/" in n for n in kernel)
     # the output projection is the attention scope's, under its own name
-    assert any("/paged_attention/output/" in n for n in names)
+    assert any("/paged_attention/output/" in n for n in kernel)
+    with ops_config.force_xla():
+        xla = names()
+    engine_lib._slot_step.cache_clear()
+    assert scopes(xla) == {"kv_cow", "kv_write", "kv_gather",
+                           "paged_attention"}
+    assert any("/paged_attention/kv_gather/" in n for n in xla)
 
 
 def test_the_training_program_carries_its_scopes_and_moves_no_parameter():

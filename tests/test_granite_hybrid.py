@@ -185,6 +185,32 @@ def test_chunked_prefill_then_decode_gives_the_references_logits(
         assert (t["ssm_slots_advanced"] == (t["lanes_live"] > 0)).all()
 
 
+def test_both_forms_of_paged_attention_serve_the_same_tokens(
+        model, params, step_traced_with):
+    """ISSUE 41: the GQA layer reads its arenas through
+    ``ops.attention.paged_gqa_attention``.  Its kernel (the interpreter
+    here) walks each slot's live blocks and nothing of a slot without a
+    live lane; its XLA form every row of the table; both serve the same
+    tokens.  ``attn_positions_walked [attention layers, slots]`` says which
+    ran and how far."""
+    tokens, walked, lanes = {}, {}, {}
+    for form in ("kernel", "xla"):
+        with step_traced_with(xla=form == "xla"):
+            eng = _engine(model, params)
+            done = _run(eng, _requests([29, 5, 17, 20], [6, 9, 4, 7]))
+        tokens[form] = {u: list(c.tokens) for u, c in done.items()}
+        log = [jax.tree_util.tree_map(np.asarray, t)
+               for _, t in eng.counter_log]
+        walked[form] = np.stack([t["attn_positions_walked"] for t in log])
+        lanes[form] = np.stack([t["lanes_live"] for t in log])
+    assert tokens["kernel"] == tokens["xla"] and len(tokens["xla"]) == 4
+    assert walked["xla"].shape[1:] == (1, SLOTS)       # one GQA layer
+    assert (walked["xla"] == MAX_LEN).all()
+    k, live = walked["kernel"], lanes["kernel"] > 0
+    assert (k % BS == 0).all() and ((k > 0) == live).all()
+    assert BS <= k[live].mean() < MAX_LEN / 2
+
+
 def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(model, params):
     """One slot, two requests one after the other: the second reads what a
     fresh engine reads, bit for bit (the first one's state and rows are

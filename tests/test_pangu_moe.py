@@ -614,12 +614,14 @@ def _other(name):
 # slots x 64, blocks of 8, under the tests' interpreter).  A PR that changes
 # one of these models' tick on purpose replaces its line: PR 39 did, for all
 # three (the step takes one packed array and a key, ``engine.TickArgs``, where
-# it took nine arrays; what it computes from them is what PR 36 pinned).  A
-# PR that only adds a model or an engine path beside them must not.
+# it took nine arrays; what it computes from them is what PR 36 pinned), and
+# PR 41 for "gpt1" and "granite" (their paged attention became
+# ``ops.attention.paged_gqa_attention``; "xing4" is PR 39's).  A PR that only
+# adds a model or an engine path beside them must not.
 TICK_SINCE_PR39 = {
-    "gpt1": "3d3e68c6aefad0b5cf85da83646e6365d42ed4f589d5cbf493e9fd48110a0566",
+    "gpt1": "d30b7b180b9438e05e4eb0e2f39eb2e238c162146865677faf5a30a0e77e0c64",
     "xing4": "d26034df746a79fc3359ee9beda6887b49a3f4c3282c6faa33712ad8e5b76b5f",
-    "granite": "14236cb44a18a4d62899c5ad0400c5741454dafcbe21663f47f62e78b98d5736",
+    "granite": "0670df7e1cda5c43df44cc7c5f5cc8bf92accefd8c93eaa9f25bce2b88a615bf",
 }
 
 

@@ -1158,3 +1158,48 @@ def test_a_model_without_packed_lanes_keeps_its_marshal_and_its_program(
     assert max(seen) == SLOTS         # every slot fed a chunk in one tick
     assert eng.prefill_chunks_deferred == 0
     assert "prefill_chunks_deferred" not in eng.summary_record()
+
+
+# ----------------------- ISSUE 41: paged attention through one op, two forms
+
+def test_both_forms_of_paged_attention_serve_the_same_tokens(
+        model_and_params, step_traced_with):
+    """The float arena is read by ``ops.attention.paged_gqa_attention``:
+    its kernel (the interpreter here, Mosaic on the TPU) and its XLA gather
+    form (``FORCE_XLA``, a plain CPU drive) serve the same tokens."""
+    model, params = model_and_params
+    tokens = {}
+    for form in ("kernel", "xla"):
+        with step_traced_with(xla=form == "xla"):
+            _, comps = _run_engine(model, params, synthetic_requests(
+                6, vocab_size=model.vocab_size, seed=5, prompt_len=(3, 12),
+                max_new=(3, 10), stagger=2))
+        tokens[form] = {tuple(c.request.prompt): list(c.tokens)
+                        for c in comps}
+        assert {c.status for c in comps} == {"ok"}
+    assert tokens["kernel"] == tokens["xla"] and len(tokens["xla"]) == 6
+
+
+@pytest.mark.parametrize("held,kernel_calls", [
+    ({}, 2), ({"kv_quant": True}, 0), ({"tensor_parallel": True}, 0)],
+    ids=["float", "kv_quant", "tensor_parallel"])
+def test_the_arena_the_module_holds_chooses_the_form(held, kernel_calls):
+    """A float arena held whole: the op's kernel once a layer.  int8 rows
+    with a scale table and heads sharded over 'model' keep the gathered
+    form (no kernel in the traced tick), whatever the dispatch allows."""
+    from apex_example_tpu.ops import _config as ops_config
+    assert ops_config.use_pallas()
+    tp = {k: v for k, v in held.items() if k == "tensor_parallel"}
+    dec = gpt_tiny(**tp).clone(
+        decode=True, slot_decode=True, fused_attention=False,
+        kv_num_blocks=SLOTS * 4, kv_block_size=8,
+        kv_quant=held.get("kv_quant", False))
+    tok = jnp.zeros((SLOTS, 8), jnp.int32)
+    variables = jax.eval_shape(dec.init, jax.random.PRNGKey(0),
+                               jnp.zeros((SLOTS, MAX_LEN), jnp.int32))
+    zeros = jnp.zeros((SLOTS,), jnp.int32)
+    paged = dict(block_table=jnp.zeros((SLOTS, 4), jnp.int32), fill=zeros,
+                 n_new=zeros + 1, cow_src=zeros, cow_dst=zeros - 1)
+    text = str(jax.make_jaxpr(lambda v: dec.apply(
+        v, tok, train=False, paged=paged, mutable=["cache"]))(variables))
+    assert text.count("name=_paged_gqa_pallas") == kernel_calls

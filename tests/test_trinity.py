@@ -416,6 +416,85 @@ def test_kernel_matches_its_reference(window, pages):
         assert not np.asarray(got)[dead].any()
 
 
+def _poisoned(args):
+    """``args`` with every arena row no slot's walk may see made NaN: the
+    tail of a slot's last block, blocks no table names."""
+    q, k, v, table, fill, n_new = args
+    live = np.zeros(k.shape[:2], bool)
+    for s, total in enumerate(np.asarray(fill + n_new)):
+        if n_new[s]:
+            for b in range(-(-int(total) // BS)):
+                live[int(table[s, b]), :min(BS, int(total) - b * BS)] = True
+    nan = lambda a: jnp.where(jnp.asarray(live)[..., None], a, jnp.nan)
+    return (q, nan(k), nan(v), table, fill, n_new)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("Hq,Hk", [(8, 2), (4, 4)])
+def test_kernel_takes_a_pair_of_64_wide_heads_a_lane_tile(Hq, Hk, dtype):
+    """ISSUE 41: at ``hd = 64`` two adjacent key/value heads are one head
+    of 128 to the kernel (groups of 4 as `granite4h`'s, of 1 as GPT-1's;
+    bfloat16 and float32 arenas): random fills and lanes, a dead slot, a
+    first chunk, tiles that end inside the walk, stale rows NaN; against
+    the XLA form on the clean arenas, ``walked`` by hand."""
+    args, _, walked, n_new = _kernel_case(5, None, Hq=Hq, Hk=Hk, hd=64)
+    args = tuple(a.astype(dtype) if a.dtype == jnp.float32 else a
+                 for a in args)
+    want, _ = attention.paged_gqa_attention_reference(*args, 0.125)
+    got, read = attention._paged_gqa_pallas(
+        *_poisoned(args), 0.125, None, None, True, pages=3)
+    assert got.dtype == dtype and got.shape == args[0].shape
+    # one rounding of a result of magnitude < 4, or float32's summation
+    bound = 2.0 ** -6 if dtype == jnp.bfloat16 else 1e-5
+    err = jnp.abs(want.astype(jnp.float32) - got.astype(jnp.float32))
+    assert float(jnp.max(err)) <= bound
+    assert list(np.asarray(read)) == walked
+    dead = np.arange(got.shape[1])[None, :] >= n_new[:, None]
+    assert not np.asarray(got.astype(jnp.float32))[dead].any()
+
+
+@pytest.mark.parametrize("hd,Hk,ok", [(64, 8, True), (64, 12, True),
+                                      (64, 3, False), (128, 4, True),
+                                      (96, 4, False)])
+def test_mosaic_takes_a_head_or_a_pair_of_heads_a_whole_lane_tile(
+        hd, Hk, ok, monkeypatch):
+    """The rule the chip's dispatch reads, read here without a chip (the
+    interpreter takes any shape): ``hd % 128 == 0``, or ``hd == 64`` with
+    an even number of key/value heads."""
+    monkeypatch.setattr(ops_config, "INTERPRET", False)
+    monkeypatch.setattr(ops_config, "use_pallas", lambda: True)
+    sds = jax.ShapeDtypeStruct
+    for dtype in (jnp.bfloat16, jnp.float32):
+        assert attention._paged_gqa_ok(
+            sds((4, 16, 2 * Hk, hd), dtype),
+            sds((8, 16, Hk * hd), dtype)) is ok
+
+
+def test_at_128_wide_heads_the_launcher_hands_the_kernel_what_it_did():
+    """`trinity_mini`'s path: at ``hd = 128`` nothing is paired, the
+    kernel's operands are the parent's (PR 40), shape for shape."""
+    S, C, cols = 4, 16, 8
+    i32, bf = jnp.int32, jnp.bfloat16
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda *a: attention._paged_gqa_pallas(
+        *a, 0.1, None, None, True))(
+            sds((S, C, 32, 128), bf), sds((S * cols, 16, 512), bf),
+            sds((S * cols, 16, 512), bf), sds((S, cols), i32),
+            sds((S,), i32), sds((S,), i32))
+    inner = jaxpr.jaxpr.eqns[0].params["jaxpr"].jaxpr
+    calls = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in calls[0].invars] == [
+        ((S * cols,), "int32"), ((S,), "int32"), ((S,), "int32"),
+        ((S, 4, C * 8, 128), "bfloat16"), ((S * cols, 16, 512), "bfloat16"),
+        ((S * cols, 16, 512), "bfloat16")]
+    assert [v.aval.shape for v in calls[0].outvars] == [
+        (S, 4, C * 8, 128), (S,)]
+    assert not any(e.primitive.name in ("select_n", "concatenate")
+                   for e in inner.eqns)
+
+
 def test_the_op_takes_the_kernel_here_and_the_xla_form_when_forced():
     args, ring, walked, _ = _kernel_case(0, 8)
     _, read = attention.paged_gqa_attention(*args, scale=0.25, window=8,
