@@ -207,7 +207,8 @@ class SwiGLU(nn.Module):
 
 class RoutedExperts(nn.Module):
     """Dropless top-k of ``n_experts`` on sigmoid scores with a
-    selection-only bias, plus one shared expert.  ``experts_held = (first,
+    selection-only bias, plus ``n_shared`` shared experts as one SwiGLU of
+    ``n_shared * width`` (none at 0).  ``experts_held = (first,
     count)``: the routed experts whose weights live here; the router always
     has its ``n_experts`` outputs, and what the other experts would add is
     left out (another chip's share).  Returns ``(y, load, visits)``: ``load
@@ -223,6 +224,7 @@ class RoutedExperts(nn.Module):
     experts_held: Tuple[int, int]
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.bfloat16
+    n_shared: int = 1
 
     @nn.compact
     def __call__(self, x, live=None):
@@ -245,9 +247,10 @@ class RoutedExperts(nn.Module):
         if visits is not None:
             first = self.experts_held[0]
             visits = jnp.pad(visits, (first, E - first - count))
-        with device_span("shared_expert"):
-            y = y + SwiGLU(d, f, self.dtype, self.param_dtype,
-                           name="shared")(flat)
+        if self.n_shared:
+            with device_span("shared_expert"):
+                y = y + SwiGLU(d, f * self.n_shared, self.dtype,
+                               self.param_dtype, name="shared")(flat)
         load = expert_load(idx, E, live)
         return y.reshape(x.shape), load, visits
 

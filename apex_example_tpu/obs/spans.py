@@ -44,7 +44,7 @@ from apex_example_tpu.obs import trace as trace_lib
 # engine.make_train_step, the loss functions of workloads.py, the models'
 # heads, ops/paged_cache.py (kv_cow, kv_write, kv_gather), the paged branch
 # of models/bert.py, models/xing4.py, models/granite_hybrid.py,
-# models/pangu_moe.py, models/trinity.py, ops/lane_pack.py,
+# models/pangu_moe.py, models/trinity.py, models/lfm2.py, ops/lane_pack.py,
 # ops/attention.py (paged_gqa_attention),
 # the dropless layer of
 # transformer/expert_parallel.py and serve/engine._slot_step.
@@ -85,6 +85,8 @@ PHASES = (
     "sandwich_norm",    # device: models/pangu_moe.py, the four norms a layer
     "mtp",              # device: its next-token module whole (layer + head)
     "draft_verify",     # device: serve step, compare-and-select after the head
+    "short_conv",       # device: models/lfm2.py, a gated short convolution whole
+    "dense_mlp",        # device: models/lfm2.py, the leading layers' dense SwiGLU
 )
 
 device_span = jax.named_scope

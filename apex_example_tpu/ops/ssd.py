@@ -295,7 +295,8 @@ def causal_conv(rows, x, w, b, n_new, reset=None):
     """Depthwise causal convolution of width ``K`` over ``x`` [S,L,ch]
     behind the ``K - 1`` rows kept from before (``rows`` [S,K-1,ch], zeros
     at a sequence's start): ``out_t = b + sum_k w[k] x_{t-K+1+k}``, float32.
-    ``w`` [K,ch], ``b`` [ch].  The live lanes of a sequence are its first
+    ``w`` [K,ch], ``b`` [ch] or None (no bias).  The live lanes of a
+    sequence are its first
     ``n_new[s]``; returns ``(out [S,L,ch], the last K - 1 live rows)``, the
     rows unchanged, bit for bit, where ``n_new`` is 0."""
     K, L = w.shape[0], x.shape[1]
@@ -304,8 +305,10 @@ def causal_conv(rows, x, w, b, n_new, reset=None):
                          rows)
     window = jnp.concatenate([rows, x.astype(rows.dtype)], axis=1)
     wf = w.astype(F32)
-    out = b.astype(F32) + sum(wf[k] * window[:, k:k + L].astype(F32)
-                              for k in range(K))
+    bf = None if b is None else b.astype(F32)
+    out = sum(wf[k] * window[:, k:k + L].astype(F32) for k in range(K))
+    if bf is not None:
+        out = bf + out
     idx = n_new[:, None] + jnp.arange(K - 1)[None, :]
     kept = jnp.take_along_axis(window, idx[..., None], axis=1)
     return out, kept

@@ -144,7 +144,8 @@ def build_parser() -> argparse.ArgumentParser:
                             "xing4_29b_a4b_cut", "granite_hybrid_tiny",
                             "granite_4_0_h_micro", "pangu_moe_tiny",
                             "openpangu_ultra_moe_718b_cut", "trinity_tiny",
-                            "trinity_mini_cut"],
+                            "trinity_mini_cut", "lfm2_tiny",
+                            "lfm2_8b_a1b_cut"],
                    help="gpt_*: the post-LN decoder (float32). xing4_*: "
                         "latent attention, dropless experts, hyper-"
                         "connected residual (models/xing4.py): the tiny "
@@ -182,7 +183,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "are handed back while a request runs; no prefix "
                         "is shared, and --kv-quant, --speculate, --role "
                         "prefill|decode and a model-sharded --mesh are "
-                        "refused")
+                        "refused. lfm2_*: gated short-convolution layers "
+                        "whose two kept rows live per slot beside the "
+                        "paged K/V of QK-normed rotary GQA layers, 32 "
+                        "sigmoid-routed experts and no shared one "
+                        "(models/lfm2.py): the tiny preset in float32, "
+                        "LFM2-8B-A1B's published widths cut to 13 layers "
+                        "in bfloat16; no prefix is shared, and "
+                        "--speculate, --kv-quant and a model-sharded "
+                        "--mesh are refused")
     p.add_argument("--checkpoint-dir", default=None,
                    help="CheckpointManager directory to restore params "
                         "from (omit = random init, smoke mode)")
@@ -624,6 +633,7 @@ def run_serve(args):
     from apex_example_tpu.models.gpt import gpt_base, gpt_tiny
     from apex_example_tpu.models.granite_hybrid import (granite_4_0_h_micro,
                                                         granite_hybrid_tiny)
+    from apex_example_tpu.models.lfm2 import lfm2_8b_a1b_cut, lfm2_tiny
     from apex_example_tpu.models.pangu_moe import (
         openpangu_ultra_moe_718b_cut, pangu_moe_tiny)
     from apex_example_tpu.models.trinity import (trinity_mini_cut,
@@ -666,6 +676,8 @@ def run_serve(args):
              "openpangu_ultra_moe_718b_cut": openpangu_ultra_moe_718b_cut,
              "trinity_tiny": trinity_tiny,
              "trinity_mini_cut": trinity_mini_cut,
+             "lfm2_tiny": lfm2_tiny,
+             "lfm2_8b_a1b_cut": lfm2_8b_a1b_cut,
              }[args.arch](tensor_parallel=tp > 1)
     max_len = args.max_len
     if max_len is None:
