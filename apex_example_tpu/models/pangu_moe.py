@@ -27,15 +27,28 @@ program; the serving contract is otherwise ``models/xing4.py``'s).  With
 ``paged["n_draft"]`` the paged forward returns ``(logits, hidden)``: the
 head on each slot's ``K + 1`` *verify lanes* (``[SLOTS, K + 1, V]``: the
 lane before the slot's draft lanes and those, or its sampled lane twice
-where it fed no draft) and the normed hidden state of every lane.  The
-engine samples and verifies, and calls again with ``draft_from = (hidden,
-next_ids, lane)``: the module alone over every lane, writing its leaf at
-the same positions through the same block table, and its logits at
-``lane`` ``[SLOTS, V]``: the next draft.  Without ``n_draft`` (an engine
-built with ``speculate=0``) the head runs on the sampled lane and the
-module does not run.
+where it fed no draft) and the normed hidden state of every lane, as the
+tick's packed rows ``[R, d]`` (below).  The engine samples and verifies, and
+calls again with ``draft_from = (hidden, next_ids, lane)``: the module
+alone over every lane (``next_ids [SLOTS, C]`` packed onto the same rows),
+writing its leaf at the same positions through the same block table, and
+its logits at ``lane`` ``[SLOTS, V]``: the next draft.  Without ``n_draft``
+(an engine built with ``speculate=0``) the head runs on the sampled lane
+and the module does not run.
 
-Counters (``counters`` collection): ``expert_load [expert layers, E]``,
+**Packed lanes** (``packed_lanes = True``, ``lane_head = 1 +
+num_nextn_predict_layers``; ``ops/lane_pack.py``, PR 44).  Both calls run
+everything token-wise on the tick's live lanes as ``lane_pack.rows(SLOTS,
+C, 2)`` dense rows, as ``models/xing4.py`` does with a head of one: a
+decoding slot's two lanes ``[t_p, d]`` (and a prompt's last two tokens) sit
+in the rows' head, a longer chunk takes a group.  ONE ``LaneMap`` a call;
+the verify lanes and the draft's lane are read at ``LaneMap.row_of``.  A
+subclass with ``packed_lanes = False`` (the tests') is the ``[SLOTS, C]``
+program, whose ``hidden`` is ``[SLOTS, C, d]``.
+
+Counters (``counters`` collection): ``lanes_live [1, SLOTS]`` and
+``rows_dense [1, 1]`` (the first call's; how full its rows were),
+``expert_load [expert layers, E]``,
 ``expert_load_held [expert layers, count]`` (the same over the experts
 held here), ``expert_weight_visits``, ``attn_positions_walked``; the
 module's call sows its one layer's rows under the same names and the
@@ -59,6 +72,7 @@ from apex_example_tpu.models.xing4 import (LatentAttention, RoutedExperts,
                                            SwiGLU, _fan_in, matmul_f32,
                                            rms_norm)
 from apex_example_tpu.obs.spans import device_span
+from apex_example_tpu.ops import lane_pack
 
 
 class PanguLayer(nn.Module):
@@ -70,7 +84,9 @@ class PanguLayer(nn.Module):
     dense: bool
 
     @nn.compact
-    def __call__(self, x, pos, paged, live):
+    def __call__(self, x, pos, paged, live, lanes=None):
+        """``x [B, L, d]``, or with ``lanes`` the tick's packed rows ``[R,
+        d]`` (``live`` then ``[R]``); ``pos [B, L]`` either way."""
         c = dict(self.cfg)
         d, eps = c["hidden_size"], c["rms_norm_eps"]
         dtype, pd = c["dtype"], c["param_dtype"]
@@ -89,7 +105,7 @@ class PanguLayer(nn.Module):
             c["v_head_dim"], c["q_lora_rank"], c["kv_lora_rank"], eps, rope,
             dtype, pd, c["decode"], c["slot_decode"], c["kv_num_blocks"],
             c["kv_block_size"], name="attn")(
-                norm("attn_norm", x), pos, paged)
+                norm("attn_norm", x), pos, paged, lanes)
         u = x + norm("attn_post_norm", y)
         h = norm("ffn_norm", u)
         load = visits = None
@@ -113,7 +129,7 @@ class NextTokenModule(nn.Module):
     cfg: Tuple[Tuple[str, object], ...]
 
     @nn.compact
-    def __call__(self, h, e, pos, paged, live):
+    def __call__(self, h, e, pos, paged, live, lanes=None):
         c = dict(self.cfg)
         d, eps, pd = c["hidden_size"], c["rms_norm_eps"], c["param_dtype"]
         scale = lambda name: self.param(name, nn.initializers.ones, (d,), pd)
@@ -123,7 +139,7 @@ class NextTokenModule(nn.Module):
         m = matmul_f32(both, self.param("eh_proj", _fan_in(2 * d),
                                         (2 * d, d), pd)).astype(c["dtype"])
         z, load, visits, walked = PanguLayer(self.cfg, False, name="block")(
-            m, pos, paged, live)
+            m, pos, paged, live, lanes)
         with device_span("sandwich_norm"):
             return rms_norm(z, scale("norm"), eps), load, visits, walked
 
@@ -170,6 +186,15 @@ class PanguMoEForCausalLM(nn.Module):
     # the paged head runs on a slot's verify lanes only: the engine can
     # verify this model's own drafts and no host proposer's
     all_lane_logits = False
+    # the paged program's token-wise sublayers take the tick's live lanes
+    # as lane_pack.rows(SLOTS, C, lane_head) dense rows, a slot's first
+    # ``lane_head`` lanes (the fed token and its drafts) outside any group:
+    # the engine budgets to that
+    packed_lanes = True
+
+    @property
+    def lane_head(self) -> int:
+        return 1 + self.num_nextn_predict_layers
 
     def __post_init__(self):
         if self.experts_held is not None:
@@ -196,10 +221,14 @@ class PanguMoEForCausalLM(nn.Module):
                     if f not in ("parent", "name"))
         B, L = input_ids.shape
         pos = jnp.broadcast_to(jnp.arange(L)[None, :], (B, L))
-        live = None
+        live = lanes = None
         if paged is not None:
             pos = paged["fill"][:, None] + pos
             live = jnp.arange(L)[None, :] < paged["n_new"][:, None]
+            if self.packed_lanes:
+                # ONE map a call: every layer packs and unpacks through it
+                lanes = lane_pack.LaneMap(paged["n_new"], L, self.lane_head)
+                live = lanes.row_live
         embed = self.param("embed", nn.initializers.normal(1.0),
                            (self.vocab_size, d), self.param_dtype)
         head = self.param("head", _fan_in(d), (d, self.vocab_size),
@@ -211,13 +240,31 @@ class PanguMoEForCausalLM(nn.Module):
                 if row is not None:
                     rows.append(row)
 
+        def embedded(ids):
+            """``E[ids]``, of the tick's packed rows where it has a map
+            (a dead row holds zeros from here on: lane_pack's promise)."""
+            if lanes is None:
+                return embed[ids].astype(self.dtype)
+            return jnp.where(live[:, None],
+                             embed[lanes.pack(ids)].astype(self.dtype), 0)
+
         def module(h, next_ids):
-            """The next-token module over every position."""
+            """The next-token module over every position (every row)."""
             with device_span("mtp"):
                 z, *rows = NextTokenModule(cfg, name="mtp")(
-                    h, embed[next_ids].astype(self.dtype), pos, paged, live)
+                    h, embedded(next_ids), pos, paged, live, lanes)
             count(*rows)
             return z
+
+        def at_lanes(rows, lane):
+            """``rows`` (``[S, C, ...]``, or packed ``[R, ...]``) at lanes
+            ``lane [S, K]`` of each slot: ``[S, K, ...]``."""
+            if lanes is None:
+                return jnp.take_along_axis(rows, lane.reshape(
+                    lane.shape + (1,) * (rows.ndim - 2)), axis=1)
+            fed = paged["n_new"] > 0
+            return jnp.where(fed.reshape((-1,) + (1,) * rows.ndim),
+                             rows[lanes.row_of(lane)], 0)
 
         if draft_from is not None:
             # the tick's second call: the module alone, and its head on
@@ -225,13 +272,13 @@ class PanguMoEForCausalLM(nn.Module):
             h, next_ids, lane = draft_from
             z = module(h, next_ids)
             with device_span("mtp"):
-                z = jnp.take_along_axis(z, lane[:, None, None], axis=1)
-                out = matmul_f32(z[:, 0], head)
+                out = matmul_f32(at_lanes(z, lane[:, None])[:, 0], head)
         else:
-            x = embed[input_ids].astype(self.dtype)
+            x = embedded(input_ids)
             for i in range(self.num_layers):
-                x, *rows = PanguLayer(cfg, i < self.first_k_dense,
-                                      name=f"layer_{i}")(x, pos, paged, live)
+                x, *rows = PanguLayer(
+                    cfg, i < self.first_k_dense, name=f"layer_{i}")(
+                        x, pos, paged, live, lanes)
                 count(*rows)
             final = self.param("final_norm", nn.initializers.ones, (d,),
                                self.param_dtype)
@@ -250,17 +297,24 @@ class PanguMoEForCausalLM(nn.Module):
                 # the head on each slot's verify lanes
                 K = self.num_nextn_predict_layers
                 last = jnp.maximum(paged["n_new"] - 1, 0)
-                lanes = jnp.clip(
+                verify = jnp.clip(
                     (last - paged["n_draft"])[:, None] + jnp.arange(K + 1),
                     0, last[:, None])
                 h = rms_norm(x, final, eps)
-                out = matmul_f32(jnp.take_along_axis(
-                    h, lanes[:, :, None], axis=1), head), h
+                out = matmul_f32(at_lanes(h, verify), head), h
             else:
                 # the head on each slot's sampled lane only
                 lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)
-                x = jnp.take_along_axis(x, lane[:, None, None], axis=1)
-                out = matmul_f32(rms_norm(x, final, eps), head)
+                out = matmul_f32(rms_norm(at_lanes(x, lane[:, None]), final,
+                                          eps), head)
+        keep = dict(reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        if paged is not None and draft_from is None:
+            # how full the rows of the token-wise products were this tick
+            # (the module's call runs on the same rows: said once)
+            self.sow("counters", "lanes_live", paged["n_new"][None, :],
+                     **keep)
+            self.sow("counters", "rows_dense", jnp.full(
+                (1, 1), live.size, jnp.int32), **keep)
         first, held = self.experts_held or (0, self.n_routed_experts)
         loads, visits, walks = counted
         for name, rows in (("expert_load", loads),
@@ -269,8 +323,7 @@ class PanguMoEForCausalLM(nn.Module):
                            ("expert_weight_visits", visits),
                            ("attn_positions_walked", walks)):
             if rows:
-                self.sow("counters", name, jnp.stack(rows),
-                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
+                self.sow("counters", name, jnp.stack(rows), **keep)
         return out
 
 

@@ -44,6 +44,23 @@ model sows what either read, ``attn_positions_walked [layers, S]``, beside
 products ran in their kernel (``ops/grouped_matmul.py``; the XLA form,
 ``lax.ragged_dot``, counts nothing).
 
+**Packed lanes** (``packed_lanes = True``; ``ops/lane_pack.py``, PR 44).  The
+paged program carries the tick's *live* lanes as dense rows ``[R, n, d]``,
+``R = lane_pack.rows(SLOTS, C)`` static (256 of a ``64 x 16`` tick): ONE
+``LaneMap`` a call, built from ``n_new``; the token ids are packed before
+the embedding, and the hyper-connection units, norms, projections, the
+rotation, the absorb and un-absorb products, ``w_o``, the dense and shared
+SwiGLUs, the router, dispatch and combine all run on rows (``RoutedExperts``
+takes the map's ``row_live`` as its ``live``).  The latents go to the arena
+straight from their rows (their ``[S, C]`` destinations packed with them, a
+dead row's dropping); only the paged kernel sees ``[SLOTS, C, H, W]``, the
+absorbed queries unpacked in front of it and its output packed behind it.
+Dead rows hold zeros, are selected away and never multiplied in.  The
+engine budgets prefill chunks to the rows' groups; ``lanes_live [1, S]`` and
+``rows_dense [1, 1]`` in ``counters`` say how full the rows were.  The plain
+forward and the init trace know nothing of it; a subclass with
+``packed_lanes = False`` (the tests') is the ``[SLOTS, C]`` program.
+
 In the paged path the vocabulary head runs on each slot's *sampled lane*
 only (``[SLOTS, 1, V]`` logits): at a vocabulary of 131072 the all-lane
 head would be half a gigabyte of logits a tick for 64 used rows.  The
@@ -68,7 +85,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_example_tpu.obs.spans import device_span
-from apex_example_tpu.ops import paged_cache
+from apex_example_tpu.ops import lane_pack, paged_cache
 from apex_example_tpu.ops.attention import paged_latent_attention
 from apex_example_tpu.transformer.expert_parallel import (dropless_experts,
                                                           dropless_route,
@@ -278,21 +295,27 @@ class LatentAttention(nn.Module):
     kv_block_size: int = 0
 
     def _rotate(self, x, pos):
-        """x [B, L, (H,) dr] at positions pos [B, L], float32 inside."""
+        """x [B, L, (H,) dr] at positions pos [B, L] (or rows ``[R, (H,)
+        dr]`` at ``[R]``), float32 inside."""
         theta, factor, orig, fast, slow, ms, ms_all = self.rope
         inv = yarn_inv_freq(self.qk_rope_head_dim, theta, factor, int(orig),
                             fast, slow)
         ang = pos.astype(F32)[..., None] * inv
         m = yarn_mscale(factor, ms) / yarn_mscale(factor, ms_all)
         cos, sin = jnp.cos(ang) * m, jnp.sin(ang) * m
-        if x.ndim == 4:
-            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        if x.ndim == pos.ndim + 2:
+            cos, sin = cos[..., None, :], sin[..., None, :]
         a, b = jnp.split(x.astype(F32), 2, axis=-1)
         return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                                -1).astype(x.dtype)
 
     @nn.compact
-    def __call__(self, x, pos, paged=None):
+    def __call__(self, x, pos, paged=None, lanes=None):
+        """``x`` is ``[B, L, d]`` at ``pos [B, L]``, or with ``lanes`` (a
+        ``lane_pack.LaneMap``, paged path only) the tick's packed rows
+        ``[R, d]``: everything but the paged kernel runs on what it is
+        given, the latents go to the arena from their rows, the kernel
+        sees the absorbed queries as ``[S, C, H, W]``."""
         d, H = self.hidden_size, self.num_heads
         dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
@@ -311,13 +334,15 @@ class LatentAttention(nn.Module):
         mm = lambda a, w: matmul_f32(a, w).astype(self.dtype)
         ein = lambda spec, a, b: einsum_f32(spec, a, b)
 
-        B, L = x.shape[:2]
+        B, L = pos.shape
+        lead = x.shape[:-1]                             # [B, L], or [R]
+        at = pos if lanes is None else lanes.pack(pos)
         cq = rms_norm(mm(x, w_dq), q_norm, eps)
-        q = mm(cq, w_uq).reshape(B, L, H, dn + dr)
-        q_nope, q_rope = q[..., :dn], self._rotate(q[..., dn:], pos)
+        q = mm(cq, w_uq).reshape(*lead, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], self._rotate(q[..., dn:], at)
         ckr = mm(x, w_dkv)
         ckv = rms_norm(ckr[..., :kr], kv_norm, eps)
-        k_rope = self._rotate(ckr[..., kr:], pos)       # one key, all heads
+        k_rope = self._rotate(ckr[..., kr:], at)        # one key, all heads
 
         if self.decode:
             if not self.slot_decode:
@@ -342,19 +367,25 @@ class LatentAttention(nn.Module):
                 cl.value = paged_cache.cow(cl.value, paged["cow_src"],
                                            paged["cow_dst"])
                 flat = paged_cache.write_rows(table, pos, n_new, NB, BS)
+                if lanes is not None:
+                    # the latents are packed rows: so are their places in
+                    # the arena (a dead row drops)
+                    flat = lanes.pack(flat.reshape(S, C), fill=NB * BS)
                 with device_span("kv_write"):
                     lat = jnp.concatenate(
                         [ckv, k_rope,
-                         jnp.zeros((S, C, W - kr - dr), self.dtype)], -1)
+                         jnp.zeros(lead + (W - kr - dr,), self.dtype)], -1)
                 cl.value = paged_cache.write(cl.value, flat, lat)
                 with device_span("latent_attention"):
                     # absorbed: queries into the latent space, scores and
                     # the weighted sum against the cached latents, out
                     # through W_UV — the cache is never up-projected
                     qf = jnp.concatenate(
-                        [ein("schd,rhd->schr", q_nope, w_uk).astype(
+                        [ein("...hd,rhd->...hr", q_nope, w_uk).astype(
                             self.dtype), q_rope,
-                         jnp.zeros((S, C, H, W - kr - dr), self.dtype)], -1)
+                         jnp.zeros(lead + (H, W - kr - dr), self.dtype)], -1)
+                if lanes is not None:
+                    qf = lanes.unpack(qf)               # [S, C, H, W]
                 # scores, mask, softmax and weighted sum.  On the TPU one
                 # Pallas call that walks each slot's live blocks where
                 # they lie in the arena; on the CPU and under FORCE_XLA
@@ -364,9 +395,11 @@ class LatentAttention(nn.Module):
                 ol, walked = paged_latent_attention(
                     qf, cl.value, table, paged["fill"], n_new, scale=scale,
                     kr=kr)
+                if lanes is not None:
+                    ol = lanes.pack(ol)                 # [R, H, kr]
                 with device_span("latent_attention"):
-                    o = ein("schr,rhd->schd", ol, w_uv).astype(self.dtype)
-                    return mm(o.reshape(S, C, H * dv), w_o), walked
+                    o = ein("...hr,rhd->...hd", ol, w_uv).astype(self.dtype)
+                    return mm(o.reshape(*lead, H * dv), w_o), walked
             # init trace on the [B, max_len] dummy: the cache is allocated
             # above; fall through so that params and shapes initialize.
         with device_span("latent_attention"):
@@ -390,7 +423,9 @@ class Xing4Layer(nn.Module):
     dense: bool
 
     @nn.compact
-    def __call__(self, X, pos, paged, live):
+    def __call__(self, X, pos, paged, live, lanes=None):
+        """``X [B, L, n, d]``, or with ``lanes`` the tick's packed rows
+        ``[R, n, d]`` (``live`` then ``[R]``); ``pos [B, L]`` either way."""
         c = dict(self.cfg)
         d, eps = c["hidden_size"], c["rms_norm_eps"]
         dtype, pd = c["dtype"], c["param_dtype"]
@@ -409,7 +444,7 @@ class Xing4Layer(nn.Module):
             c["v_head_dim"], c["q_lora_rank"], c["kv_lora_rank"], eps, rope,
             dtype, pd, c["decode"], c["slot_decode"], c["kv_num_blocks"],
             c["kv_block_size"], name="attn")(
-                rms_norm(u, norm("attn_norm"), eps), pos, paged)
+                rms_norm(u, norm("attn_norm"), eps), pos, paged, lanes)
         X = hc.mix_out(X, y, coeff)
         hc = hyper("ffn_hc")
         u, coeff = hc.mix_in(X)
@@ -476,6 +511,9 @@ class Xing4ForCausalLM(nn.Module):
     # the paged head runs on the sampled lane only, so the engine cannot
     # verify draft lanes against this model (serve/engine.py)
     all_lane_logits = False
+    # the paged program's token-wise sublayers take the tick's live lanes
+    # as lane_pack.rows(SLOTS, C) dense rows: the engine budgets to that
+    packed_lanes = True
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, paged=None):
@@ -490,21 +528,28 @@ class Xing4ForCausalLM(nn.Module):
                     if f not in ("parent", "name"))
         B, L = input_ids.shape
         pos = jnp.broadcast_to(jnp.arange(L)[None, :], (B, L))
-        live = None
+        live = lanes = None
         if paged is not None:
             # positions come from the host's per-slot fill levels; rotary
             # positions need no table, so nothing clips
             pos = paged["fill"][:, None] + pos
             live = jnp.arange(L)[None, :] < paged["n_new"][:, None]
+            if self.packed_lanes:
+                # ONE map a call: every layer packs and unpacks through it
+                lanes = lane_pack.LaneMap(paged["n_new"], L)
+                input_ids, live = lanes.pack(input_ids), lanes.row_live
         embed = self.param("embed", nn.initializers.normal(1.0),
                            (self.vocab_size, d), self.param_dtype)
         x = embed[input_ids].astype(self.dtype)
-        X = jnp.repeat(x[:, :, None, :], self.hc_mult, axis=2)  # [B,L,n,d]
+        if lanes is not None:
+            # a dead row holds zeros from here on (lane_pack's promise)
+            x = jnp.where(live[:, None], x, 0)
+        X = jnp.repeat(x[..., None, :], self.hc_mult, axis=-2)  # [..,n,d]
         loads, visits, walks = [], [], []
         for i in range(self.num_layers):
             X, load, visited, walked = Xing4Layer(
                 cfg, i < self.first_k_dense, name=f"layer_{i}")(
-                    X, pos, paged, live)
+                    X, pos, paged, live, lanes)
             for rows, row in ((loads, load), (visits, visited),
                               (walks, walked)):
                 if row is not None:
@@ -516,16 +561,25 @@ class Xing4ForCausalLM(nn.Module):
         # positions paged attention read for each slot [layers, S] — read
         # by the engine when the "counters" collection is mutable, dropped
         # otherwise
+        keep = dict(reduce_fn=lambda _, new: new, init_fn=lambda: None)
         for name, rows in (("expert_load", loads),
                            ("expert_weight_visits", visits),
                            ("attn_positions_walked", walks)):
             if rows:
-                self.sow("counters", name, jnp.stack(rows),
-                         reduce_fn=lambda _, new: new, init_fn=lambda: None)
+                self.sow("counters", name, jnp.stack(rows), **keep)
         if paged is not None:
+            # how full the rows of the token-wise products were this tick
+            self.sow("counters", "lanes_live", paged["n_new"][None, :],
+                     **keep)
+            self.sow("counters", "rows_dense", jnp.full(
+                (1, 1), x.size // d, jnp.int32), **keep)
             # the head on each slot's sampled lane only
-            lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)
-            X = jnp.take_along_axis(X, lane[:, None, None, None], axis=1)
+            if lanes is not None:
+                X = lanes.last(X)[:, None]
+            else:
+                lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)
+                X = jnp.take_along_axis(X, lane[:, None, None, None],
+                                        axis=1)
         x = jnp.sum(X.astype(F32), axis=2).astype(self.dtype)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones, (d,),
                                    self.param_dtype), self.rms_norm_eps)

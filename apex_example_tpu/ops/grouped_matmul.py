@@ -65,10 +65,13 @@ def ragged_dot_f32(a, w, sizes):
 
 
 def _tiles(M: int, K: int, N: int, itemsize: int) -> Tuple[int, int]:
-    """(row tile, column tile): the largest row tile up to ``_ROW_TILE``
-    that divides M, and the widest whole-lane-tile divisor of N whose
-    ``[K, tn]`` block stays inside ``_WEIGHT_BLOCK_BYTES``."""
-    tm = next((t for t in (_ROW_TILE, 64, 32, 16, 8) if M % t == 0), M)
+    """(row tile, column tile): all of M where one ``_ROW_TILE`` holds it
+    (a whole dimension is always a legal block: 24 rows are one visit a
+    group, not three tiles of 8), else the largest row tile up to
+    ``_ROW_TILE`` that divides M; and the widest whole-lane-tile divisor of
+    N whose ``[K, tn]`` block stays inside ``_WEIGHT_BLOCK_BYTES``."""
+    tm = M if M <= _ROW_TILE else next(
+        (t for t in (_ROW_TILE, 64, 32, 16, 8) if M % t == 0), M)
     fits = [tn for tn in range(128, N + 1, 128)
             if N % tn == 0 and K * tn * itemsize <= _WEIGHT_BLOCK_BYTES]
     return tm, (fits[-1] if fits else N)

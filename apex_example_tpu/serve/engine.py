@@ -288,11 +288,13 @@ def draft_tick(dec, args: TickArgs, params, cache, packed, rng):
     says how many of a slot's last lanes are drafts: 1 or 0).  The model's
     first call returns the head's logits on each slot's verify lanes
     ``[SLOTS, 2, V]`` (the lane before the draft and the draft's; the
-    sampled lane twice where there is none) and every lane's normed hidden
-    state.  ``n1`` is sampled from the first as :func:`_slot_step` samples
-    (so a sampled-temperature slot, which feeds no draft, behaves as on the
-    plain path); the draft is accepted where ``d == n1``, and ``n2``, the
-    greedy choice after it, is then delivered with it.  The second call
+    sampled lane twice where there is none) and the normed hidden state of
+    every lane (``[SLOTS, C, d]``, or the tick's packed rows ``[R, d]`` from
+    a model that declares ``packed_lanes``: it is only handed back).  ``n1``
+    is sampled from the first as :func:`_slot_step` samples (so a
+    sampled-temperature slot, which feeds no draft, behaves as on the plain
+    path); the draft is accepted where ``d == n1``, and ``n2``, the greedy
+    choice after it, is then delivered with it.  The second call
     runs the module on every lane — lane ``j`` reads the token that
     follows it: the next lane's inside a prompt chunk, ``aux[:, 1]`` (the
     prompt's next token, from the host; -1 where the prompt ends here or
@@ -614,13 +616,17 @@ class ServeEngine:
             self.chunk = max(self.chunk, self.speculate + 1)
         # Lane packing (ops/lane_pack.py): a model that declares
         # ``packed_lanes`` runs its token-wise sublayers on the tick's
-        # live lanes as dense rows, which hold every slot's lane 0 and
-        # this many multi-lane chunks; the marshal loop grants no more.
+        # live lanes as dense rows, which hold every slot's first
+        # ``lane_head`` lanes (1 where the model names none; a model that
+        # drafts for itself names the fed token and its drafts) and this
+        # many longer chunks; the marshal loop grants no more.
         # None — every other model — is no budget: each lane of
         # [SLOTS, C] is a row of the program, and the marshal is what it
         # was.  A decode-role engine has C = 1, budget 0 and no slot
         # that asks for more than a lane: packing is the identity there.
-        self._chunk_budget = lane_pack.groups(num_slots, self.chunk) \
+        self._lane_head = int(getattr(model, "lane_head", 1))
+        self._chunk_budget = lane_pack.groups(
+            num_slots, self.chunk, self._lane_head) \
             if getattr(model, "packed_lanes", False) else None
         self.prefill_chunks_deferred = 0
         self.prefill_ticks_deferring = 0
@@ -956,17 +962,19 @@ class ServeEngine:
             # (-1: the module reads the token sampled this tick)
             aux = f.get("aux")
             # The token budget of chunked prefill, for a model whose rows are
-            # packed: a chunk of more than one lane is granted whole or not
-            # at all, oldest admission first; a slot granted nothing has
-            # n_new = 0 this tick, stages no write and keeps state and rows
-            # bit for bit.  Decoding slots (and a prompt's last single
-            # token) keep their one lane: the rows always hold those.
+            # packed: a chunk of more lanes than the rows' head is granted
+            # whole or not at all, oldest admission first; a slot granted
+            # nothing has n_new = 0 this tick, stages no write and keeps
+            # state and rows bit for bit.  Decoding slots (their drafts
+            # with them) and a prompt's last token or two keep their lanes:
+            # the rows' head always holds those.
             deferred = frozenset()
             if self._chunk_budget is not None:
                 slots = pool.slots
                 asking = sorted(
                     (i for i in live
-                     if min(C, slots[i].n_prompt - slots[i].cursor) > 1),
+                     if min(C, slots[i].n_prompt - slots[i].cursor)
+                     > self._lane_head),
                     key=lambda i: (slots[i].admitted_step,
                                    slots[i].t_admitted))
                 deferred = frozenset(asking[self._chunk_budget:])

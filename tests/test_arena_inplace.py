@@ -281,7 +281,10 @@ def test_tpu_tick_multiplies_the_experts_in_a_kernel(expert_tick):
     stack; the XLA form of the same ops holds XLA's ragged-dot kernels
     and no such call."""
     form, text = expert_tick
-    rows = EXPERT_SLOTS * BS * 4
+    # four pairs a row of the tick's packed rows (ops/lane_pack.py: since
+    # PR 44 the experts see the live lanes' rows, not all SLOTS x BS lanes)
+    from apex_example_tpu.ops import lane_pack
+    rows = lane_pack.rows(EXPERT_SLOTS, BS) * 4
     stack = EXPERTS * EXPERT_IN * EXPERT_WIDTH
     calls = {name: [line for line in text.splitlines()
                     if 'custom_call_target="tpu_custom_call"' in line

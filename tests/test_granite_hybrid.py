@@ -416,7 +416,7 @@ def test_rows_follow_from_the_ticks_geometry_alone():
 def test_lane_map_packs_and_unpacks_every_live_lane_once(
         n_new, monkeypatch):
     from apex_example_tpu.ops import lane_pack
-    monkeypatch.setattr(lane_pack, "groups", lambda s, c: 3)
+    monkeypatch.setattr(lane_pack, "groups", lambda s, c, head=1: 3)
     S, C = 8, 8
     n = jnp.asarray(n_new, jnp.int32)
     m = lane_pack.LaneMap(n, C)

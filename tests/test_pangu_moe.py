@@ -261,9 +261,11 @@ def test_served_tokens_are_the_same_with_drafting_on_and_off(
     assert len(verdicts) == on.tokens_drafted
     assert all(not c.drafts for c in want.values())
     # a tick may yield two tokens: a tick less in its slot an accepted draft
-    ticks = lambda done: sum(c.finished_step - c.admitted_step
-                             for c in done.values())
-    assert ticks(got) == ticks(want) - on.tokens_accepted
+    # (and a tick more a chunk that waited for rows: ops/lane_pack.py)
+    ticks = lambda eng, done: sum(
+        c.finished_step - c.admitted_step
+        for c in done.values()) - eng.prefill_chunks_deferred
+    assert ticks(on, got) == ticks(off, want) - on.tokens_accepted
     summary = on.summary_record()
     assert (summary["speculate_k"], summary["draft_kind"]) == (1, "mtp")
     assert summary["tokens_drafted"] == on.tokens_drafted
@@ -606,6 +608,12 @@ def _other(name):
         return gpt_tiny()
     if name == "xing4":
         return xing4.xing4_tiny(num_layers=2)
+    if name == "trinity":
+        from apex_example_tpu.models.trinity import trinity_tiny
+        return trinity_tiny()
+    if name == "lfm2":
+        from apex_example_tpu.models.lfm2 import lfm2_tiny
+        return lfm2_tiny()
     from apex_example_tpu.models.granite_hybrid import granite_hybrid_tiny
     return granite_hybrid_tiny()
 
@@ -616,16 +624,21 @@ def _other(name):
 # three (the step takes one packed array and a key, ``engine.TickArgs``, where
 # it took nine arrays; what it computes from them is what PR 36 pinned), and
 # PR 41 for "gpt1" and "granite" (their paged attention became
-# ``ops.attention.paged_gqa_attention``; "xing4" is PR 39's).  A PR that only
-# adds a model or an engine path beside them must not.
+# ``ops.attention.paged_gqa_attention``), and PR 44 for "xing4" (its
+# token-wise sublayers run on ``ops/lane_pack.py``'s packed rows; "granite",
+# the map's first caller, "trinity" and "lfm2", which share ``RoutedExperts``
+# and ``SwiGLU`` with it, and "gpt1" are what PR 44's parent a09fe8f lowered).
+# A PR that only adds a model or an engine path beside them must not.
 TICK_SINCE_PR39 = {
     "gpt1": "d30b7b180b9438e05e4eb0e2f39eb2e238c162146865677faf5a30a0e77e0c64",
-    "xing4": "d26034df746a79fc3359ee9beda6887b49a3f4c3282c6faa33712ad8e5b76b5f",
+    "xing4": "b816f09c208380fbc92edd265b4e75ae24b2d91c178523a48a950be55dbceece",
     "granite": "0670df7e1cda5c43df44cc7c5f5cc8bf92accefd8c93eaa9f25bce2b88a615bf",
+    "trinity": "7872dc93d8cd9046802bfe8f26265e52e1f7ff34b1c7a26e67043013e092cf82",
+    "lfm2": "50966f410f0a3251750340dfee9c1c25e6d3effdc3f8ad8c56be2e7707f6f73b",
 }
 
 
-@pytest.mark.parametrize("name", ["gpt1", "xing4", "granite"])
+@pytest.mark.parametrize("name", sorted(TICK_SINCE_PR39))
 def test_the_other_models_engines_and_tick_programs_are_untouched(name):
     model = _other(name)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
@@ -635,7 +648,11 @@ def test_the_other_models_engines_and_tick_programs_are_untouched(name):
     eng = _engine(model, params)
     assert not eng.self_draft and eng.speculate == 0 and eng.proposer is None
     assert not eng.pool.rows_read_next_token and eng.pool.spec_slack == 0
-    assert eng.tick_args == engine_lib.TickArgs(BS, MAX_LEN // BS)
+    assert eng.tick_args == engine_lib.TickArgs(
+        BS, MAX_LEN // BS, ring=eng.tick_args.ring)
+    # a row budget only where the model declares packed rows, of head 1
+    assert (eng._chunk_budget, eng._lane_head) == (
+        (1 if name in ("xing4", "granite") else None), 1)
     step = engine_lib._slot_step(eng.pool.dec, eng.tick_args)
     text = step.lower(
         params, eng.pool.cache,

@@ -191,14 +191,22 @@ BEFORE = {
                    [68, 68, 96, 96, 96, 29, 29, 29, 29],
                    [109, 109, 109, 157, 157, 157, 157, 157, 157, 157]],
         "rng": [1792790541, 2846007282], "compute_steps": 24, "idle": 4},
+    # Since PR 44 this model's rows are packed (ops/lane_pack.py) and the
+    # engine grants one chunk of more than two lanes a tick at 4 slots x 16:
+    # four chunks wait a tick, the run takes three steps more, and the two
+    # sampled requests that decode after a wait (s3, s4) draw from later
+    # keys of the same chain.  The greedy requests and s0 are 178afdb's
+    # tokens; a ``packed_lanes = False`` subclass serves all six of 178afdb's
+    # (s3 [5, 76, 206, 215, 123, 206, 126, 50], s4 [23, 39, 3, 23, 42, 112,
+    # 43, 255, 80]) in its 26 steps.
     "self_draft": {
         "tokens": [[158, 120, 47, 241, 212],
                    [176, 40, 29, 180, 134, 129],
                    [212, 42, 211, 28, 255, 48, 215],
-                   [5, 76, 206, 215, 123, 206, 126, 50],
-                   [23, 39, 3, 23, 42, 112, 43, 255, 80],
+                   [93, 104, 240, 231, 159, 79, 56, 169],
+                   [23, 191, 189, 207, 193, 254, 254, 131, 131],
                    [151, 155, 186, 173, 232, 146, 196, 199, 182, 33]],
-        "rng": [2030533547, 3853773407], "compute_steps": 26, "idle": 3},
+        "rng": [232827639, 2877507195], "compute_steps": 29, "idle": 2},
 }
 
 
