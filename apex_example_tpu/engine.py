@@ -130,6 +130,16 @@ def make_train_step(model, optimizer, policy: Policy,
     CP) don't need it — their grads arrive psum-ed, so the flag is
     already mesh-invariant.
 
+    A loss that declares a form over rows (``loss_fn.over_rows(hidden,
+    head, params, target) -> (loss, head_rows)``, as ``workloads.mlm_loss``
+    does) gets the encoder's output and the head instead of logits, from a
+    model that offers the two apart (``model.encode``, ``model.head``,
+    ``model.head_apart`` true: ``BertForMaskedLM`` outside its TP, CP and
+    MoE builds): the head and the loss then run over the labelled rows only,
+    and the metrics gain ``head_rows``, the rows the head ran on (summed over
+    ``grad_accum``'s microbatches; a replica's mean under ``axis_name``).
+    Any other pair of loss and model takes the logits path unchanged.
+
     ``numerics=True`` adds overflow provenance to the metrics: per-top-
     level-module non-finite counts + grad norms (``metrics["numerics"]``,
     obs/numerics.module_grad_stats), computed right next to the finite
@@ -153,6 +163,12 @@ def make_train_step(model, optimizer, policy: Policy,
                         ddp.allreduce_always_fp32 or
                         ddp.quantized_allreduce))
 
+    # the loss's form over rows, where the model can serve it (docstring)
+    over_rows = getattr(loss_fn, "over_rows", None) \
+        if getattr(model, "head_apart", False) else None
+    apply_head = lambda params, rows: model.apply(
+        {"params": params}, rows, method="head")
+
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         x, y = batch
 
@@ -164,12 +180,22 @@ def make_train_step(model, optimizer, policy: Policy,
 
         def scaled_loss_for(stats, x_mb, y_mb):
             def scaled_loss_fn(params):
-                logits, new_stats = _apply_model(
-                    model, params, stats, x_mb, train=True)
-                loss = loss_fn(logits, y_mb)
+                if over_rows is None:
+                    logits, new_stats = _apply_model(
+                        model, params, stats, x_mb, train=True)
+                    loss, counts = loss_fn(logits, y_mb), {}
+                else:
+                    # encoder, then the loss with the head in its hands: it
+                    # forms logits for the rows it counts and no others
+                    hidden = model.apply({"params": params}, x_mb,
+                                         train=True, method="encode")
+                    loss, head_rows = over_rows(hidden, apply_head, params,
+                                                y_mb)
+                    logits, new_stats = None, stats
+                    counts = {"head_rows": head_rows}
                 # amp.scale_loss: multiply before backward (§4.3).
                 return amp_lib.scale_loss(loss, state.scaler), (
-                    loss, logits, new_stats)
+                    loss, logits, new_stats, counts)
             return scaled_loss_fn
 
         # device_span (jax.named_scope): phase labels in xprof/tensorboard
@@ -178,7 +204,7 @@ def make_train_step(model, optimizer, policy: Policy,
         # spans and the device timeline share one vocabulary.
         if grad_accum == 1:
             with device_span("fwd_bwd"):
-                grads, (loss, logits, new_stats) = jax.grad(
+                grads, (loss, logits, new_stats, counts) = jax.grad(
                     scaled_loss_for(state.batch_stats, x, y),
                     has_aux=True)(diff_params)
             top1 = _batch_top1(logits, y) if (
@@ -192,7 +218,7 @@ def make_train_step(model, optimizer, policy: Policy,
             tail = lambda t: jax.tree_util.tree_map(lambda a: a[1:], t)
 
             def micro(stats, x_mb, y_mb):
-                grads_mb, (loss_mb, logits_mb, stats) = jax.grad(
+                grads_mb, (loss_mb, logits_mb, stats, counts_mb) = jax.grad(
                     scaled_loss_for(stats, x_mb, y_mb),
                     has_aux=True)(diff_params)
                 gf = jax.tree_util.tree_map(
@@ -200,13 +226,14 @@ def make_train_step(model, optimizer, policy: Policy,
                 t = (_batch_top1(logits_mb, y_mb)
                      if compute_accuracy and isinstance(y, jnp.ndarray)
                      else jnp.zeros((), jnp.float32))
-                return stats, gf, loss_mb, t
+                return stats, gf, loss_mb, t, counts_mb
 
             def body(carry, mb):
-                stats, gsum, lsum, tsum = carry
-                stats, gf, loss_mb, t = micro(stats, *mb)
+                stats, gsum, lsum, tsum, csum = carry
+                stats, gf, loss_mb, t, counts_mb = micro(stats, *mb)
                 gsum = jax.tree_util.tree_map(jnp.add, gsum, gf)
-                return (stats, gsum, lsum + loss_mb, tsum + t), None
+                csum = jax.tree_util.tree_map(jnp.add, csum, counts_mb)
+                return (stats, gsum, lsum + loss_mb, tsum + t, csum), None
 
             # Prologue: microbatch 0 runs outside the scan so the carry's
             # per-leaf shard-variance (vma) types are exactly those the body
@@ -215,7 +242,7 @@ def make_train_step(model, optimizer, policy: Policy,
             # and blanket-casting it varying would erase the invariant typing
             # of implicitly-psummed grads that allreduce_grads relies on to
             # skip the double reduction.
-            (new_stats, gsum, lsum, tsum), _ = jax.lax.scan(
+            (new_stats, gsum, lsum, tsum, counts), _ = jax.lax.scan(
                 body, micro(state.batch_stats, *head((xk, yk))),
                 tail((xk, yk)))
             grads = jax.tree_util.tree_map(
@@ -230,6 +257,7 @@ def make_train_step(model, optimizer, policy: Policy,
             with device_span("grad_allreduce"):
                 grads = allreduce_grads(grads, ddp, axis_name)
                 loss = jax.lax.pmean(loss, axis_name)
+                counts = jax.lax.pmean(counts, axis_name)
         with device_span("unscale_check"):
             grads, grads_finite = amp_lib.unscale_grads(grads, state.scaler)
             if finite_reduce_axes is not None:
@@ -254,7 +282,8 @@ def make_train_step(model, optimizer, policy: Policy,
         scaler = amp_lib.update_scaler(state.scaler, grads_finite)
 
         metrics = {"loss": loss, "scale": scaler.scale,
-                   "grads_finite": grads_finite.astype(jnp.float32)}
+                   "grads_finite": grads_finite.astype(jnp.float32),
+                   **counts}
         if finite_reduce_axes is None:
             # Post-unscale global grad norm, for the telemetry record (the
             # TXL step computes its own for clipping; this covers the image

@@ -482,7 +482,14 @@ class BertLayer(nn.Module):
 
 
 class BertForMaskedLM(nn.Module):
-    """BERT encoder + tied-decoder MLM head; returns vocab logits (fp32)."""
+    """BERT encoder + tied-decoder MLM head over one parameter tree.
+
+    ``encode`` gives the encoder's output (B, S, H), ``head`` the fp32 vocab
+    logits of any rows (..., H) of it, and ``__call__`` is the head of every
+    row of the encoder's output.  A train step whose loss counts only the
+    labelled rows (``workloads.mlm_loss.over_rows``) applies the two apart,
+    where ``head_apart`` says it may, and forms logits for those rows alone.
+    """
 
     vocab_size: int = 30522
     hidden_size: int = 768
@@ -518,10 +525,17 @@ class BertForMaskedLM(nn.Module):
     # (all-to-all head sharding; "zigzag" is causal-only -> GPT)
     cp_mode: str = "ring"
 
-    @nn.compact
-    def __call__(self, input_ids, attention_mask: Optional[jnp.ndarray] = None,
-                 train: bool = True):
-        del train  # no dropout in the pretraining benchmark path
+    @property
+    def head_apart(self) -> bool:
+        """Whether a step may gather rows of ``encode``'s output and apply
+        ``head`` to them alone.  Not under tensor parallelism (the decoder is
+        vocab-sharded and the rows data-sharded: the gather would cross the
+        mesh), nor where ``encode`` returns an auxiliary loss beside the
+        rows (MoE) or holds a slice of the sequence (context parallelism)."""
+        return not (self.tensor_parallel or self.moe_experts
+                    or self.context_parallel)
+
+    def setup(self):
         if self.moe_experts and self.sequence_parallel:
             # SP re-shards the sequence dim the dispatch indexes.  (TP
             # composes: the FFN is the expert block and the Megatron
@@ -536,23 +550,52 @@ class BertForMaskedLM(nn.Module):
             raise ValueError("sequence_parallel shards activations along "
                              "the sequence dim the context axis already "
                              "owns; CP composes with plain tensor_parallel")
-        if self.context_parallel and attention_mask is not None:
-            raise ValueError("context_parallel BERT does not support an "
-                             "attention mask")
-        ln_io = self.ln_dtype or self.dtype
-        b, L = input_ids.shape
+        self.ln_io = ln_io = self.ln_dtype or self.dtype
         if self.tensor_parallel:
             from apex_example_tpu.transformer.tensor_parallel.layers import (
                 VocabParallelEmbedding)
-            word_emb = VocabParallelEmbedding(
+            self.word_embeddings = VocabParallelEmbedding(
                 self.vocab_size, self.hidden_size, dtype=self.dtype,
-                param_dtype=self.param_dtype, name="word_embeddings")
+                param_dtype=self.param_dtype)
         else:
-            word_emb = nn.Embed(self.vocab_size, self.hidden_size,
-                                dtype=self.dtype,
-                                param_dtype=self.param_dtype,
-                                name="word_embeddings")
-        x = word_emb(input_ids)
+            self.word_embeddings = nn.Embed(
+                self.vocab_size, self.hidden_size, dtype=self.dtype,
+                param_dtype=self.param_dtype)
+        self.position_embeddings = nn.Embed(
+            self.max_position, self.hidden_size, dtype=self.dtype,
+            param_dtype=self.param_dtype)
+        self.embeddings_ln = FusedLayerNorm(dtype=ln_io)
+        for i in range(self.num_layers):
+            setattr(self, f"layer_{i}", BertLayer(
+                self.hidden_size, self.num_heads, self.intermediate_size,
+                self.dtype, self.param_dtype, self.ln_dtype,
+                self.softmax_dtype,
+                fused_attention=self.fused_attention,
+                tensor_parallel=self.tensor_parallel,
+                sequence_parallel=self.sequence_parallel,
+                context_parallel=self.context_parallel,
+                moe_experts=self.moe_experts,
+                moe_capacity_factor=self.moe_capacity_factor,
+                moe_axis_name=self.moe_axis_name,
+                moe_top_k=self.moe_top_k,
+                cp_mode=self.cp_mode))
+        self.mlm_dense = nn.Dense(self.hidden_size, dtype=self.dtype,
+                                  param_dtype=self.param_dtype)
+        self.mlm_ln = FusedLayerNorm(dtype=ln_io)
+        bias_init = nn.initializers.zeros
+        if self.tensor_parallel:
+            bias_init = nn.with_partitioning(bias_init, ("model",))
+        self.mlm_bias = self.param("mlm_bias", bias_init,
+                                   (self.vocab_size,), jnp.float32)
+
+    def _layers(self, input_ids, attention_mask):
+        """Embeddings and the layers: (B, S) ids -> (B, S, H) rows and the
+        sum of the layers' auxiliary losses (0 without ``moe_experts``)."""
+        if self.context_parallel and attention_mask is not None:
+            raise ValueError("context_parallel BERT does not support an "
+                             "attention mask")
+        L = input_ids.shape[1]
+        x = self.word_embeddings(input_ids)
         pos = jnp.arange(L)[None, :]
         if self.context_parallel:
             # input_ids hold this context shard's slice; global positions
@@ -560,11 +603,8 @@ class BertForMaskedLM(nn.Module):
             from jax import lax as _lax
             from apex_example_tpu.parallel.mesh import CONTEXT_AXIS
             pos = pos + _lax.axis_index(CONTEXT_AXIS) * L
-        x = x + nn.Embed(self.max_position, self.hidden_size,
-                         dtype=self.dtype, param_dtype=self.param_dtype,
-                         name="position_embeddings")(pos)
-        x = FusedLayerNorm(dtype=ln_io, name="embeddings_ln")(
-            x.astype(ln_io)).astype(self.dtype)
+        x = x + self.position_embeddings(pos)
+        x = self.embeddings_ln(x.astype(self.ln_io)).astype(self.dtype)
 
         if attention_mask is not None:
             mask_bias = jnp.where(attention_mask[:, None, None, :] > 0,
@@ -574,41 +614,36 @@ class BertForMaskedLM(nn.Module):
 
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(self.num_layers):
-            x = BertLayer(self.hidden_size, self.num_heads,
-                          self.intermediate_size, self.dtype,
-                          self.param_dtype, self.ln_dtype,
-                          self.softmax_dtype,
-                          fused_attention=self.fused_attention,
-                          tensor_parallel=self.tensor_parallel,
-                          sequence_parallel=self.sequence_parallel,
-                          context_parallel=self.context_parallel,
-                          moe_experts=self.moe_experts,
-                          moe_capacity_factor=self.moe_capacity_factor,
-                          moe_axis_name=self.moe_axis_name,
-                          moe_top_k=self.moe_top_k,
-                          cp_mode=self.cp_mode,
-                          name=f"layer_{i}")(x, mask_bias)
+            x = getattr(self, f"layer_{i}")(x, mask_bias)
             if self.moe_experts:
                 x, aux = x
                 aux_total = aux_total + aux
+        return x, aux_total
 
-        # MLM head: dense+gelu+LN, then tied decoder.  Under TP the decoder
-        # is the parallel LM head (vocab-sharded logits — the CE's logsumexp
-        # reduction over vocab becomes a psum, GSPMD's lowering of
-        # Megatron's vocab_parallel_cross_entropy).
+    def encode(self, input_ids, attention_mask: Optional[jnp.ndarray] = None,
+               train: bool = True):
+        """The encoder's output, (B, S) ids -> (B, S, H) rows (without the
+        auxiliary loss of ``moe_experts``, which ``__call__`` returns)."""
+        del train  # no dropout in the pretraining benchmark path
+        return self._layers(input_ids, attention_mask)[0]
+
+    def head(self, x):
+        """MLM head of rows (..., H): dense+gelu+LN, then the tied decoder;
+        fp32 logits (..., V).  Under TP the decoder is the parallel LM head
+        (vocab-sharded logits — the CE's logsumexp reduction over vocab
+        becomes a psum, GSPMD's lowering of Megatron's
+        vocab_parallel_cross_entropy)."""
         with device_span("mlm_head"):
-            x = nn.Dense(self.hidden_size, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="mlm_dense")(x)
-            x = nn.gelu(x, approximate=False)
-            x = FusedLayerNorm(dtype=ln_io, name="mlm_ln")(
-                x.astype(ln_io)).astype(self.dtype)
-            logits = word_emb.attend(x)
-            bias_init = nn.initializers.zeros
-            if self.tensor_parallel:
-                bias_init = nn.with_partitioning(bias_init, ("model",))
-            logits = logits + self.param("mlm_bias", bias_init,
-                                         (self.vocab_size,), jnp.float32)
-            logits = logits.astype(jnp.float32)
+            x = nn.gelu(self.mlm_dense(x), approximate=False)
+            x = self.mlm_ln(x.astype(self.ln_io)).astype(self.dtype)
+            logits = self.word_embeddings.attend(x) + self.mlm_bias
+            return logits.astype(jnp.float32)
+
+    def __call__(self, input_ids, attention_mask: Optional[jnp.ndarray] = None,
+                 train: bool = True):
+        del train
+        x, aux_total = self._layers(input_ids, attention_mask)
+        logits = self.head(x)
         if self.moe_experts:
             return logits, aux_total / self.num_layers
         return logits

@@ -655,6 +655,9 @@ OPTIONAL: Dict[str, Dict[str, Any]] = {
         "ppl": _NUM,
         "masked_acc": _NUM,
         "lr": _NUM,
+        # rows the masked-LM head ran on (engine.make_train_step, a loss
+        # over rows); only such a step's records carry it
+        "head_rows": _NUM,
         "time": _NUM,
         "memory": dict,
         "spans": dict,

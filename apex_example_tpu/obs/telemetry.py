@@ -145,7 +145,7 @@ class TelemetryEmitter:
             "scale": values.get("scale", 1.0),
         }
         for key in ("grad_norm", "grads_finite", "top1", "ppl",
-                    "masked_acc", "lr"):
+                    "masked_acc", "lr", "head_rows"):
             if key in values:
                 rec[key] = values[key]
         if self.memory_every and (self._steps - 1) % self.memory_every == 0:

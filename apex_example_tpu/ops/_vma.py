@@ -36,3 +36,11 @@ def align_param_grad(g, param):
     from jax import lax
     extra = tuple(sorted(jax.typeof(g).vma - jax.typeof(param).vma))
     return lax.psum(g, extra) if extra else g
+
+
+def vary_like(x, ref):
+    """``x`` cast to vary over every mesh axis ``ref`` varies over (a no-op
+    outside shard_map, or where it already does)."""
+    from jax import lax
+    extra = tuple(sorted(jax.typeof(ref).vma - jax.typeof(x).vma))
+    return lax.pcast(x, extra, to="varying") if extra else x

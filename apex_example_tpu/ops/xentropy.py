@@ -10,9 +10,12 @@ saves only ``(logits, labels, lse)`` — logits are an input the caller
 already holds, and lse is O(tokens) — and the backward REMATERIALIZES the
 (tokens, V) probability tensor as ``exp(logits − lse)`` instead of storing
 it.  Under plain autodiff the residual set includes an O(tokens·V) tensor
-(log-softmax or probs); at BERT scale (B·S·V fp32 logits are ~GBs) dropping
-that residual is the entire point of the contrib kernel, and XLA fuses the
-rematerialized exp into the backward's subtract.  No Pallas kernel is
+(log-softmax or probs); dropping that residual is the entire point of the
+contrib kernel, and XLA fuses the rematerialized exp into the backward's
+subtract.  (How many tokens reach it is the caller's: BERT's train step
+hands it blocks of the labelled rows, ``workloads.mlm_loss.over_rows``, so
+the B·S·V logits themselves — ~4 GB in fp32 at BERT's shape — are never
+formed; evaluation and the LM losses hand it every row.)  No Pallas kernel is
 needed: both passes are single fused elementwise+reduce sweeps, which XLA
 already emits optimally (the same rely-on-XLA stance as fused_dense,
 SURVEY.md §2.1).

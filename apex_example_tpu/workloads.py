@@ -20,6 +20,7 @@ from apex_example_tpu import amp as amp_lib
 from apex_example_tpu.amp.policy import Policy
 from apex_example_tpu.engine import TrainState, _wrap_optimizer
 from apex_example_tpu.obs.spans import device_span
+from apex_example_tpu.ops._vma import vary_like
 from apex_example_tpu.ops.xentropy import softmax_cross_entropy
 from apex_example_tpu.parallel.distributed import DDPConfig, allreduce_grads
 from apex_example_tpu.parallel.mesh import DATA_AXIS
@@ -28,15 +29,130 @@ from apex_example_tpu.parallel.mesh import DATA_AXIS
 def mlm_loss(logits: jnp.ndarray, target: Tuple[jnp.ndarray, jnp.ndarray]
              ) -> jnp.ndarray:
     """Masked-LM loss: mean CE over masked positions only (weights mark
-    them).  target = (labels, weights).  Uses the fused-CE op: its backward
-    rematerializes the (B, S, V) probability tensor instead of saving it —
-    at vocab 30k that residual is the largest activation in the step
+    them).  target = (labels, weights).  This form takes logits of every
+    row (evaluation, the model-parallel steps); a train step that holds the
+    encoder's output and the model's head apart takes ``mlm_loss.over_rows``
+    and forms logits for the labelled rows alone.  Uses the fused-CE op,
+    whose backward rematerializes the probabilities instead of saving them
     (ops/xentropy.py, the contrib-xentropy analog)."""
     labels, weights = target
     with device_span("loss"):
         ce = softmax_cross_entropy(logits, labels)
         denom = jnp.maximum(weights.sum(), 1.0)
         return (ce * weights).sum() / denom
+
+
+# Rows of one block of the row-wise form: its float32 logits (1024 x 30522
+# at BERT's vocabulary: 125 MB) are the largest array either pass holds.
+MLM_BLOCK_ROWS = 1024
+
+
+def _mlm_loss_over_rows(hidden: jnp.ndarray, head, params,
+                        target: Tuple[jnp.ndarray, jnp.ndarray]
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``mlm_loss`` from the encoder's output ``hidden`` (B, S, H) and the
+    model's ``head`` ((params, rows (R, H)) -> logits (R, V)): the same loss
+    and the same gradients as ``mlm_loss(head(params, hidden), target)``,
+    with the head and the CE evaluated on the rows of non-zero weight only
+    (Google BERT's ``gather_indexes``, NVIDIA BERT's ``dense_seq_output``).
+
+    The labelled rows are ordered first (a stable sort) and walked in
+    blocks of ``MLM_BLOCK_ROWS``: gather, head, CE, weighted sum.  Both
+    passes loop as many times as there are blocks holding a labelled row —
+    the count is read from the batch, no label is dropped — and the backward
+    forms each block's logits again, so neither holds more than one block's.
+    A row of weight 0 inside the last block adds exactly 0 to the loss and
+    to every gradient, as every such row does in the all-rows form.
+
+    Returns ``(loss, head_rows)``: the rows the head ran on (blocks x block
+    rows), float32.
+    """
+    labels, weights = target
+    n = weights.size
+    r = min(MLM_BLOCK_ROWS, n)
+    with device_span("loss"):
+        rows = hidden.reshape(n, -1)
+        flat_w = weights.reshape(n).astype(jnp.float32)
+        labelled = flat_w != 0
+        blocks = (labelled.sum() + (r - 1)) // r
+        # sorted place -> row; the tail fills the last block with rows past
+        # the end (gathered as 0, of weight 0)
+        order = jnp.pad(jnp.argsort(~labelled, stable=True),
+                        (0, -n % r), constant_values=n)
+        take = lambda a: jnp.take(a.reshape(n), order, mode="fill",
+                                  fill_value=0)
+        denom = jnp.maximum(flat_w.sum(), 1.0)
+        # row -> sorted place, past the end for a row of no weight
+        place = jnp.where(labelled, jnp.cumsum(labelled) - 1, order.size)
+        # Inside shard_map a replicated parameter met by shard-varying rows
+        # has its gradient psum-ed where they meet: that would be inside a
+        # loop whose trip count differs from shard to shard.  Cast once,
+        # here, and autodiff sums once, after the loop.
+        varying = jax.tree_util.tree_map(lambda p: vary_like(p, rows),
+                                         params)
+        head_fn, consts = jax.closure_convert(
+            lambda x: head(varying, x), rows[:r])
+        loss = _blocked_ce(head_fn, r)(
+            consts, rows, order, take(labels), take(flat_w) / denom,
+            blocks, place)
+    return loss, (blocks * r).astype(jnp.float32)
+
+
+mlm_loss.over_rows = _mlm_loss_over_rows
+
+
+def _blocked_ce(head_fn, r: int):
+    """``f(consts, rows, order, labels, weights, blocks, place)``: the sum
+    over the first ``blocks`` blocks of ``r`` sorted rows of weight x CE of
+    ``head_fn(rows[order[block]], *consts)``, with its own VJP: the backward
+    loops over the same blocks, forms each one's logits again and writes the
+    block's ``d rows`` into its place among the sorted rows; ``place`` (a
+    row's sorted place, past the end for a row of no weight) brings them
+    home."""
+
+    def block_sum(consts, picked, labels, weights):
+        ce = softmax_cross_entropy(head_fn(picked, *consts), labels)
+        return (ce * weights).sum()
+
+    def block_of(i, rows, order, labels, weights):
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * r, r)
+        picked = jnp.take(rows, cut(order), axis=0, mode="fill",
+                          fill_value=0)
+        return picked, cut(labels), cut(weights)
+
+    def total(consts, rows, order, labels, weights, blocks, place):
+        def body(i, acc):
+            return acc + block_sum(
+                consts, *block_of(i, rows, order, labels, weights))
+        return jax.lax.fori_loop(
+            0, blocks, body, vary_like(jnp.zeros((), jnp.float32), rows))
+
+    def fwd(*args):
+        return total(*args), args
+
+    def bwd(args, g):
+        consts, rows, order, labels, weights, blocks, place = args
+
+        def body(i, carry):
+            d_consts, d_sorted = carry
+            picked, lab, w = block_of(i, rows, order, labels, weights)
+            dc, dp = jax.grad(block_sum, argnums=(0, 1))(
+                consts, picked, lab, w * g)
+            return (jax.tree_util.tree_map(jnp.add, d_consts, dc),
+                    jax.lax.dynamic_update_slice_in_dim(d_sorted, dp,
+                                                        i * r, 0))
+
+        zeros = lambda shape, dtype: vary_like(jnp.zeros(shape, dtype), rows)
+        d_consts, d_sorted = jax.lax.fori_loop(
+            0, blocks, body,
+            ([zeros(c.shape, c.dtype) for c in consts],
+             zeros((order.size, rows.shape[-1]), rows.dtype)))
+        d_rows = jnp.take(d_sorted, place, axis=0, mode="fill", fill_value=0)
+        return d_consts, d_rows, None, None, None, None, None
+
+    f = jax.custom_vjp(total)
+    f.defvjp(fwd, bwd)
+    return f
 
 
 def lm_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:

@@ -452,8 +452,13 @@ def test_the_training_program_carries_its_scopes_and_moves_no_parameter():
     # (unscale_check is folded away under O2's static scale of 1)
     for scope in ("fwd_bwd", "optimizer", "mlm_head", "loss"):
         assert any(wrapped(scope).search(n + "/") for n in names), scope
-    # forward and backward of the head and of the loss are both named
-    assert any("/jvp(loss)/" in n for n in names)
-    assert any("transpose(jvp(loss))" in n for n in names)
-    assert any("transpose(jvp(BertForMaskedLM))/mlm_head/" in n
+    # forward and backward of the head and of the loss are both named: since
+    # PR 46 both passes are loops over the labelled rows' blocks under
+    # ``loss`` (workloads.mlm_loss.over_rows), the head inside them
+    assert any("/fwd_bwd/jvp(loss)/while/body/" in n for n in names)
+    assert any("/transpose(fwd_bwd)/jvp(loss)/while/body/" in n
                for n in names)
+    assert any("/while/body/jvp(BertForMaskedLM.head)/mlm_head/" in n
+               for n in names)
+    assert any("/while/body/transpose(jvp(BertForMaskedLM.head))/mlm_head/"
+               in n for n in names)
