@@ -79,40 +79,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_example_tpu.models.layers import (F32, causal_gqa_attention,
+                                            einsum_f32, fan_in, matmul_f32,
+                                            need_host_state, paged_gqa_step,
+                                            rms_norm_f32)
 from apex_example_tpu.obs.spans import device_span
 from apex_example_tpu.ops import lane_pack, paged_cache, ssd
-from apex_example_tpu.ops.attention import paged_gqa_attention
-
-F32 = jnp.float32
-
-
-def _fan_in(fan_in: int):
-    return nn.initializers.normal(1.0 / math.sqrt(fan_in))
-
-
-def matmul_f32(a, b):
-    """``a @ b`` accumulated and returned in float32 (on the TPU the MXU
-    multiplies bfloat16 operands exactly and adds in float32)."""
-    return jnp.matmul(a, b, preferred_element_type=F32)
-
-
-def einsum_f32(spec, a, b):
-    return jnp.einsum(spec, a, b, preferred_element_type=F32)
-
-
-def rms_norm(x, scale, eps):
-    """RMSNorm with float32 statistics, returned in float32."""
-    y = x.astype(F32)
-    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True) + eps)
-    return y * scale.astype(F32)
-
-
-def _need_host_state(paged):
-    if paged is None:
-        raise ValueError(
-            "paged slot decode needs the host state: pass "
-            "paged={'block_table', 'fill', 'n_new', 'cow_src', "
-            "'cow_dst'} (serve/engine.py builds it each tick)")
 
 
 def _dt_bias_init(lo: float = 1e-3, hi: float = 1e-1):
@@ -162,14 +134,14 @@ class MambaMixer(nn.Module):
                          self.d_state, self.d_conv)
         di, ch = H * P, H * P + 2 * N
         pd = self.param_dtype
-        w_in = self.param("in_proj", _fan_in(d), (d, di + ch + H), pd)
+        w_in = self.param("in_proj", fan_in(d), (d, di + ch + H), pd)
         conv_w = self.param("conv_w", _uniform(1 / math.sqrt(K)), (K, ch), pd)
         conv_b = self.param("conv_b", nn.initializers.zeros, (ch,), pd)
         dt_bias = self.param("dt_bias", _dt_bias_init(), (H,), F32)
         a_log = self.param("A_log", _a_log_init, (H,), F32)
         D = self.param("D", nn.initializers.ones, (H,), F32)
         norm = self.param("norm", nn.initializers.ones, (di,), pd)
-        w_out = self.param("out_proj", _fan_in(di), (di, d), pd)
+        w_out = self.param("out_proj", fan_in(di), (di, d), pd)
 
         S, L = h.shape[:2] if lanes is None else (lanes.slots, lanes.chunk)
         state = rows = reset = n_new = None
@@ -183,7 +155,7 @@ class MambaMixer(nn.Module):
             cv = paged_cache.slot_variable(self, "conv_rows", S,
                                            ((K - 1) * ch,), self.dtype)
             if ready:
-                _need_host_state(paged)
+                need_host_state(paged)
                 n_new = paged["n_new"]
                 # a slot's first chunk starts its request: from zero,
                 # whatever the slot's last request left
@@ -220,7 +192,7 @@ class MambaMixer(nn.Module):
         if lanes is not None:
             y = lanes.pack(y)
         y = y * jax.nn.silu(z.astype(F32))
-        y = rms_norm(y, norm, self.rms_norm_eps).astype(self.dtype)
+        y = rms_norm_f32(y, norm, self.rms_norm_eps).astype(self.dtype)
         out = matmul_f32(y, w_out).astype(self.dtype)
         if not carried:
             return out, None, None
@@ -250,11 +222,11 @@ class GQAttention(nn.Module):
         to the arena from their rows, the scores see ``[S, L, ...]``."""
         d, Hq, Hk, hd = (self.hidden_size, self.num_heads, self.num_kv_heads,
                          self.head_dim)
-        pd, g = self.param_dtype, self.num_heads // self.num_kv_heads
-        wq = self.param("wq", _fan_in(d), (d, Hq * hd), pd)
-        wk = self.param("wk", _fan_in(d), (d, Hk * hd), pd)
-        wv = self.param("wv", _fan_in(d), (d, Hk * hd), pd)
-        wo = self.param("wo", _fan_in(Hq * hd), (Hq * hd, d), pd)
+        pd = self.param_dtype
+        wq = self.param("wq", fan_in(d), (d, Hq * hd), pd)
+        wk = self.param("wk", fan_in(d), (d, Hk * hd), pd)
+        wv = self.param("wv", fan_in(d), (d, Hk * hd), pd)
+        wo = self.param("wo", fan_in(Hq * hd), (Hq * hd, d), pd)
         mm = lambda a, w: matmul_f32(a, w).astype(self.dtype)
         S, L = pos.shape
         q, k, v = mm(h, wq), mm(h, wk), mm(h, wv)          # [.., Hk * hd]
@@ -263,44 +235,14 @@ class GQAttention(nn.Module):
         q = q.reshape(S, L, Hq, hd)
         o = walked = None
         if self.decode:
-            NB, BS = self.kv_num_blocks, self.kv_block_size
-            ready = self.has_variable("cache", "cached_key")
-            ck, cv = (paged_cache.variable(self, name, NB, BS, self.dtype,
-                                           Hk * hd)
-                      for name in ("cached_key", "cached_value"))
-            if ready:
-                _need_host_state(paged)
-                table = paged["block_table"]
-                ck.value, cv.value = paged_cache.cow(
-                    (ck.value, cv.value), paged["cow_src"],
-                    paged["cow_dst"])
-                flat = paged_cache.write_rows(table, pos, paged["n_new"],
-                                              NB, BS)
-                if lanes is not None:
-                    # the rows of k and v are the packed ones: so are
-                    # their places in the arena (a dead row drops)
-                    flat = lanes.pack(flat.reshape(S, L), fill=NB * BS)
-                ck.value, cv.value = paged_cache.write(
-                    (ck.value, cv.value), flat, (k, v))
-                # scores, mask, softmax and weighted sum over the slot's
-                # blocks where they lie: one op that names its own scope
-                # (ops/attention.py)
-                o, walked = paged_gqa_attention(
-                    q, ck.value, cv.value, table, paged["fill"],
-                    paged["n_new"], scale=self.scale)
-            # init trace on the [B, max_len] dummy: the leaves are
-            # allocated above; fall through so that params initialize
+            # K and V to the arena from their packed rows, scores over the
+            # slot's blocks; nothing from the init trace, which allocates
+            # the leaves: fall through so that params initialize
+            o, walked = paged_gqa_step(
+                self, q, k, v, pos, paged, self.kv_num_blocks,
+                self.kv_block_size, self.scale, lanes=lanes)
         if o is None:
-            with device_span("gqa_attention"):
-                keys, vals = (t.reshape(S, L, Hk, hd) for t in (k, v))
-                scores = einsum_f32("sqkgd,slkd->skgql",
-                                    q.reshape(S, L, Hk, g, hd),
-                                    keys) * self.scale
-                seen = pos[:, None, None, None, :] \
-                    <= pos[:, None, None, :, None]
-                probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
-                o = einsum_f32("skgql,slkd->sqkgd", probs.astype(self.dtype),
-                               vals).astype(self.dtype)
+            o = causal_gqa_attention(q, k, v, pos, self.scale)
         o = o.reshape(S, L, Hq * hd)
         if lanes is not None:
             o = lanes.pack(o)
@@ -317,8 +259,8 @@ class SharedMLP(nn.Module):
     @nn.compact
     def __call__(self, h):
         d, f = self.hidden_size, self.width
-        w_in = self.param("w_in", _fan_in(d), (d, 2 * f), self.param_dtype)
-        w_out = self.param("w_out", _fan_in(f), (f, d), self.param_dtype)
+        w_in = self.param("w_in", fan_in(d), (d, 2 * f), self.param_dtype)
+        w_out = self.param("w_out", fan_in(f), (f, d), self.param_dtype)
         with device_span("shared_mlp"):
             gv = matmul_f32(h, w_in)                       # gate half first
             a = (jax.nn.silu(gv[..., :f]) * gv[..., f:]).astype(self.dtype)
@@ -339,7 +281,7 @@ class GraniteHybridLayer(nn.Module):
         d, eps, r = c["hidden_size"], c["rms_norm_eps"], \
             c["residual_multiplier"]
         dtype, pd = c["dtype"], c["param_dtype"]
-        norm = lambda name, t: rms_norm(
+        norm = lambda name, t: rms_norm_f32(
             t, self.param(name, nn.initializers.ones, (d,), pd),
             eps).astype(dtype)
         h, counts, walked = norm("norm1", x), (None, None), None
@@ -443,7 +385,7 @@ class GraniteHybridForCausalLM(nn.Module):
         # seeded so that x_0 = embedding_multiplier E[ids] has a projection's
         # scale: at 1/sqrt(d) a tied head echoes its input token
         embed = self.param("embed",
-                           _fan_in(d * self.embedding_multiplier ** 2),
+                           fan_in(d * self.embedding_multiplier ** 2),
                            (self.vocab_size, d), self.param_dtype)
         x = (embed[input_ids].astype(F32)
              * self.embedding_multiplier).astype(self.dtype)
@@ -475,7 +417,7 @@ class GraniteHybridForCausalLM(nn.Module):
             else:
                 lane = jnp.clip(paged["n_new"] - 1, 0, L - 1)
                 x = jnp.take_along_axis(x, lane[:, None, None], axis=1)
-        x = rms_norm(x, self.param("final_norm", nn.initializers.ones, (d,),
+        x = rms_norm_f32(x, self.param("final_norm", nn.initializers.ones, (d,),
                                    self.param_dtype),
                      self.rms_norm_eps).astype(self.dtype)
         return einsum_f32("bld,vd->blv", x, embed) / self.logits_scaling

@@ -20,7 +20,7 @@ attention layer; ``v = h W_v``; query head ``i`` reads key/value head ``i //
 (heads / kv heads)``; causal, scale ``head_dim ** -0.5``; ``y = concat_h(p
 v) W_o``.  No bias, no gate.
 
-*Expert FFN*: ``models/xing4.RoutedExperts`` with ``n_shared = 0`` (sigmoid
+*Expert FFN*: ``models/layers.RoutedExperts`` with ``n_shared = 0`` (sigmoid
 scores, the ``k`` largest of score + selection bias, gates the chosen
 experts' own scores over their sum, times ``routed_scaling_factor``).
 ``benchmarks/reference/lfm2.py`` holds the same equations in plain float32;
@@ -60,17 +60,15 @@ from __future__ import annotations
 from typing import Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
-from apex_example_tpu.models.granite_hybrid import _need_host_state
-from apex_example_tpu.models.trinity import rotate_half
-from apex_example_tpu.models.xing4 import (F32, RoutedExperts, SwiGLU,
-                                           _fan_in, einsum_f32, matmul_f32,
-                                           rms_norm)
+from apex_example_tpu.models.layers import (RoutedExperts, SwiGLU,
+                                            causal_gqa_attention, einsum_f32,
+                                            fan_in, matmul_f32,
+                                            need_host_state, paged_gqa_step,
+                                            rms_norm, rotate_half)
 from apex_example_tpu.obs.spans import device_span
 from apex_example_tpu.ops import paged_cache, ssd
-from apex_example_tpu.ops.attention import paged_gqa_attention
 
 CONV, FULL = "conv", "full_attention"
 
@@ -89,10 +87,10 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, h, paged=None):
         d, K, pd = self.hidden_size, self.taps, self.param_dtype
-        w_in = self.param("in_proj", _fan_in(d), (d, 3 * d), pd)
+        w_in = self.param("in_proj", fan_in(d), (d, 3 * d), pd)
         # seeded at 1/sqrt(K) so that the taps' sum keeps v's scale
-        conv_w = self.param("conv_w", _fan_in(K), (K, d), pd)
-        w_out = self.param("out_proj", _fan_in(d), (d, d), pd)
+        conv_w = self.param("conv_w", fan_in(K), (K, d), pd)
+        w_out = self.param("out_proj", fan_in(d), (d, d), pd)
         S, L = h.shape[:2]
         rows = reset = cv = None
         n_new = jnp.full((S,), L, jnp.int32)
@@ -103,7 +101,7 @@ class ShortConv(nn.Module):
             cv = paged_cache.slot_variable(self, "conv_rows", S,
                                            ((K - 1) * d,), self.dtype)
             if ready:
-                _need_host_state(paged)
+                need_host_state(paged)
                 n_new = paged["n_new"]
                 # a slot's first chunk starts its request: from zero,
                 # whatever the slot's last request left
@@ -146,10 +144,10 @@ class RotaryGQAttention(nn.Module):
         d, Hq, Hk, hd = (self.hidden_size, self.num_heads, self.num_kv_heads,
                          self.head_dim)
         pd, eps = self.param_dtype, self.norm_eps
-        wq = self.param("wq", _fan_in(d), (d, Hq * hd), pd)
-        wk = self.param("wk", _fan_in(d), (d, Hk * hd), pd)
-        wv = self.param("wv", _fan_in(d), (d, Hk * hd), pd)
-        wo = self.param("wo", _fan_in(Hq * hd), (Hq * hd, d), pd)
+        wq = self.param("wq", fan_in(d), (d, Hq * hd), pd)
+        wk = self.param("wk", fan_in(d), (d, Hk * hd), pd)
+        wv = self.param("wv", fan_in(d), (d, Hk * hd), pd)
+        wo = self.param("wo", fan_in(Hq * hd), (Hq * hd, d), pd)
         q_norm = self.param("q_norm", nn.initializers.ones, (hd,), pd)
         k_norm = self.param("k_norm", nn.initializers.ones, (hd,), pd)
         mm = lambda a, w: matmul_f32(a, w).astype(self.dtype)
@@ -163,44 +161,17 @@ class RotaryGQAttention(nn.Module):
                                      eps), pos, self.rope_theta)
             v = mm(h, wv)
 
-        def out(o):
-            with device_span("gqa_attention"):
-                return mm(o.reshape(B, L, Hq * hd), wo)
-
+        o = walked = None
         if self.decode:
-            NB, BS = self.kv_num_blocks, self.kv_block_size
-            ready = self.has_variable("cache", "cached_key")
-            ck, cv = (paged_cache.variable(self, n, NB, BS, self.dtype,
-                                           Hk * hd)
-                      for n in ("cached_key", "cached_value"))
-            if ready:
-                _need_host_state(paged)
-                table = paged["block_table"]
-                ck.value, cv.value = paged_cache.cow(
-                    (ck.value, cv.value), paged["cow_src"],
-                    paged["cow_dst"])
-                flat = paged_cache.write_rows(table, pos, paged["n_new"],
-                                              NB, BS)
-                ck.value, cv.value = paged_cache.write(
-                    (ck.value, cv.value), flat,
-                    (k.reshape(B, L, Hk * hd), v))
-                # scores, mask, softmax and weighted sum: one op that
-                # names its own scope (ops/attention.py)
-                o, walked = paged_gqa_attention(
-                    q, ck.value, cv.value, table, paged["fill"],
-                    paged["n_new"], scale=scale)
-                return out(o), walked
-            # init trace on the [slots, max_len] dummy: the cache is
-            # allocated above; fall through so that params initialize
+            # nothing from the init trace, which allocates the leaves:
+            # fall through so that params initialize
+            o, walked = paged_gqa_step(
+                self, q, k, v, pos, paged, self.kv_num_blocks,
+                self.kv_block_size, scale)
+        if o is None:
+            o = causal_gqa_attention(q, k, v, pos, scale)
         with device_span("gqa_attention"):
-            scores = einsum_f32("bqkgd,blkd->bkgql",
-                                q.reshape(B, L, Hk, Hq // Hk, hd), k) * scale
-            seen = pos[:, None, :] <= pos[:, :, None]          # [B, q, l]
-            probs = jax.nn.softmax(
-                jnp.where(seen[:, None, None], scores, -1e30), -1)
-            o = einsum_f32("bkgql,blkd->bqkgd", probs.astype(self.dtype),
-                           v.reshape(B, L, Hk, hd)).astype(self.dtype)
-        return out(o), None
+            return mm(o.reshape(B, L, Hq * hd), wo), walked
 
 
 class Lfm2Layer(nn.Module):
@@ -327,7 +298,7 @@ class Lfm2ForCausalLM(nn.Module):
         # seeded at 1/sqrt(d): N_f(x_L) E^T then has unit scale, and the
         # tied head does not echo the input token (the first norm rescales
         # x_0 whatever its size)
-        embed = self.param("embed", _fan_in(d), (self.vocab_size, d),
+        embed = self.param("embed", fan_in(d), (self.vocab_size, d),
                            self.param_dtype)
         x = embed[input_ids].astype(self.dtype)
         names = ("conv_slots_advanced", "attn_positions_walked",

@@ -6,7 +6,8 @@ prediction module that drafts for the serve engine.
 Per layer ``u = x + N2(Attn(N1(x)))``, ``x' = u + N4(FFN(N3(u)))``: each
 sublayer's output is normed before it is added (``sandwich_norm``).  The
 attention, the routed experts, the SwiGLU and the RMSNorm are
-``models/xing4.py``'s own classes (plain rotary is its YaRN at factor 1);
+``models/layers.py``'s, as ``models/xing4.py``'s are (plain rotary is
+``LatentAttention``'s YaRN at factor 1);
 ``experts_held = (first, count)`` gives the chip's share of the routed
 experts and ``vocab_size`` its slice of the vocabulary (the router keeps
 its published width; what the absent experts would add is left out).
@@ -68,9 +69,9 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-from apex_example_tpu.models.xing4 import (LatentAttention, RoutedExperts,
-                                           SwiGLU, _fan_in, matmul_f32,
-                                           rms_norm)
+from apex_example_tpu.models.layers import (LatentAttention, RoutedExperts,
+                                            SwiGLU, fan_in, matmul_f32,
+                                            rms_norm)
 from apex_example_tpu.obs.spans import device_span
 from apex_example_tpu.ops import lane_pack
 
@@ -136,7 +137,7 @@ class NextTokenModule(nn.Module):
         with device_span("sandwich_norm"):
             both = jnp.concatenate([rms_norm(e, scale("enorm"), eps),
                                     rms_norm(h, scale("hnorm"), eps)], -1)
-        m = matmul_f32(both, self.param("eh_proj", _fan_in(2 * d),
+        m = matmul_f32(both, self.param("eh_proj", fan_in(2 * d),
                                         (2 * d, d), pd)).astype(c["dtype"])
         z, load, visits, walked = PanguLayer(self.cfg, False, name="block")(
             m, pos, paged, live, lanes)
@@ -231,7 +232,7 @@ class PanguMoEForCausalLM(nn.Module):
                 live = lanes.row_live
         embed = self.param("embed", nn.initializers.normal(1.0),
                            (self.vocab_size, d), self.param_dtype)
-        head = self.param("head", _fan_in(d), (d, self.vocab_size),
+        head = self.param("head", fan_in(d), (d, self.vocab_size),
                           self.param_dtype)
         counted = ([], [], [])                # load, visits, walked
 

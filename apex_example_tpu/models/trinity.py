@@ -53,26 +53,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_example_tpu.models.xing4 import (F32, RoutedExperts, SwiGLU,
-                                           _fan_in, einsum_f32, matmul_f32,
-                                           rms_norm)
+from apex_example_tpu.models.layers import (F32, RoutedExperts, SwiGLU,
+                                            causal_gqa_attention, fan_in,
+                                            matmul_f32, paged_gqa_step,
+                                            rms_norm, rotate_half)
 from apex_example_tpu.obs.spans import device_span
-from apex_example_tpu.ops import paged_cache
-from apex_example_tpu.ops.attention import paged_gqa_attention
 
 WINDOW, FULL = "sliding_attention", "full_attention"
-
-
-def rotate_half(x, pos, theta: float):
-    """``x [B, L, H, hd]`` rotated at positions ``pos [B, L]`` over all of
-    ``hd`` (pairs ``(i, i + hd / 2)``), float32 inside."""
-    hd = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
-    ang = pos.astype(F32)[..., None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    a, b = jnp.split(x.astype(F32), 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           -1).astype(x.dtype)
 
 
 class GatedGQAttention(nn.Module):
@@ -99,11 +86,11 @@ class GatedGQAttention(nn.Module):
         d, Hq, Hk, hd = (self.hidden_size, self.num_heads, self.num_kv_heads,
                          self.head_dim)
         pd, eps, W = self.param_dtype, self.rms_norm_eps, self.window
-        wq = self.param("wq", _fan_in(d), (d, Hq * hd), pd)
-        wk = self.param("wk", _fan_in(d), (d, Hk * hd), pd)
-        wv = self.param("wv", _fan_in(d), (d, Hk * hd), pd)
-        wg = self.param("wg", _fan_in(d), (d, Hq * hd), pd)
-        wo = self.param("wo", _fan_in(Hq * hd), (Hq * hd, d), pd)
+        wq = self.param("wq", fan_in(d), (d, Hq * hd), pd)
+        wk = self.param("wk", fan_in(d), (d, Hk * hd), pd)
+        wv = self.param("wv", fan_in(d), (d, Hk * hd), pd)
+        wg = self.param("wg", fan_in(d), (d, Hq * hd), pd)
+        wo = self.param("wo", fan_in(Hq * hd), (Hq * hd, d), pd)
         q_norm = self.param("q_norm", nn.initializers.ones, (hd,), pd)
         k_norm = self.param("k_norm", nn.initializers.ones, (hd,), pd)
         mm = lambda a, w: matmul_f32(a, w).astype(self.dtype)
@@ -119,70 +106,23 @@ class GatedGQAttention(nn.Module):
                 q = rotate_half(q, pos, self.rope_theta)
                 k = rotate_half(k, pos, self.rope_theta)
 
-        def out(o):
-            with device_span("gqa_attention"):
-                o = (o.reshape(B, L, Hq * hd).astype(F32)
-                     * gate).astype(self.dtype)
-                return mm(o, wo)
-
+        o = walked = None
         if self.decode:
             if not self.slot_decode:
                 raise ValueError("this model decodes through the block-"
                                  "paged slot path only (slot_decode=True)")
-            NB, BS = self.kv_num_blocks, self.kv_block_size
-            names = ("cached_key", "cached_value")
-            if W is None:
-                ready = self.has_variable("cache", names[0])
-                ck, cv = (paged_cache.variable(self, n, NB, BS, self.dtype,
-                                               Hk * hd) for n in names)
-            else:
-                # a window leaf: [slots * ring_blocks, BS, Hk * hd], the
-                # slots the init trace's batch
-                ready = paged_cache.has_window_variable(self, names[0], W)
-                ck, cv = (paged_cache.window_variable(
-                    self, n, B, W, BS, self.dtype, Hk * hd) for n in names)
-            if ready:
-                if paged is None or (W is not None
-                                     and "ring_table" not in paged):
-                    raise ValueError(
-                        "paged slot decode needs the host state: pass "
-                        "paged={'block_table', 'ring_table', 'fill', "
-                        "'n_new', 'cow_src', 'cow_dst'} (serve/engine.py "
-                        "builds it each tick)")
-                fill, n_new = paged["fill"], paged["n_new"]
-                if W is None:
-                    table, ring = paged["block_table"], None
-                    ck.value, cv.value = paged_cache.cow(
-                        (ck.value, cv.value), paged["cow_src"],
-                        paged["cow_dst"])
-                else:
-                    table = paged["ring_table"]
-                    ring = table.shape[1]
-                flat = paged_cache.write_rows(
-                    table, pos, n_new, ck.value.shape[0], BS,
-                    ring=ring is not None)
-                ck.value, cv.value = paged_cache.write(
-                    (ck.value, cv.value), flat,
-                    (k.reshape(B, L, Hk * hd), v))
-                # scores, mask, softmax and weighted sum: one op that
-                # names its own scope (ops/attention.py)
-                o, walked = paged_gqa_attention(
-                    q, ck.value, cv.value, table, fill, n_new, scale=scale,
-                    window=W, ring=ring)
-                return out(o), walked
-            # init trace on the [slots, max_len] dummy: the cache is
-            # allocated above; fall through so that params initialize
+            # a full layer's block leaves or a window layer's ring;
+            # nothing from the init trace, which allocates them: fall
+            # through so that params initialize
+            o, walked = paged_gqa_step(
+                self, q, k, v, pos, paged, self.kv_num_blocks,
+                self.kv_block_size, scale, window=W)
+        if o is None:
+            o = causal_gqa_attention(q, k, v, pos, scale, window=W)
         with device_span("gqa_attention"):
-            qg = q.reshape(B, L, Hk, Hq // Hk, hd)
-            scores = einsum_f32("bqkgd,blkd->bkgql", qg, k) * scale
-            seen = pos[:, None, :] <= pos[:, :, None]          # [B, q, l]
-            if W is not None:
-                seen &= pos[:, None, :] > pos[:, :, None] - W
-            probs = jax.nn.softmax(
-                jnp.where(seen[:, None, None], scores, -1e30), -1)
-            o = einsum_f32("bkgql,blkd->bqkgd", probs.astype(self.dtype),
-                           v.reshape(B, L, Hk, hd)).astype(self.dtype)
-        return out(o), None
+            o = (o.reshape(B, L, Hq * hd).astype(F32)
+                 * gate).astype(self.dtype)
+            return mm(o, wo), walked
 
 
 class TrinityLayer(nn.Module):
@@ -307,7 +247,7 @@ class TrinityForCausalLM(nn.Module):
             pos = paged["fill"][:, None] + pos
             live = jnp.arange(L)[None, :] < paged["n_new"][:, None]
         # seeded at 1/sqrt(d), so that x_0 = sqrt(d) E[ids] has unit scale
-        embed = self.param("embed", _fan_in(d), (self.vocab_size, d),
+        embed = self.param("embed", fan_in(d), (self.vocab_size, d),
                            self.param_dtype)
         x = embed[input_ids]
         if self.mup_enabled:
@@ -336,7 +276,7 @@ class TrinityForCausalLM(nn.Module):
             x = jnp.take_along_axis(x, lane[:, None, None], axis=1)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones, (d,),
                                    self.param_dtype), self.rms_norm_eps)
-        head = self.param("head", _fan_in(d), (d, self.vocab_size),
+        head = self.param("head", fan_in(d), (d, self.vocab_size),
                           self.param_dtype)
         return matmul_f32(x, head)
 

@@ -12,37 +12,15 @@ matrix that Sinkhorn iterations project onto the doubly stochastic ones.
 float32; the configuration file lists what the published config leaves
 open (``assumed``).
 
-Latent attention has two forms of the same mathematics.  The plain forward
-(training-shaped, no cache) *expands* the latent ``c_kv`` into per-head
-keys and values.  The paged slot-decode path (``decode=True,
-slot_decode=True``; the contract ``serve/slots.BlockPool`` and
-``serve/engine.ServeEngine`` hold every served model to) caches
-``c_kv ⊕ k_rope`` — ``kv_lora_rank + qk_rope_head_dim`` values a token a
-layer, after the norm and after the rotation, in ONE head-less
-``[num_blocks, block_size, W]`` arena leaf (``W`` those 576 values rounded
-up to whole 128-lane tiles, 640: what the tiled layout occupies anyway) —
-and attends in the *absorbed*
-form: queries are carried into the latent space (``q_nope W_UK^T``), scores
-and the weighted sum run against the cached latents themselves, and the
-result is carried out through ``W_UV``.  The cache is never up-projected.
-The leaf, its copy-on-write, write and gather are ops/paged_cache.py's, as
-models/bert.py's are (one layout, the cache donated: updated in place).
-
-Scores, mask, softmax and weighted sum of the paged path are one op,
-``ops.attention.paged_latent_attention``, in two forms chosen by the
-backend as every kernel here is.  On the TPU (and under the interpreter,
-which the tests run) a Pallas kernel walks each slot's live blocks where
-they lie in the arena — ``ceil((fill + n_new) / block)`` of them, none for
-a dead slot, a decode slot's one live lane in one row tile — with an online
-softmax in float32 scratch, and no ``[S, L, W]`` view or ``[S, H, C, L]``
-score tensor exists.  On the CPU and under ``FORCE_XLA`` the XLA form
-gathers every slot's whole row of the block table (``kv_gather``) and
-scores all ``L`` positions: the same function, the tests' golden.  The
-model sows what either read, ``attn_positions_walked [layers, S]``, beside
-``expert_load`` in the ``counters`` collection, and beside
-``expert_weight_visits [layers, E]`` where the expert layers' grouped
-products ran in their kernel (``ops/grouped_matmul.py``; the XLA form,
-``lax.ragged_dot``, counts nothing).
+Latent attention (``LatentAttention``, which tells its two forms: the plain
+forward expands the latent, the paged path caches ``c_kv ⊕ k_rope`` in ONE
+head-less arena leaf and attends absorbed), the routed experts and the SwiGLU
+are ``models/layers.py``'s.  The model sows what the paged attention read,
+``attn_positions_walked [layers, S]``, beside ``expert_load`` in the
+``counters`` collection, and beside ``expert_weight_visits [layers, E]``
+where the expert layers' grouped products ran in their kernel
+(``ops/grouped_matmul.py``; the XLA form, ``lax.ragged_dot``, counts
+nothing).
 
 **Packed lanes** (``packed_lanes = True``; ``ops/lane_pack.py``, PR 44).  The
 paged program carries the tick's *live* lanes as dense rows ``[R, n, d]``,
@@ -84,58 +62,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_example_tpu.models.layers import (F32, LatentAttention,
+                                            RoutedExperts, SwiGLU, fan_in,
+                                            matmul_f32, rms_norm)
 from apex_example_tpu.obs.spans import device_span
-from apex_example_tpu.ops import lane_pack, paged_cache
-from apex_example_tpu.ops.attention import paged_latent_attention
-from apex_example_tpu.transformer.expert_parallel import (dropless_experts,
-                                                          dropless_route,
-                                                          expert_load)
-
-F32 = jnp.float32
-
-
-def _fan_in(fan_in: int):
-    return nn.initializers.normal(1.0 / math.sqrt(fan_in))
-
-
-def matmul_f32(a, b):
-    """``a @ b`` accumulated and returned in float32 (on the TPU the MXU
-    multiplies bfloat16 operands exactly and adds in float32)."""
-    return jnp.matmul(a, b, preferred_element_type=F32)
-
-
-def einsum_f32(spec, a, b):
-    return jnp.einsum(spec, a, b, preferred_element_type=F32)
-
-
-def rms_norm(x, scale, eps):
-    """RMSNorm with float32 statistics; ``scale`` None = no learned scale."""
-    y = x.astype(F32)
-    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True) + eps)
-    if scale is not None:
-        y = y * scale.astype(F32)
-    return y.astype(x.dtype)
-
-
-def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
-                  beta_fast: float, beta_slow: float):
-    """The ``dim / 2`` rotary frequencies under YaRN: ``1/theta_i`` where
-    more than ``beta_fast`` rotations fit the original context,
-    ``1/(factor theta_i)`` where fewer than ``beta_slow`` do, and a linear
-    blend over the dimensions between."""
-    def corr_dim(rot):
-        return dim * math.log(original_max / (rot * 2 * math.pi)) \
-            / (2 * math.log(theta))
-    low = max(math.floor(corr_dim(beta_fast)), 0)
-    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
-    i = jnp.arange(dim // 2, dtype=F32)
-    extra = 1.0 / theta ** (2 * i / dim)
-    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
-    return extra / factor * ramp + extra * (1.0 - ramp)
-
-
-def yarn_mscale(factor: float, m: float) -> float:
-    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+from apex_example_tpu.ops import lane_pack
 
 
 def sinkhorn(logits, iters: int, eps: float, lo: float, hi: float):
@@ -162,7 +93,7 @@ class HyperConnection(nn.Module):
 
     def setup(self):
         n, nd = self.n, self.n * self.hidden_size
-        w = lambda name, cols: self.param(name, _fan_in(nd), (nd, cols),
+        w = lambda name, cols: self.param(name, fan_in(nd), (nd, cols),
                                           self.param_dtype)
         self.w_pre, self.w_post = w("w_pre", n), w("w_post", n)
         self.w_res = w("w_res", n * n)
@@ -203,216 +134,6 @@ class HyperConnection(nn.Module):
                        for j in range(self.n)) + h_post[..., i, None] * yf
                    for i in range(self.n)]
             return jnp.stack(out, axis=-2).astype(X.dtype)
-
-
-class SwiGLU(nn.Module):
-    hidden_size: int
-    width: int
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        d, f = self.hidden_size, self.width
-        w_gate = self.param("w_gate", _fan_in(d), (d, f), self.param_dtype)
-        w_up = self.param("w_up", _fan_in(d), (d, f), self.param_dtype)
-        w_down = self.param("w_down", _fan_in(f), (f, d), self.param_dtype)
-        h = (jax.nn.silu(matmul_f32(x, w_gate))
-             * matmul_f32(x, w_up)).astype(self.dtype)
-        return matmul_f32(h, w_down).astype(self.dtype)
-
-
-class RoutedExperts(nn.Module):
-    """Dropless top-k of ``n_experts`` on sigmoid scores with a
-    selection-only bias, plus ``n_shared`` shared experts as one SwiGLU of
-    ``n_shared * width`` (none at 0).  ``experts_held = (first,
-    count)``: the routed experts whose weights live here; the router always
-    has its ``n_experts`` outputs, and what the other experts would add is
-    left out (another chip's share).  Returns ``(y, load, visits)``: ``load
-    [n_experts]`` the live lanes routed to each expert, ``visits
-    [n_experts]`` the row tiles the grouped kernel visited for each (0 for
-    one not held or not touched), None from the XLA form."""
-
-    hidden_size: int
-    width: int
-    n_experts: int
-    top_k: int
-    scale: float
-    experts_held: Tuple[int, int]
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.bfloat16
-    n_shared: int = 1
-
-    @nn.compact
-    def __call__(self, x, live=None):
-        d, f, E = self.hidden_size, self.width, self.n_experts
-        count = self.experts_held[1]
-        router = self.param("router", _fan_in(d), (d, E), F32)
-        bias = self.param("router_bias", nn.initializers.zeros, (E,), F32)
-        w_gate = self.param("w_gate", _fan_in(d), (count, d, f),
-                            self.param_dtype)
-        w_up = self.param("w_up", _fan_in(d), (count, d, f),
-                          self.param_dtype)
-        w_down = self.param("w_down", _fan_in(f), (count, f, d),
-                            self.param_dtype)
-        flat = x.reshape(-1, d)
-        idx, gates = dropless_route(flat, router, bias, self.top_k,
-                                    self.scale)
-        live = None if live is None else live.reshape(-1)
-        y, visits = dropless_experts(flat, idx, gates, w_gate, w_up, w_down,
-                                     self.experts_held, live)
-        if visits is not None:
-            first = self.experts_held[0]
-            visits = jnp.pad(visits, (first, E - first - count))
-        if self.n_shared:
-            with device_span("shared_expert"):
-                y = y + SwiGLU(d, f * self.n_shared, self.dtype,
-                               self.param_dtype, name="shared")(flat)
-        load = expert_load(idx, E, live)
-        return y.reshape(x.shape), load, visits
-
-
-class LatentAttention(nn.Module):
-    """MLA; see the module docstring for the two forms.  Returns ``(y,
-    walked)``: ``walked [S]`` the cache positions the paged form read for
-    each slot this call, None from the plain forward."""
-
-    hidden_size: int
-    num_heads: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
-    q_lora_rank: int
-    kv_lora_rank: int
-    rms_norm_eps: float
-    rope: Tuple[float, ...]      # theta, factor, original_max, beta_fast,
-    #                              beta_slow, mscale, mscale_all_dim
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.bfloat16
-    decode: bool = False
-    slot_decode: bool = False
-    kv_num_blocks: int = 0
-    kv_block_size: int = 0
-
-    def _rotate(self, x, pos):
-        """x [B, L, (H,) dr] at positions pos [B, L] (or rows ``[R, (H,)
-        dr]`` at ``[R]``), float32 inside."""
-        theta, factor, orig, fast, slow, ms, ms_all = self.rope
-        inv = yarn_inv_freq(self.qk_rope_head_dim, theta, factor, int(orig),
-                            fast, slow)
-        ang = pos.astype(F32)[..., None] * inv
-        m = yarn_mscale(factor, ms) / yarn_mscale(factor, ms_all)
-        cos, sin = jnp.cos(ang) * m, jnp.sin(ang) * m
-        if x.ndim == pos.ndim + 2:
-            cos, sin = cos[..., None, :], sin[..., None, :]
-        a, b = jnp.split(x.astype(F32), 2, axis=-1)
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                               -1).astype(x.dtype)
-
-    @nn.compact
-    def __call__(self, x, pos, paged=None, lanes=None):
-        """``x`` is ``[B, L, d]`` at ``pos [B, L]``, or with ``lanes`` (a
-        ``lane_pack.LaneMap``, paged path only) the tick's packed rows
-        ``[R, d]``: everything but the paged kernel runs on what it is
-        given, the latents go to the arena from their rows, the kernel
-        sees the absorbed queries as ``[S, C, H, W]``."""
-        d, H = self.hidden_size, self.num_heads
-        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
-                      self.v_head_dim)
-        qr, kr, eps = self.q_lora_rank, self.kv_lora_rank, self.rms_norm_eps
-        pd = self.param_dtype
-        w_dq = self.param("w_dq", _fan_in(d), (d, qr), pd)
-        q_norm = self.param("q_norm", nn.initializers.ones, (qr,), pd)
-        w_uq = self.param("w_uq", _fan_in(qr), (qr, H * (dn + dr)), pd)
-        w_dkv = self.param("w_dkv", _fan_in(d), (d, kr + dr), pd)
-        kv_norm = self.param("kv_norm", nn.initializers.ones, (kr,), pd)
-        w_uk = self.param("w_uk", _fan_in(kr), (kr, H, dn), pd)
-        w_uv = self.param("w_uv", _fan_in(kr), (kr, H, dv), pd)
-        w_o = self.param("w_o", _fan_in(H * dv), (H * dv, d), pd)
-        scale = (dn + dr) ** -0.5 \
-            * yarn_mscale(self.rope[1], self.rope[6]) ** 2
-        mm = lambda a, w: matmul_f32(a, w).astype(self.dtype)
-        ein = lambda spec, a, b: einsum_f32(spec, a, b)
-
-        B, L = pos.shape
-        lead = x.shape[:-1]                             # [B, L], or [R]
-        at = pos if lanes is None else lanes.pack(pos)
-        cq = rms_norm(mm(x, w_dq), q_norm, eps)
-        q = mm(cq, w_uq).reshape(*lead, H, dn + dr)
-        q_nope, q_rope = q[..., :dn], self._rotate(q[..., dn:], at)
-        ckr = mm(x, w_dkv)
-        ckv = rms_norm(ckr[..., :kr], kv_norm, eps)
-        k_rope = self._rotate(ckr[..., kr:], at)        # one key, all heads
-
-        if self.decode:
-            if not self.slot_decode:
-                raise ValueError("this model decodes through the block-"
-                                 "paged slot path only (slot_decode=True)")
-            NB, BS = self.kv_num_blocks, self.kv_block_size
-            cache_ready = self.has_variable("cache", "cached_latent")
-            # ONE head-less [NB, BS, W] leaf (ops/paged_cache.py): c_kv
-            # (after the norm) and k_rope (after the rotation) side by
-            # side, kr + dr values stored in whole 128-lane tiles.
-            W = paged_cache.lane_tiles(kr + dr)
-            cl = paged_cache.variable(self, "cached_latent", NB, BS,
-                                      self.dtype, W)
-            if cache_ready:
-                if paged is None:
-                    raise ValueError(
-                        "paged slot decode needs the host state: pass "
-                        "paged={'block_table', 'fill', 'n_new', 'cow_src', "
-                        "'cow_dst'} (serve/engine.py builds it each tick)")
-                S, C = B, L
-                table, n_new = paged["block_table"], paged["n_new"]
-                cl.value = paged_cache.cow(cl.value, paged["cow_src"],
-                                           paged["cow_dst"])
-                flat = paged_cache.write_rows(table, pos, n_new, NB, BS)
-                if lanes is not None:
-                    # the latents are packed rows: so are their places in
-                    # the arena (a dead row drops)
-                    flat = lanes.pack(flat.reshape(S, C), fill=NB * BS)
-                with device_span("kv_write"):
-                    lat = jnp.concatenate(
-                        [ckv, k_rope,
-                         jnp.zeros(lead + (W - kr - dr,), self.dtype)], -1)
-                cl.value = paged_cache.write(cl.value, flat, lat)
-                with device_span("latent_attention"):
-                    # absorbed: queries into the latent space, scores and
-                    # the weighted sum against the cached latents, out
-                    # through W_UV — the cache is never up-projected
-                    qf = jnp.concatenate(
-                        [ein("...hd,rhd->...hr", q_nope, w_uk).astype(
-                            self.dtype), q_rope,
-                         jnp.zeros(lead + (H, W - kr - dr), self.dtype)], -1)
-                if lanes is not None:
-                    qf = lanes.unpack(qf)               # [S, C, H, W]
-                # scores, mask, softmax and weighted sum.  On the TPU one
-                # Pallas call that walks each slot's live blocks where
-                # they lie in the arena; on the CPU and under FORCE_XLA
-                # the XLA form, which gathers every slot's [L, W] view
-                # (kv_gather) and scores all L positions.  The op names
-                # its own scopes (ops/attention.py).
-                ol, walked = paged_latent_attention(
-                    qf, cl.value, table, paged["fill"], n_new, scale=scale,
-                    kr=kr)
-                if lanes is not None:
-                    ol = lanes.pack(ol)                 # [R, H, kr]
-                with device_span("latent_attention"):
-                    o = ein("...hr,rhd->...hd", ol, w_uv).astype(self.dtype)
-                    return mm(o.reshape(*lead, H * dv), w_o), walked
-            # init trace on the [B, max_len] dummy: the cache is allocated
-            # above; fall through so that params and shapes initialize.
-        with device_span("latent_attention"):
-            # expanded: per-head keys and values from the latent
-            k_nope = ein("blr,rhd->blhd", ckv, w_uk).astype(self.dtype)
-            v = ein("blr,rhd->blhd", ckv, w_uv).astype(self.dtype)
-            scores = (ein("bqhd,bkhd->bhqk", q_nope, k_nope)
-                      + ein("bqhd,bkd->bhqk", q_rope, k_rope)) * scale
-            keep = pos[:, None, :, None] >= pos[:, None, None, :]
-            probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), -1)
-            o = ein("bhqk,bkhd->bqhd", probs.astype(self.dtype),
-                    v).astype(self.dtype)
-            return mm(o.reshape(B, L, H * dv), w_o), None
 
 
 class Xing4Layer(nn.Module):
@@ -583,7 +304,7 @@ class Xing4ForCausalLM(nn.Module):
         x = jnp.sum(X.astype(F32), axis=2).astype(self.dtype)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones, (d,),
                                    self.param_dtype), self.rms_norm_eps)
-        head = self.param("head", _fan_in(d), (d, self.vocab_size),
+        head = self.param("head", fan_in(d), (d, self.vocab_size),
                           self.param_dtype)
         return matmul_f32(x, head)
 

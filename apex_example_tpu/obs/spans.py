@@ -43,7 +43,9 @@ from apex_example_tpu.obs import trace as trace_lib
 # loop through span(); the device-side ones through device_span by
 # engine.make_train_step, the loss functions of workloads.py, the models'
 # heads, ops/paged_cache.py (kv_cow, kv_write, kv_gather), the paged branch
-# of models/bert.py, models/xing4.py, models/granite_hybrid.py,
+# of models/bert.py, models/layers.py (the served decoders' shared layers:
+# latent_attention, shared_expert, the latent's kv_write, the plain
+# forward's gqa_attention), models/xing4.py, models/granite_hybrid.py,
 # models/pangu_moe.py, models/trinity.py, models/lfm2.py, ops/lane_pack.py,
 # ops/attention.py (paged_gqa_attention),
 # the dropless layer of
@@ -68,7 +70,7 @@ PHASES = (
     "kv_gather",        # device: paged decode, each slot's K/V view gathered
     "paged_attention",  # device: paged decode, attention + output projection
     "sample",           # device: serve step, last-lane take + sampling
-    "latent_attention",  # device: models/xing4.py, MLA (absorbed or expanded)
+    "latent_attention",  # device: models/layers.py, MLA (absorbed or expanded)
     "hc_mix",           # device: hyper-connection coefficients, Sinkhorn, mixing
     "moe_route",        # device: dropless experts, router + top-k + gates
     "moe_dispatch",     # device: dropless experts, sort by expert + gather

@@ -519,7 +519,7 @@ def test_the_other_callers_trees_and_ticks_are_the_parents(name):
 
 
 def test_routed_experts_without_a_shared_expert_is_the_routed_sum():
-    from apex_example_tpu.models.xing4 import RoutedExperts
+    from apex_example_tpu.models.layers import RoutedExperts
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 32))
     kw = dict(hidden_size=32, width=16, n_experts=4, top_k=2, scale=1.0,
               experts_held=(0, 4), dtype=jnp.float32,

@@ -9,8 +9,10 @@ the mechanism (ResNet-50's step, the six serve ticks, the model's
 ``__call__``) lowering to the text it had before.
 """
 
+import ast
 import hashlib
 import math
+import pathlib
 import re
 
 import jax
@@ -20,6 +22,7 @@ import optax
 import pytest
 
 from apex_example_tpu import amp, workloads
+from apex_example_tpu import models as models_pkg
 from apex_example_tpu.engine import (create_train_state,
                                      make_sharded_train_step,
                                      make_train_step)
@@ -317,3 +320,71 @@ def test_the_six_serve_ticks_are_the_parents_text(name):
         jnp.zeros((4, eng.tick_args.width), jnp.int32),
         jax.random.PRNGKey(0)).as_text()
     assert _sha(text) == TICK_BEFORE_PR46[name]
+
+
+# What the tick's text does not hold: names.  sha256 (16 digits each) of the
+# parameter tree (paths, shapes, dtypes) of ``model.init`` at (1, 4) ids, the
+# leaves a checkpoint finds by path, and of the ``cache`` tree of the paged
+# clone (``decode=True, slot_decode=True``; 4 slots x 64, 32 blocks of 8),
+# the leaves ``serve/slots.BlockPool`` finds by name; read on the parent of
+# PR 47 (f0a84cd), where ``models/xing4.py`` held the shared layers.
+TREES_BEFORE_PR47 = {
+    "xing4": ("ba01ff959f52bcc9", "2819ec28355a03af"),
+    "granite": ("f8cd4135ab977b14", "120c7d79d3f5763e"),
+    "pangu": ("0fbebc4380dcdb26", "403efb8824e12c68"),
+    "trinity": ("169d2b945e80e893", "56d880b035cf7e40"),
+    "lfm2": ("e7020ccca5840f4e", "e439eb2fbd89e0e4"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TREES_BEFORE_PR47))
+def test_the_served_models_parameter_and_cache_trees_are_the_parents(name):
+    model = _served(name)
+    key = jax.random.PRNGKey(0)
+    tree = lambda shapes: _sha(str(jax.tree_util.tree_map(
+        lambda t: (t.shape, str(t.dtype)), shapes)))[:16]
+    params = jax.eval_shape(model.init, key,
+                            jnp.zeros((1, 4), jnp.int32))["params"]
+    paged = model.clone(decode=True, slot_decode=True, kv_num_blocks=32,
+                        kv_block_size=8)
+    cache = jax.eval_shape(paged.init, key,
+                           jnp.zeros((4, 64), jnp.int32))["cache"]
+    assert (tree(params), tree(cache)) == TREES_BEFORE_PR47[name]
+
+
+# The arrows: serve/ -> models/<one model>.py -> models/layers.py -> ops/.
+SERVED_MODEL_FILES = ("xing4", "granite_hybrid", "trinity", "lfm2",
+                      "pangu_moe")
+
+
+def _models_imported(name):
+    """The modules of ``apex_example_tpu.models`` that ``models/<name>.py``
+    imports, read from its source (nothing runs)."""
+    tree = ast.parse((pathlib.Path(models_pkg.__file__).parent
+                      / f"{name}.py").read_text())
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            dotted = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = ("apex_example_tpu.models" + ("." + node.module
+                                                 if node.module else "")
+                    if node.level else node.module)
+            dotted = [base] + [f"{base}.{a.name}" for a in node.names]
+        else:
+            continue
+        for d in dotted:
+            parts = d.split(".")
+            if parts[:2] == ["apex_example_tpu", "models"] and len(parts) > 2:
+                found.add(parts[2])
+    return found
+
+
+@pytest.mark.parametrize("name", SERVED_MODEL_FILES + ("layers",))
+def test_no_served_model_imports_another_and_the_layers_import_none(name):
+    imported = _models_imported(name)
+    if name == "layers":
+        assert not imported
+    else:
+        assert "layers" in imported
+        assert not imported & set(SERVED_MODEL_FILES) - {name}

@@ -25,7 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from apex_example_tpu.models import pangu_moe, xing4  # noqa: E402
+from apex_example_tpu.models import layers, pangu_moe, xing4  # noqa: E402
 from apex_example_tpu.ops import grouped_matmul, paged_cache  # noqa: E402
 from apex_example_tpu.serve import Request, ServeEngine  # noqa: E402
 from apex_example_tpu.serve import engine as engine_lib  # noqa: E402
@@ -219,7 +219,7 @@ def test_a_lower_precision_in_one_place_fails_the_tolerance(
     if where == "router":
         real = ep.dropless_route
         monkeypatch.setattr(
-            xing4, "dropless_route",
+            layers, "dropless_route",
             lambda x, w, *a, **k: real(bf16(x), bf16(w), *a, **k))
     else:
         real = jax.nn.softmax
@@ -404,10 +404,10 @@ def _emulate_mxu(monkeypatch):
     multiplies bfloat16 operands exactly and adds in float32, which an
     upcast of both operands is (tests/test_xing4.py)."""
     up = lambda t: t.astype(jnp.float32)
-    for mod in (xing4, pangu_moe):
+    for mod in (layers, pangu_moe):
         monkeypatch.setattr(mod, "matmul_f32",
                             lambda a, b: jnp.matmul(up(a), up(b)))
-    monkeypatch.setattr(xing4, "einsum_f32",
+    monkeypatch.setattr(layers, "einsum_f32",
                         lambda s, a, b: jnp.einsum(s, up(a), up(b)))
     monkeypatch.setattr(grouped_matmul, "_dot_f32",
                         lambda a, b: jnp.matmul(up(a), up(b)))
@@ -461,7 +461,7 @@ def test_expert_shares_add_up_to_the_whole_layer(params):
     no_shared = jax.tree_util.tree_map(jnp.zeros_like, p["shared"])
 
     def share(first, count):
-        layer = xing4.RoutedExperts(64, 32, 8, 2, 2.5, (first, count),
+        layer = layers.RoutedExperts(64, 32, 8, 2, 2.5, (first, count),
                                     jnp.float32, jnp.float32)
         held = {n: p[n][first:first + count]
                 for n in ("w_gate", "w_up", "w_down")}

@@ -25,6 +25,7 @@ import json
 import math
 import os
 import signal
+import subprocess
 import sys
 import time
 
@@ -472,11 +473,15 @@ def test_resilience_cli_guards():
 @pytest.fixture(scope="module")
 def baseline(tmp_path_factory):
     """Uninterrupted 6-step run under the shared config: the loss-trail
-    oracle for the equivalence tests — and the clean-run acceptance
-    check (grace armed, zero resilience records emitted)."""
+    oracle for the supervised run — and the clean-run acceptance check
+    (grace armed, zero resilience records emitted).  Made the way the
+    supervised child is made, a plain ``python train.py``: in this process
+    conftest.py has put the Pallas kernels under the interpreter, the
+    child runs their XLA forms, and the two differ in the last bits."""
     path = str(tmp_path_factory.mktemp("resilience_base") / "a.jsonl")
-    rc = train_mod.main(_args(6) + ["--metrics-jsonl", path,
-                                    "--preempt-grace"])
+    rc = subprocess.call(
+        [sys.executable, os.path.join(REPO, "train.py")] + _args(6)
+        + ["--metrics-jsonl", path, "--preempt-grace"])
     assert rc == 0
     records = obs.read_jsonl(path)
     kinds = [r["record"] for r in records]
@@ -542,7 +547,7 @@ def test_supervised_sigterm_e2e(tmp_path, baseline, capsys):
     # Children inherit the suite's XLA_FLAGS (8-logical-device client):
     # the XLA CPU client's device count perturbs low-bit float reduction
     # order, and the splice assertion below is BIT-exact against the
-    # in-process baseline — the environments must match.
+    # baseline, a child of this process too — the environments match.
     ck = str(tmp_path / "ck")
     sup_path = str(tmp_path / "sup.jsonl")
     child_metrics = str(tmp_path / "child.jsonl")

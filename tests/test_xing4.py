@@ -22,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from apex_example_tpu.models import xing4  # noqa: E402
+from apex_example_tpu.models import layers, xing4  # noqa: E402
 from apex_example_tpu.models.gpt import gpt_tiny  # noqa: E402
 from apex_example_tpu.ops import grouped_matmul, paged_cache  # noqa: E402
 from apex_example_tpu.serve import Request, ServeEngine  # noqa: E402
@@ -178,9 +178,12 @@ def _emulate_mxu(monkeypatch):
     multiplies bfloat16 operands exactly and adds in float32, which an
     upcast of both operands is."""
     up = lambda t: t.astype(jnp.float32)
-    monkeypatch.setattr(xing4, "matmul_f32",
-                        lambda a, b: jnp.matmul(up(a), up(b)))
-    monkeypatch.setattr(xing4, "einsum_f32",
+    # the one home, where the shared layers read them, and the model's
+    # own binding (its head)
+    for mod in (layers, xing4):
+        monkeypatch.setattr(mod, "matmul_f32",
+                            lambda a, b: jnp.matmul(up(a), up(b)))
+    monkeypatch.setattr(layers, "einsum_f32",
                         lambda s, a, b: jnp.einsum(s, up(a), up(b)))
     # the grouped products: the kernel's dot (the interpreter runs it
     # here) and the XLA form
@@ -256,7 +259,7 @@ def test_yarn_frequencies_by_hand():
     """64 rotary dimensions, theta 10000, factor 64 over 4096 positions,
     beta 32 and 1: the ramp runs over dimensions 10 (32 rotations fit:
     floor(10.47)) to 23 (one fits: ceil(22.51))."""
-    f = np.asarray(xing4.yarn_inv_freq(64, 10000.0, 64.0, 4096, 32.0, 1.0))
+    f = np.asarray(layers.yarn_inv_freq(64, 10000.0, 64.0, 4096, 32.0, 1.0))
     assert f.shape == (32,)
     np.testing.assert_allclose(f[0], 1.0, rtol=1e-6)
     np.testing.assert_allclose(f[10], 10000.0 ** (-20 / 64), rtol=1e-5)
@@ -269,7 +272,7 @@ def test_yarn_frequencies_by_hand():
     np.testing.assert_allclose(
         f, REF.xing4_yarn_inv_freq(dict(RCFG, qk_rope_head_dim=64)),
         rtol=1e-6)
-    assert xing4.yarn_mscale(64.0, 1.0) == pytest.approx(1.4159, abs=1e-4)
+    assert layers.yarn_mscale(64.0, 1.0) == pytest.approx(1.4159, abs=1e-4)
     assert REF.xing4_softmax_scale(dict(
         RCFG, qk_nope_head_dim=128, qk_rope_head_dim=64)) \
         == pytest.approx(192 ** -0.5 * 1.4159 ** 2, rel=1e-4)
@@ -325,7 +328,7 @@ def test_dead_lanes_belong_to_no_group():
                routed_scaling_factor=2.0)
     x = jax.random.normal(jax.random.PRNGKey(6), (4, 6, d))
     live = jnp.arange(6)[None, :] < jnp.asarray([6, 1, 0, 3])[:, None]
-    layer = xing4.RoutedExperts(d, f, E, k, 2.0, (0, E), jnp.float32,
+    layer = layers.RoutedExperts(d, f, E, k, 2.0, (0, E), jnp.float32,
                                 jnp.float32)
     y, load, _ = layer.apply({"params": p}, x, live)
     whole = REF.xing4_moe(x, p, cfg)
@@ -349,7 +352,7 @@ def test_expert_shares_add_up_to_the_whole_layer():
     whole = REF.xing4_moe(x, p, cfg)
 
     def share(first, count):
-        layer = xing4.RoutedExperts(d, f, E, k, 2.0, (first, count),
+        layer = layers.RoutedExperts(d, f, E, k, 2.0, (first, count),
                                     jnp.float32, jnp.float32)
         held = {n: p[n][first:first + count]
                 for n in ("w_gate", "w_up", "w_down")}
